@@ -18,6 +18,8 @@ from benchmarks.common import write_bench_json
 
 
 def main() -> None:
+    from repro.core.platform import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (infer_bench, kernel_bench, serve_bench,
                             table1_2x2, table6_error, table7_4x4,
                             table8_dist, table9_scaling, table10_psnr)

@@ -59,6 +59,7 @@ _early_device_flag(sys.argv[1:])
 
 import numpy as np                                                # noqa: E402
 
+from repro.core.platform import enable_compile_cache              # noqa: E402
 from repro.filters import apply_filter                            # noqa: E402
 from repro.serve import ImageFilterServer, ServerConfig           # noqa: E402
 
@@ -114,6 +115,7 @@ def main():
                     help="write the §15 request trace (JSONL) here; "
                          "convert via python -m repro.obs.snapshot")
     args = ap.parse_args()
+    enable_compile_cache()
 
     infer_models = build_infer_models() if args.infer else None
     workloads = None

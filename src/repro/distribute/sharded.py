@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import Array
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distribute.mesh import BATCH_AXIS, ROWS_AXIS, filter_mesh, shard_dims
@@ -97,13 +96,14 @@ def _sharded_fn(pass_key: tuple, pass_fn: Callable, mesh: Mesh, ph: int,
         if halo == "exchange":
             nr = mesh.devices.shape[1]
             body = _exchange_body(pass_fn, ph, nr)
-            sm = shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                           check_rep=False)     # pallas_call has no rep rule
+            sm = jax.shard_map(body, mesh=mesh, in_specs=spec,
+                               out_specs=spec,
+                               check_vma=False)  # pallas_call has no vma rule
         else:
             body = _embedded_body(pass_fn, ph, hl)
             bspec = P(ROWS_AXIS, BATCH_AXIS)
-            sm = shard_map(body, mesh=mesh, in_specs=bspec, out_specs=bspec,
-                           check_rep=False)
+            sm = jax.shard_map(body, mesh=mesh, in_specs=bspec,
+                               out_specs=bspec, check_vma=False)
         fn = _FN_CACHE[key] = jax.jit(sm)
     return fn
 

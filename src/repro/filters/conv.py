@@ -55,8 +55,10 @@ Tap-product implementations (`mult_impl`, DESIGN.md §7):
   * 'kcm'     -- constant-coefficient fast path: coefficients are trace-time
     constants, so each tap is a `repro.core.kcm` product-table gather
     (sign baked in), bit-identical to 'recurse' for every method;
-  * 'auto'    -- 'kcm' whenever the taps are static (not traced), else
-    'recurse'.
+  * 'auto'    -- 'kcm' whenever the taps are static (not traced) and the
+    pass runs under the interpreter, else 'recurse'. Compiled TPU passes
+    always take 'recurse': Mosaic lowers only 2-D gathers, not the 1-D
+    ROM lookup.
 
 Multiplier methods: 'exact', 'refmlm', 'refmlm_nc', 'mitchell',
 'mitchell_ecc{k}', 'odma' -- see repro/core and DESIGN.md §1.
@@ -178,12 +180,19 @@ def _is_static(taps) -> bool:
         return False
 
 
-def _resolve_mult_impl(mult_impl: str, *tap_arrays) -> str:
+def _resolve_mult_impl(mult_impl: str, *tap_arrays, interpret: bool) -> str:
+    """'auto' -> 'kcm' for static taps under the interpreter, else
+    'recurse'. Compiled (Mosaic) passes never take 'kcm': its ROM lookup is
+    a 1-D gather, which Mosaic does not lower."""
     if mult_impl not in MULT_IMPLS:
         raise ValueError(f"mult_impl must be one of {MULT_IMPLS}, got {mult_impl!r}")
     static = all(_is_static(t) for t in tap_arrays)
     if mult_impl == "auto":
-        return "kcm" if static else "recurse"
+        return "kcm" if static and interpret else "recurse"
+    if mult_impl == "kcm" and not interpret:
+        raise ValueError("mult_impl='kcm' runs only under the Pallas "
+                         "interpreter: Mosaic cannot lower the KCM table "
+                         "gather; use 'recurse' (bit-identical) or 'auto'")
     if mult_impl == "kcm" and not static:
         raise ValueError("mult_impl='kcm' needs trace-time-constant taps; "
                          "traced coefficients must use 'recurse'")
@@ -357,12 +366,12 @@ def conv2d_pass(
     mult_impl picks the tap-product implementation (module docstring).
     """
     interpret = resolve_interpret(interpret)
-    impl = _resolve_mult_impl(mult_impl, taps)
+    impl = _resolve_mult_impl(mult_impl, taps, interpret=interpret)
     n, h, w = imgs.shape
     kh, kw = np.shape(taps)     # list/tuple taps accepted, Tracers untouched
     cfg = resolve_blocks("direct", n, h, w, kh, kw, impl,
                          block_rows=block_rows, block_cols=block_cols,
-                         batch_fold=batch_fold)
+                         batch_fold=batch_fold, interpret=interpret)
     if impl == "kcm":
         taps_np = np.asarray(taps)
         tables, acc = _tables_for(method, taps_np, nbits)
@@ -524,13 +533,13 @@ def fused_separable_pass(
     cache exactly like `conv2d_pass` (DESIGN.md §8).
     """
     interpret = resolve_interpret(interpret)
-    impl = _resolve_mult_impl(mult_impl, row, col)
+    impl = _resolve_mult_impl(mult_impl, row, col, interpret=interpret)
     n, h, w = imgs.shape
     kh = int(np.asarray(col).size) if _is_static(col) else col.shape[-1]
     kw = int(np.asarray(row).size) if _is_static(row) else row.shape[-1]
     cfg = resolve_blocks("fused", n, h, w, kh, kw, impl,
                          block_rows=block_rows, block_cols=block_cols,
-                         batch_fold=batch_fold)
+                         batch_fold=batch_fold, interpret=interpret)
     if cfg.block_rows < 2 * (kh // 2):
         if block_rows is not None:      # explicit values win or fail loud
             raise ValueError(f"block_rows={block_rows} too shallow for a "

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import Array
 
+from repro.core.platform import resolve_interpret
 from repro.filters.bank import (
     FILTER_NAMES,
     FilterSpec,
@@ -194,7 +195,7 @@ def apply_filter(
                         separable_ok=spec.separable, mult_impl=mult_impl,
                         separable=separable, fused=fused,
                         block_rows=block_rows, block_cols=block_cols,
-                        batch_fold=batch_fold)
+                        batch_fold=batch_fold, interpret=interpret)
     out = _apply(arr, spec, method, nbits, plan.dataflow != "direct",
                  plan.dataflow == "fused", plan.mult_impl, plan.block_rows,
                  plan.block_cols, plan.batch_fold, interpret)
@@ -211,6 +212,7 @@ def resolve_filter_blocks(
     mult_impl: str = "auto",
     separable: bool | None = None,
     fused: bool | None = None,
+    interpret: bool | None = None,
 ) -> "BlockConfig":
     """The grid organization `apply_filter` would resolve for an (n, h, w)
     batch of `filt` -- dataflow kind, tap extents and resolved mult_impl
@@ -228,18 +230,20 @@ def resolve_filter_blocks(
     from repro.filters.conv import _resolve_mult_impl
     from repro.tuning import resolve_blocks_cached
 
+    interpret = resolve_interpret(interpret)
     spec = get_filter(filt) if isinstance(filt, str) else filt
     separable = spec.separable if separable is None else separable
     fused = separable if fused is None else fused
     if fused and separable:
         kind = "fused"
         kh, kw = len(spec.sep_col), len(spec.sep_row)
-        impl = _resolve_mult_impl(mult_impl, spec.sep_row, spec.sep_col)
+        tap_arrays = (spec.sep_row, spec.sep_col)
     else:
         kind = "direct"
         kh, kw = np.shape(spec.taps)
-        impl = _resolve_mult_impl(mult_impl, spec.taps)
-    return resolve_blocks_cached(kind, n, h, w, kh, kw, impl)
+        tap_arrays = (spec.taps,)
+    impl = _resolve_mult_impl(mult_impl, *tap_arrays, interpret=interpret)
+    return resolve_blocks_cached(kind, n, h, w, kh, kw, impl, interpret)
 
 
 def resolve_filter_plan(
@@ -252,6 +256,7 @@ def resolve_filter_plan(
     mult_impl: str = "auto",
     separable: bool | None = None,
     fused: bool | None = None,
+    interpret: bool | None = None,
 ) -> PlanConfig:
     """The fully-concrete execution plan `apply_filter` would run for an
     (n, h, w) batch of `filt`: dataflow, resolved mult_impl and grid
@@ -270,10 +275,11 @@ def resolve_filter_plan(
     from repro.filters.conv import _resolve_mult_impl
     from repro.tuning import resolve_blocks_cached
 
+    interpret = resolve_interpret(interpret)
     spec = get_filter(filt) if isinstance(filt, str) else filt
     plan = resolve_plan(spec.name, n, h, w, *spec.ksize,
                         separable_ok=spec.separable, mult_impl=mult_impl,
-                        separable=separable, fused=fused)
+                        separable=separable, fused=fused, interpret=interpret)
     if plan.dataflow == "fused":
         kind = "fused"
         kh, kw = len(spec.sep_col), len(spec.sep_row)
@@ -288,10 +294,10 @@ def resolve_filter_plan(
         kind = "direct"
         kh, kw = spec.ksize
         tap_arrays = (spec.taps,)
-    impl = (plan.mult_impl if plan.mult_impl != "auto"
-            else _resolve_mult_impl("auto", *tap_arrays))
+    impl = _resolve_mult_impl(plan.mult_impl, *tap_arrays,
+                              interpret=interpret)
     if None in (plan.block_rows, plan.block_cols, plan.batch_fold):
-        base = resolve_blocks_cached(kind, n, h, w, kh, kw, impl)
+        base = resolve_blocks_cached(kind, n, h, w, kh, kw, impl, interpret)
         plan = PlanConfig(
             plan.dataflow, impl,
             base.block_rows if plan.block_rows is None else plan.block_rows,

@@ -13,9 +13,10 @@ The kernel emits THREE int32 accumulators (hh, mid, ll) so reconstruction /
 rescale happens outside in f32 and the kernel stays bit-exact vs ref.py.
 
 Tiling: classic (M/bm, N/bn, K/bk) grid; all limb blocks in VMEM. MXU dims
-default to 128-multiples. On TPU the limb dtypes would be int8 (4x VMEM
-savings); interpret-mode CPU carries them as int32 with int8 values, which
-is numerically identical.
+default to 128-multiples. The limbs enter the kernel as int8, the MXU's
+integer operand type (Mosaic has no int32 x int32 matmul); balanced limbs
+fit int8 by construction, and so does the Karatsuba middle operand
+(hi + lo, w = 7).
 
 Grid semantics (DESIGN.md §8): M and N are `parallel` output-tile axes, K
 is the carried reduction (`arbitrary`). The three partial-product
@@ -47,7 +48,12 @@ def _block_products(ah_ref, al_ref, bh_ref, bl_ref, *, karatsuba: bool):
     ll = dot(al, bl)
     if karatsuba:
         # 3rd and final pass: (hi+lo) x (hi+lo) - hh - ll == the cross term.
-        mid = dot(ah + al, bh + bl) - hh - ll
+        # The sum is formed in int32 (Mosaic has no int8 vector add) and
+        # narrowed back: it fits int8 for w = 7 limbs.
+        i32, i8 = jnp.int32, jnp.int8
+        a_sum = (ah.astype(i32) + al.astype(i32)).astype(i8)
+        b_sum = (bh.astype(i32) + bl.astype(i32)).astype(i8)
+        mid = dot(a_sum, b_sum) - hh - ll
     else:
         mid = dot(ah, bl) + dot(al, bh)
     return hh, mid, ll
@@ -131,8 +137,8 @@ def karatsuba_matmul_kernel(
             ("parallel", "parallel", "arbitrary"), interpret),
         interpret=interpret,
     )(
-        a_hi.astype(jnp.int32),
-        a_lo.astype(jnp.int32),
-        b_hi.astype(jnp.int32),
-        b_lo.astype(jnp.int32),
+        a_hi.astype(jnp.int8),
+        a_lo.astype(jnp.int8),
+        b_hi.astype(jnp.int8),
+        b_lo.astype(jnp.int8),
     )

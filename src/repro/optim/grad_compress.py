@@ -23,12 +23,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import Array
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                   # jax >= 0.8
-    from jax import shard_map
-except ImportError:                    # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def _quantize(g: Array) -> tuple[Array, Array]:

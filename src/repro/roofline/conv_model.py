@@ -28,20 +28,25 @@ small batches the fixed per-call cost dominates the tap work entirely
 (measured on CPU interpret: a (2, 64, 64) gaussian5 runs *direct* fastest
 -- one launch beats two cheaper passes -- while from (8, 64, 64) up the
 two-pass dataflow wins), so a model without it mis-ranks every small
-shape. Absolute constants come from per-backend presets (`hw_for` /
-`launch_overhead_for`); the autotuner calibrates them against its own
+shape. Absolute constants come from presets keyed by device (`hw_for` /
+`launch_overhead_for`): 'cpu' for the Pallas interpreter, else the
+compiled device's `device_kind`; a compiled device without a preset is an
+error, never a silent default. The autotuner calibrates them against its own
 measurements (the efficiency scale in `repro.tuning.autotune.sweep_plan`),
 so only the *relative* weighting must be roughly right per backend:
 interpret-mode CPU is op-dispatch-bound (bytes are nearly free next to
 per-element dispatch, so candidates rank by op counts plus launch floors,
 and the two-pass HBM round-trip is cheap), while the TPU preset keeps the
-assignment-given v5e terms where the round-trip is exactly what fusion
-buys back and launches are microseconds.
+published v5e terms where the round-trip is exactly what fusion buys back
+and launches are microseconds.
 """
 from __future__ import annotations
 
 import dataclasses
 
+import jax
+
+from repro.core.platform import default_interpret
 from repro.roofline.analysis import HW
 
 #: conservative flop expansion of one digit-plane-flattened REFMLM
@@ -50,34 +55,52 @@ from repro.roofline.analysis import HW
 #: that so a 'recurse' bound never overshoots a real 'recurse' time.
 RECURSE_FLOP_FACTOR = 32.0
 
-#: per-backend roofline constants. 'cpu' models the interpret-mode
-#: executor: `peak_flops` is the *effective* per-element op throughput of
-#: interpreted Pallas (~1.4 ns/op, measured), far below any hardware peak,
-#: and the byte term is scaled to be nearly free -- candidates rank by op
-#: counts plus launch floors. Any other backend falls back to the TPU v5e
-#: terms of `analysis.HW`.
+#: roofline constants by device key (`device_key`). 'cpu' models the
+#: interpret-mode executor: `peak_flops` is the *effective* per-element op
+#: throughput of interpreted Pallas (~1.4 ns/op, measured), far below any
+#: hardware peak, and the byte term is scaled to be nearly free --
+#: candidates rank by op counts plus launch floors. Compiled devices are
+#: keyed by `device_kind`: 'TPU v5 lite' is TPU v5e -- 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links (Google Cloud
+#: documentation, "TPU v5e"), the `analysis.HW` defaults.
 HW_PRESETS: dict[str, HW] = {
     "cpu": HW(peak_flops=7e8, hbm_bw=2e12, ici_bw=50e9),
-    "tpu": HW(),
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
 }
 
-#: per-backend fixed cost of one kernel launch, by kernel flavor, in
+#: fixed cost of one kernel launch, by device key and kernel flavor, in
 #: seconds. The interpret-mode numbers are deliberately conservative
 #: (below the measured per-call floors) but keep the measured ordering:
 #: a 1-D or 2-D direct pass dispatches one plain accumulate loop, the
 #: fused kernel's band concatenations and dual tap stages cost ~3x that.
 LAUNCH_OVERHEAD_S: dict[str, dict[str, float]] = {
     "cpu": {"pass_1d": 100e-6, "pass_2d": 100e-6, "fused": 300e-6},
-    "tpu": {"pass_1d": 2e-6, "pass_2d": 2e-6, "fused": 2e-6},
+    "TPU v5 lite": {"pass_1d": 2e-6, "pass_2d": 2e-6, "fused": 2e-6},
 }
 
 
-def hw_for(backend: str | None) -> HW:
-    return HW_PRESETS.get(backend or "", HW_PRESETS["tpu"])
+def device_key() -> str:
+    """Preset key of the running backend: 'cpu' when Pallas interprets,
+    else the first device's `device_kind`."""
+    return "cpu" if default_interpret() else jax.devices()[0].device_kind
 
 
-def launch_overhead_for(backend: str | None) -> dict[str, float]:
-    return LAUNCH_OVERHEAD_S.get(backend or "", LAUNCH_OVERHEAD_S["tpu"])
+def _preset(table: dict, key: str | None):
+    key = key or device_key()
+    if key not in table:
+        raise ValueError(f"no conv roofline preset for device {key!r}; "
+                         f"known: {sorted(table)}")
+    return table[key]
+
+
+def hw_for(key: str | None = None) -> HW:
+    """Roofline peaks of device `key` (None = the running device)."""
+    return _preset(HW_PRESETS, key)
+
+
+def launch_overhead_for(key: str | None = None) -> dict[str, float]:
+    """Per-launch floors of device `key` (None = the running device)."""
+    return _preset(LAUNCH_OVERHEAD_S, key)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,6 +156,7 @@ def plan_cost(
 ) -> ConvCost:
     """Roofline lower bound of one `PlanConfig` point (DESIGN.md §11).
 
+    `backend` is a preset key (`device_key`; None = the running device).
     `block_cols=None` means a full-width tile. The fold transform is
     modeled faithfully: a folded batch becomes one (1, N*(H+2*ph), W)
     image whose embedded halo rows are also computed (and cropped), an
@@ -194,4 +218,5 @@ def plan_cost(
 
 
 __all__ = ["HW_PRESETS", "LAUNCH_OVERHEAD_S", "RECURSE_FLOP_FACTOR",
-           "ConvCost", "hw_for", "launch_overhead_for", "plan_cost"]
+           "ConvCost", "device_key", "hw_for", "launch_overhead_for",
+           "plan_cost"]

@@ -64,9 +64,10 @@ batch's request sequence numbers -- the hooks the §12/§13 tests and
 Pool integration (DESIGN.md §13): `name` tags the executor's probe keys
 so chaos rules can target one pool member; `devices` additionally accepts
 an explicit device-id tuple (the elastic pool's device-subset meshes,
-`repro.distribute.mesh.filter_mesh`); and `on_dispatch(key, mode, ok)`
-reports every dispatch outcome to the owning `ExecutorPool`'s health
-tracker.
+`repro.distribute.mesh.filter_mesh`), and a local dispatch then runs on
+the first device of that tuple, so one-chip pool members each use their
+own chip; and `on_dispatch(key, mode, ok)` reports every dispatch outcome
+to the owning `ExecutorPool`'s health tracker.
 
 Telemetry (DESIGN.md §15): the ledger counters live in a
 `repro.obs.MetricsRegistry` (labelled `member=` so pool members share
@@ -81,13 +82,16 @@ predicted-vs-observed drift histogram. All three default off/no-op.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict
 from typing import Callable, Sequence
 
+import jax
 import numpy as np
 
+from repro.distribute.mesh import devices_by_id
 from repro.filters.pipeline import resolve_filter_plan
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NOOP
@@ -124,6 +128,10 @@ class BatchExecutor:
         self.pad_pow2 = pad_pow2
         self.devices = (tuple(devices) if isinstance(devices, (list, tuple))
                         else devices)
+        # where exec='local' dispatches run: a pool member's own first
+        # device (§13), else the process default
+        self._local_device = (devices_by_id(self.devices[:1])[0]
+                              if isinstance(self.devices, tuple) else None)
         self.tile = tuple(tile)
         self.tile_batch = int(tile_batch)
         self.degrade_after = max(int(degrade_after), 1)
@@ -212,7 +220,8 @@ class BatchExecutor:
                 return plan
             self._c_plan_misses.inc(member=self.name)
         cfg = resolve_filter_plan(filt, n, h, w, method=method,
-                                  mult_impl=mult_impl)
+                                  mult_impl=mult_impl,
+                                  interpret=self.interpret)
         plan = {"separable": cfg.dataflow != "direct",
                 "fused": cfg.dataflow == "fused",
                 "mult_impl": cfg.mult_impl,
@@ -260,6 +269,12 @@ class BatchExecutor:
         return f"{mode}/{r0.workload}"
 
     # ------------------------------------------------------------- execution
+    def _placed(self, mode: str):
+        """Context that runs a local dispatch on this member's device."""
+        if mode == "local" and self._local_device is not None:
+            return jax.default_device(self._local_device)
+        return contextlib.nullcontext()
+
     def execute(self, key: str, requests: tuple[FilterRequest, ...], *,
                 exec_override: str | None = None) -> list[np.ndarray]:
         """One dispatch of a coalesced bucket slice, no retry; returns one
@@ -293,11 +308,12 @@ class BatchExecutor:
                                   skey=skey, exec=mode, n=n,
                                   traced_n=traced_n, plan=plan,
                                   member=self.name, workload=r0.workload)
-        if prof is None:
-            return wl.execute(self, requests, traced_n, mode)
-        predicted = prof.predicted(wl, key, r0, traced_n)
-        t0 = time.perf_counter()
-        outs = wl.execute(self, requests, traced_n, mode)
+        with self._placed(mode):
+            if prof is None:
+                return wl.execute(self, requests, traced_n, mode)
+            predicted = prof.predicted(wl, key, r0, traced_n)
+            t0 = time.perf_counter()
+            outs = wl.execute(self, requests, traced_n, mode)
         prof.record(key, plan, predicted, time.perf_counter() - t0)
         return outs
 
@@ -436,9 +452,10 @@ class BatchExecutor:
         traced_n = next_pow2(n) if self.pad_pow2 else n
         key = bucket_key(filt, method, mult_impl, exec_mode, nbits, h, w,
                          priority, workload)
-        self.workloads[workload].warm(
-            self, (h, w), filt, method=method, mult_impl=mult_impl,
-            exec_mode=exec_mode, nbits=nbits, traced_n=traced_n)
+        with self._placed(exec_mode):
+            self.workloads[workload].warm(
+                self, (h, w), filt, method=method, mult_impl=mult_impl,
+                exec_mode=exec_mode, nbits=nbits, traced_n=traced_n)
         skey = serve_key(key, traced_n)
         with self._lock:
             self.warmed.add(skey)

@@ -15,7 +15,10 @@ instead of on a user.
 
 A running server exposes the same sweep as `ImageFilterServer.warmup()`;
 this CLI is the deploy-time entry point (run it before admitting
-traffic, like `repro.tuning.autotune` is run before benchmarking).
+traffic, like `repro.tuning.autotune` is run before benchmarking). The
+CLI keeps JAX's persistent compile cache on
+(`repro.core.platform.enable_compile_cache`), so the executables it
+compiles outlive the process for the server started after it.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ import argparse
 import itertools
 import time
 
+from repro.core.platform import enable_compile_cache
 from repro.filters.bank import FILTER_NAMES
 from repro.serve.executor import BatchExecutor
 
@@ -79,6 +83,7 @@ def main(argv=None) -> int:
     ap.add_argument("--batches", default="1,8",
                     help="comma-separated traced batch sizes")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     keys = warm(parse_shapes(args.shapes),
                 args.filters.split(","), args.methods.split(","),
                 args.mult_impls.split(","), args.execs.split(","),

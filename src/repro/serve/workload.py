@@ -117,7 +117,6 @@ class FilterWorkload(Workload):
         """Roofline lower bound of the bucket's resolved §11 plan."""
         from repro.filters.pipeline import resolve_filter_plan
         from repro.roofline.conv_model import plan_cost
-        from repro.tuning.cache import backend_key
         h, w = req.img.shape
         spec = get_filter(req.filt)
         plan = resolve_filter_plan(spec, n, h, w, method=req.method,
@@ -128,7 +127,7 @@ class FilterWorkload(Workload):
                          block_rows=plan.block_rows,
                          block_cols=plan.block_cols,
                          batch_fold=bool(plan.batch_fold),
-                         backend=backend or backend_key())
+                         backend=backend)
         return cost.lower_bound_s
 
 

@@ -252,7 +252,7 @@ def plan_bound_us(plan: PlanConfig, name: str, n: int, h: int, w: int,
     cost = plan_cost(plan.dataflow, plan.mult_impl, n, h, w, kh, kw,
                      block_rows=plan.block_rows, block_cols=plan.block_cols,
                      batch_fold=bool(plan.batch_fold),
-                     backend=backend or backend_key())
+                     backend=backend)
     return cost.lower_bound_s * 1e6
 
 

@@ -185,6 +185,7 @@ def resolve_blocks(
     block_rows: int | None = None,
     block_cols: int | None = None,
     batch_fold: bool | None = None,
+    interpret: bool | None = None,
 ) -> BlockConfig:
     """Tuned-cache lookup with explicit-override and heuristic fallback.
 
@@ -196,7 +197,9 @@ def resolve_blocks(
     miss) the `default_blocks` heuristic fills the gaps, with the fold
     decision pinned to the caller's. `block_cols` has no "explicitly full
     width" spelling -- pass `block_cols=w` (a tile as wide as the image
-    disables column tiling).
+    disables column tiling). `interpret` (None = autodetect) tells the
+    heuristic whether the pass compiles, which caps its band height by
+    the scoped-VMEM budget.
     """
     if None not in (block_rows, block_cols, batch_fold):
         # fully explicit call: nothing to look up (the serve hot path, which
@@ -214,7 +217,8 @@ def resolve_blocks(
                      or bool(batch_fold) == cached.batch_fold)):
             base = cached
     if base is None:
-        base = default_blocks(kind, n, h, w, kh, kw, batch_fold=batch_fold)
+        base = default_blocks(kind, n, h, w, kh, kw, batch_fold=batch_fold,
+                              interpret=interpret)
     return BlockConfig(
         base.block_rows if block_rows is None else int(block_rows),
         base.block_cols if block_cols is None else int(block_cols),
@@ -224,7 +228,8 @@ def resolve_blocks(
 
 @lru_cache(maxsize=None)
 def resolve_blocks_cached(kind: str, n: int, h: int, w: int, kh: int,
-                          kw: int, mult_impl: str) -> BlockConfig:
+                          kw: int, mult_impl: str,
+                          interpret: bool | None = None) -> BlockConfig:
     """Memoised default-field `resolve_blocks` for steady-state dispatch.
 
     The serving layer (and any other hot loop re-resolving the same
@@ -235,7 +240,8 @@ def resolve_blocks_cached(kind: str, n: int, h: int, w: int, kh: int,
     per-call overrides have no business here -- they bypass the cache
     entirely via `resolve_blocks`' fully-explicit fast path.
     """
-    return resolve_blocks(kind, n, h, w, kh, kw, mult_impl)
+    return resolve_blocks(kind, n, h, w, kh, kw, mult_impl,
+                          interpret=interpret)
 
 
 __all__ = ["CACHE_VERSION", "backend_key", "cache_generation", "cache_path",

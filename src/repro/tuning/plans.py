@@ -34,6 +34,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+from repro.core.platform import resolve_interpret
 from repro.tuning.blocks import min_block_cols, min_block_rows, round_up
 from repro.tuning.cache import load_plans
 
@@ -142,6 +143,7 @@ def resolve_plan(
     block_rows: int | None = None,
     block_cols: int | None = None,
     batch_fold: bool | None = None,
+    interpret: bool | None = None,
 ) -> PlanConfig:
     """The single plan lookup path: explicit > cached > pre-plan defaults.
 
@@ -159,6 +161,9 @@ def resolve_plan(
         fixed default (fused when the spec separates, else direct),
         mult_impl to the pass-level 'auto', block fields to the §8 block
         cache/heuristic inside the conv passes.
+
+    A cached 'kcm' plan is dropped when the passes compile (`interpret`
+    resolves False): Mosaic cannot lower the KCM gather.
     """
     allowed = allowed_dataflows(separable_ok, separable, fused)
     if (len(allowed) == 1 and mult_impl != "auto"
@@ -174,6 +179,9 @@ def resolve_plan(
         if cand is not None:
             cand = sanitize_plan(cand, n, h, w, kh, kw)
         if cand is not None and cand.dataflow not in allowed:
+            cand = None
+        if (cand is not None and cand.mult_impl == "kcm"
+                and not resolve_interpret(interpret)):
             cand = None
         if cand is not None:
             if mult_impl != "auto" and cand.mult_impl != mult_impl:

@@ -204,3 +204,29 @@ class TestFusedSeparable:
                                        block_cols=bc, batch_fold=fold, **kw)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(base),
                                           err_msg=f"br={br} bc={bc} fold={fold}")
+
+
+class TestCompiledResolution:
+    """Mosaic lowers only 2-D gathers, so compiled passes never take the
+    KCM ROM lookup: 'auto' resolves to the bit-identical recursion, and an
+    explicit 'kcm' fails with a clear error before any compile."""
+
+    TAPS = np.asarray(get_filter("gaussian3").taps)
+
+    @pytest.mark.parametrize("interpret,want", [(True, "kcm"),
+                                                (False, "recurse")])
+    def test_auto_follows_the_interpret_flag(self, interpret, want):
+        from repro.filters.conv import _resolve_mult_impl
+        assert _resolve_mult_impl("auto", self.TAPS,
+                                  interpret=interpret) == want
+
+    def test_explicit_kcm_on_compiled_pass_raises(self):
+        with pytest.raises(ValueError, match="Mosaic cannot lower"):
+            conv2d_pass(BATCH, self.TAPS, mult_impl="kcm", interpret=False)
+        spec = get_filter("gaussian5")
+        with pytest.raises(ValueError, match="Mosaic cannot lower"):
+            fused_separable_pass(BATCH, spec.sep_row, spec.sep_col,
+                                 mult_impl="kcm", interpret=False)
+        with pytest.raises(ValueError, match="Mosaic cannot lower"):
+            apply_filter(BATCH, "gaussian5", mult_impl="kcm",
+                         interpret=False)

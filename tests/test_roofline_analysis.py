@@ -206,8 +206,29 @@ class TestConvModel:
             _cost("direct", impl="booth")
 
     def test_backend_fallback_is_tpu(self):
-        assert hw_for("gpu") == hw_for("tpu")
-        assert launch_overhead_for(None) == launch_overhead_for("tpu")
+        # compiled presets are keyed by device_kind; a device without one
+        # is an error, never a silent TPU default
+        assert hw_for("TPU v5 lite").peak_flops == 197e12
+        assert hw_for("TPU v5 lite").hbm_bw == 819e9
+        for key in ("gpu", "tpu", "TPU v4"):
+            with pytest.raises(ValueError, match="no conv roofline preset"):
+                hw_for(key)
+            with pytest.raises(ValueError, match="no conv roofline preset"):
+                launch_overhead_for(key)
+        # under the interpreter the running device's key is 'cpu'
+        assert launch_overhead_for(None) == launch_overhead_for("cpu")
+        assert hw_for() == hw_for("cpu")
+
+    def test_presets_keyed_by_device_kind(self):
+        from repro.roofline.conv_model import (HW_PRESETS, LAUNCH_OVERHEAD_S,
+                                               device_key)
+        assert set(HW_PRESETS) == set(LAUNCH_OVERHEAD_S) == {"cpu",
+                                                             "TPU v5 lite"}
+        assert device_key() == "cpu"          # the interpreter's preset
+        tpu = _cost("fused", backend="TPU v5 lite")
+        assert tpu.lower_bound_s < _cost("fused").lower_bound_s
+        with pytest.raises(ValueError):
+            _cost("fused", backend="TPU v9 imaginary")
 
     def test_fold_models_embedded_halos(self):
         unfolded = _cost("direct", n=8, h=64, w=64, br=64, fold=False)

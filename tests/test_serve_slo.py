@@ -13,6 +13,7 @@ single CPU device with the §12 deterministic injector driving failures.
 """
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -426,6 +427,33 @@ class TestExecutorPool:
         assert st["pool"]["members"]["m0"]["state"] == "active"
         assert st["pool"]["drain_refused"] >= 1
         assert st["state"] == "degraded"      # pinned fallback, by design
+
+    def test_local_member_dispatch_runs_on_its_own_device(self, monkeypatch):
+        """A pool member's local dispatches (and its warmup) run under its
+        own first device, not the process default -- one-chip members
+        each use their own chip."""
+        from repro.serve.workload import FilterWorkload
+        seen = []
+        real = FilterWorkload.execute
+
+        def spy(self, executor, *a, **k):
+            seen.append(jax.config.jax_default_device)
+            return real(self, executor, *a, **k)
+
+        monkeypatch.setattr(FilterWorkload, "execute", spy)
+        dev = jax.devices()[0]
+        cfg = ServerConfig(max_batch=2, max_delay_ms=2.0, pool=((dev.id,),))
+        with ImageFilterServer(cfg) as srv:
+            out = srv.submit(image(5), "gaussian3").result(60)
+        np.testing.assert_array_equal(
+            out, np.asarray(apply_filter(image(5), "gaussian3")))
+        assert seen == [dev]
+        seen.clear()
+        # no device subset: the process default
+        with ImageFilterServer(ServerConfig(max_batch=2,
+                                            max_delay_ms=2.0)) as srv:
+            srv.submit(image(5), "gaussian3").result(60)
+        assert seen == [None]
 
     def test_pool_warmup_routes_to_the_serving_member(self):
         cfg = ServerConfig(max_batch=4, max_delay_ms=5.0, pool=((0,), (0,)))

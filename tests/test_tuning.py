@@ -26,7 +26,7 @@ from repro.tuning.autotune import (
     sweep_plan,
     tune,
 )
-from repro.tuning.blocks import round_up
+from repro.tuning.blocks import VMEM_BUDGET_BYTES, round_up, vmem_step_bytes
 from repro.tuning.cache import backend_key, cache_path
 
 
@@ -71,6 +71,57 @@ class TestHeuristic:
     def test_fused_halo_floor(self):
         cfg = default_blocks("fused", 2, 8, 64, 5, 5)
         assert cfg.block_rows >= 2 * (5 // 2)
+
+
+class TestVmemBudget:
+    """Compiled passes cap the band height by the per-step scoped-VMEM
+    budget; interpreted passes keep the uncapped heuristic."""
+
+    SHAPES = [("direct", 8, 128, 128, 3, 3), ("fused", 8, 128, 128, 5, 5),
+              ("direct", 8, 480, 640, 5, 5), ("fused", 1, 1080, 1920, 5, 5),
+              ("fused", 8, 364, 328, 3, 3), ("direct", 1, 512, 512, 1, 5),
+              ("direct", 4, 256, 512, 5, 1), ("fused", 16, 64, 64, 5, 5)]
+
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_compiled_step_fits_budget(self, shape):
+        kind, n, h, w, kh, kw = shape
+        cfg = default_blocks(kind, n, h, w, kh, kw, interpret=False)
+        assert cfg.block_rows % 8 == 0 and cfg.block_rows >= 8
+        assert vmem_step_bytes(kind, cfg.block_rows, w, kh, kw,
+                               cfg.block_cols) <= VMEM_BUDGET_BYTES
+
+    def test_interpret_shapes_unchanged(self):
+        # the CPU-interpret heuristic: 8 * 130 folded rows in two bands
+        assert default_blocks("direct", 8, 128, 128, 3, 3,
+                              interpret=True) == BlockConfig(520, None, True)
+        assert default_blocks("direct", 8, 128, 128, 3, 3) == \
+            default_blocks("direct", 8, 128, 128, 3, 3, interpret=True)
+        assert default_blocks("direct", 1, 512, 512, 3, 3,
+                              interpret=True).block_rows == 128
+
+    def test_folded_batch_splits_into_more_bands_when_compiled(self):
+        # the served 8 x 128 x 128 3x3 batch that overflowed v5e's 16 MiB
+        cfg = default_blocks("direct", 8, 128, 128, 3, 3, interpret=False)
+        assert cfg.batch_fold and cfg.block_cols is None
+        assert cfg.block_rows < 520
+        tall = 8 * (128 + 2)
+        assert cfg.block_rows * -(-tall // cfg.block_rows) >= tall
+
+    def test_unfolded_band_prefers_divisors_under_the_cap(self):
+        cfg = default_blocks("fused", 1, 1024, 128, 5, 5, interpret=False)
+        assert 1024 % cfg.block_rows == 0
+        assert choose_block_rows(1024, 40) == 32
+        assert choose_block_rows(1000, 4) == 8       # floor stays at 8
+
+    def test_step_bytes_model(self):
+        one = vmem_step_bytes("direct", 8, 128, 3, 3, None)
+        assert vmem_step_bytes("direct", 16, 128, 3, 3, None) == 2 * one
+        # column tiling doubles the views but narrows each to the tile
+        tiled = vmem_step_bytes("direct", 8, 640, 3, 3, 256)
+        full = vmem_step_bytes("direct", 8, 640, 3, 3, None)
+        assert tiled < full
+        # the fused kernel's in-kernel temporaries dominate
+        assert vmem_step_bytes("fused", 8, 128, 5, 5, None) > one
 
 
 class TestCandidates:
@@ -253,6 +304,27 @@ class TestResolvePlan:
         got = self._resolve(fused=True, mult_impl="recurse", block_rows=16,
                             block_cols=32, batch_fold=False)
         assert got == PlanConfig("fused", "recurse", 16, 32, False)
+
+    def test_compiled_pass_drops_cached_kcm_plan(self, tmp_cache):
+        """Mosaic cannot lower the KCM gather: a cached 'kcm' plan is
+        unusable when the passes compile, so the lookup falls back to the
+        pre-plan defaults instead of pinning it."""
+        store_cache({}, {self.KEY: PLAN_ENTRY})
+        assert self._resolve(interpret=True).mult_impl == "kcm"
+        assert self._resolve(interpret=False) == PlanConfig(
+            "fused", "auto", None, None, None)
+
+    def test_compiled_filter_plan_pins_recurse(self, tmp_cache):
+        from repro.filters import resolve_filter_plan
+        store_cache({}, {self.KEY: PLAN_ENTRY})
+        plan = resolve_filter_plan("gaussian5", self.N, self.H, self.W,
+                                   interpret=False)
+        assert plan.mult_impl == "recurse"
+        assert plan == PlanConfig("fused", "recurse", *default_blocks(
+            "fused", self.N, self.H, self.W, 5, 5, interpret=False)._replace(
+                block_cols=self.W))
+        assert resolve_filter_plan("gaussian5", self.N, self.H, self.W,
+                                   interpret=True).mult_impl == "kcm"
 
 
 class TestPlanSweep:
