@@ -53,11 +53,9 @@ direct `apply_filter` call, and a pool member whose scale-out mesh is
 killed must drain to the survivor with zero client-visible failures.
 
 The observability scenario (DESIGN.md §15) prices the telemetry layer:
-the same coalesced load with tracing+profiling off vs on into the
-`serve_obs_off` / `serve_obs_on` rows, the `serve_obs_overhead` ratio
-(the <5% budget), and `serve_obs_drift` -- the mean observed-vs-roofline
-dispatch drift from the traced run's per-(bucket, plan) profile table.
-``--smoke-obs`` is the `scripts/check.sh --smoke-obs` guard: overhead
+the same coalesced load with tracing off vs on into the
+`serve_obs_off` / `serve_obs_on` rows and the `serve_obs_overhead` ratio
+(the <5% budget). ``--smoke-obs`` is the `scripts/check.sh --smoke-obs` guard: overhead
 inside the budget, a 50-request mixed-priority load leaving a complete
 well-formed trace (one terminal per request, monotone stages), stable
 snapshot schema keys, and bit-identical served bytes with tracing on.
@@ -641,10 +639,9 @@ OBS_MIX = (((256, 256), "gaussian5"),
 def bench_obs(*, clients: int = 4, per_client: int = 16, mix=OBS_MIX,
               max_batch: int = 8, max_delay_ms: float = 2.0,
               tag: str = "serve_obs_", best_of: int = 3) -> dict:
-    """The §15 observability price: the same coalesced load with tracing +
-    profiling off vs on (best-of-`best_of` to damp scheduler noise), the
-    `serve_obs_overhead` ratio row, and the roofline drift summary from
-    the traced run's per-(bucket, plan) profile table."""
+    """The §15 observability price: the same coalesced load with tracing
+    off vs on (best-of-`best_of` to damp scheduler noise) and the
+    `serve_obs_overhead` ratio row."""
     runs = {}
     for label, obs in (("off", False), ("on", True)):
         best = None
@@ -661,19 +658,12 @@ def bench_obs(*, clients: int = 4, per_client: int = 16, mix=OBS_MIX,
     emit(f"{tag}overhead", runs["off"]["mpix_s"] / runs["on"]["mpix_s"],
          "x_off_vs_on_mpix_s", spans=tr["spans"],
          events=sum(tr["events"].values()))
-    prof = runs["on"]["stats"].get("profile", {})
-    drifts = sorted(row["drift_mean"] for row in prof.values()
-                    if row.get("drift_mean"))
-    if drifts:
-        emit(f"{tag}drift", float(np.mean(drifts)),
-             "x_observed_vs_roofline_mean", rows=len(prof),
-             drift_min=round(drifts[0], 3), drift_max=round(drifts[-1], 3))
     return runs
 
 
 def smoke_obs(threshold: float = 1.05, attempts: int = 3) -> int:
     """Reduced-size §15 observability guards (scripts/check.sh
-    --smoke-obs): tracing+profiling costs < 5% coalesced throughput
+    --smoke-obs): tracing costs < 5% coalesced throughput
     (best-of pairs, retried to damp noise); a 50-request mixed-priority
     mixed-tenant load leaves a complete well-formed trace (exactly one
     terminal per submitted request, stage timestamps monotone); the
@@ -727,7 +717,7 @@ def smoke_obs(threshold: float = 1.05, attempts: int = 3) -> int:
     stats_keys = {"submitted", "served", "failed", "shed", "shed_overload",
                   "pending", "rejected", "tenants", "batches", "occupancy",
                   "flush_reasons", "served_priority", "compile", "plan_memo",
-                  "profile", "healthy", "state"}
+                  "transfer", "healthy", "state"}
     snap_keys = {"counters", "gauges", "histograms", "series",
                  "dropped_series"}
     schema_ok = stats_keys <= set(stats) and snap_keys == set(msnap)

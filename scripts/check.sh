@@ -62,7 +62,7 @@
 # bytes equal to the direct forward call.
 #
 # The observability smoke (--smoke-obs, serve_bench.py --smoke-obs) is the
-# DESIGN.md §15 guard: tracing + profiling must cost under 5% of coalesced
+# DESIGN.md §15 guard: tracing must cost under 5% of coalesced
 # throughput on realistic frames, a 50-request mixed-priority load must
 # leave a complete well-formed trace (exactly one fulfil/shed/fail
 # terminal per submitted request, stage timestamps monotone), the

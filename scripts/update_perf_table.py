@@ -57,7 +57,7 @@ ROWS = [
     ("serve_slo_adaptive",
      "overloaded serving, mixed priorities, **SLO-adaptive batching + priority shedding** (§13)"),
     ("serve_obs_on",
-     "online serving, coalesced, **tracing + roofline profiling on** (§15; realistic-frame mix)"),
+     "online serving, coalesced, **tracing on** (§15; realistic-frame mix)"),
     ("infer_cnn_int8",
      "CNN inference (8×8, n=32), **exact-quantized int8 oracle** (§14; µs = batched forward)"),
     ("infer_cnn_refmlm",

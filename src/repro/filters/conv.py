@@ -273,6 +273,7 @@ def _pass_call(imgs: Array, coef: Array, coef_spec, kernel, *, kh: int,
         kernel,
         out_shape=jax.ShapeDtypeStruct((n, h2, w2), jnp.int32),
         grid=grid,
+        name="refmlm_conv_pass",
         in_specs=[coef_spec, *specs],
         out_specs=pl.BlockSpec((1, br, bc), lambda nn, i, j: (nn, i, j)),
         compiler_params=grid_compiler_params(
@@ -456,6 +457,7 @@ def _fused_call(imgs: Array, row, col, row_spec, col_spec, kernel, *,
         kernel,
         out_shape=jax.ShapeDtypeStruct((n, h2, w2), jnp.int32),
         grid=(n, nb, ncb),
+        name="refmlm_conv_fused",
         in_specs=[row_spec, col_spec, *view_specs],
         out_specs=pl.BlockSpec((1, br, bc), lambda nn, i, j: (nn, i, j)),
         compiler_params=grid_compiler_params(

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import Array
@@ -37,7 +38,11 @@ from repro.filters.conv import (
     fused_separable_pass,
     second_pass_nbits,
 )
+from repro.obs import trace as obs_trace
 from repro.tuning.plans import PlanConfig, resolve_plan
+
+#: the dtype `apply_filter_batch` stacks a batch in for the device.
+BATCH_DTYPE = np.int32
 
 
 def _normalize(imgs: Array) -> tuple[Array, tuple[int, ...]]:
@@ -327,6 +332,17 @@ def apply_filter_batch(
     single-image `apply_filter` call -- the §8 batch fold embeds every
     image's own zero halo, so batch neighbors (and zero pads) can never
     leak into a request's pixels (asserted in tests/test_serve.py).
+
+    The steps are the serve worker's phase spans (`repro.obs.trace`,
+    recorded only while a recorder is active): 'prep' (stack, cast to
+    `BATCH_DTYPE`, pad), 'upload' (`jax.device_put` to the default
+    device, which the executor sets to its pool member's), 'launch' (the
+    `apply_filter` dispatch), 'device_wait' (`block_until_ready`) and
+    'fetch' (the copy back and the split). The scale-out modes place
+    their own inputs, so they have no 'upload' or 'device_wait'. Only
+    while tracing does the upload block until the batch is on the
+    device, which splits the copy from the device run; otherwise it
+    stays asynchronous.
     """
     if not imgs:
         return []
@@ -338,12 +354,25 @@ def apply_filter_batch(
     if len(shape) != 2:
         raise ValueError(f"expected (H, W) images, got shape {shape}")
     n = len(imgs)
-    batch = np.stack([np.asarray(im) for im in imgs]).astype(np.int32)
-    if pad_to is not None and pad_to > n:
-        batch = np.concatenate(
-            [batch, np.zeros((pad_to - n, *shape), np.int32)])
-    out = np.asarray(apply_filter(batch, filt, **kw))
-    return [out[i] for i in range(n)]
+    with obs_trace.span("prep"):
+        batch = np.stack([np.asarray(im) for im in imgs]).astype(BATCH_DTYPE)
+        if pad_to is not None and pad_to > n:
+            batch = np.concatenate(
+                [batch, np.zeros((pad_to - n, *shape), BATCH_DTYPE)])
+    local = kw.get("exec", "local") == "local"
+    if local:
+        with obs_trace.span("upload"):
+            batch = jax.device_put(batch)
+            if obs_trace.tracing():
+                batch.block_until_ready()
+    with obs_trace.span("launch"):
+        out = apply_filter(batch, filt, **kw)
+    if local:
+        with obs_trace.span("device_wait"):
+            out.block_until_ready()
+    with obs_trace.span("fetch"):
+        out = np.asarray(out)
+        return [out[i] for i in range(n)]
 
 
 def filter_bank_apply(
@@ -359,6 +388,6 @@ def filter_bank_apply(
             for name in names}
 
 
-__all__ = ["EXEC_MODES", "apply_filter", "apply_filter_batch",
+__all__ = ["BATCH_DTYPE", "EXEC_MODES", "apply_filter", "apply_filter_batch",
            "filter_bank_apply", "resolve_filter_blocks",
            "resolve_filter_plan"]

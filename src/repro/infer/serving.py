@@ -125,11 +125,15 @@ class InferWorkload(Workload):
                 traced_n: int, exec_mode: str) -> list[np.ndarray]:
         r0 = requests[0]
         h, w = r0.img.shape
-        x = np.zeros((traced_n, h, w), dtype=np.float32)
-        for i, r in enumerate(requests):
-            x[i] = r.img
-        logits = np.asarray(self._fn(r0.filt, r0.method, r0.nbits)(x))
-        return [logits[i] for i in range(len(requests))]
+        with obs_trace.span("prep"):
+            x = np.zeros((traced_n, h, w), dtype=np.float32)
+            for i, r in enumerate(requests):
+                x[i] = r.img
+        with obs_trace.span("launch"):
+            logits = self._fn(r0.filt, r0.method, r0.nbits)(x)
+        with obs_trace.span("fetch"):
+            logits = np.asarray(logits)
+            return [logits[i] for i in range(len(requests))]
 
     def warm(self, executor, shape: tuple[int, int], target: str, *,
              method: str, mult_impl: str, exec_mode: str, nbits: int,

@@ -129,6 +129,7 @@ def karatsuba_matmul_kernel(
         kernel,
         out_shape=(acc, acc, acc),
         grid=grid,
+        name="karatsuba_matmul",
         in_specs=[a_spec, a_spec, b_spec, b_spec],
         out_specs=(o_spec, o_spec, o_spec),
         scratch_shapes=(

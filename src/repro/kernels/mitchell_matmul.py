@@ -142,6 +142,7 @@ def mitchell_matmul_kernel(
         kernel,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         grid=grid,
+        name="mitchell_matmul",
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),

@@ -1,6 +1,6 @@
 """`repro.obs` -- unified serving telemetry (DESIGN.md §15).
 
-Three cooperating pieces, all optional-by-default and zero-cost when off:
+Two cooperating pieces, zero-cost when off:
 
   metrics.py  -- `MetricsRegistry`: thread-safe bounded
                  counters/gauges/histograms with label sets. The serving
@@ -11,24 +11,25 @@ Three cooperating pieces, all optional-by-default and zero-cost when off:
                  under ONE lock -- a consistent snapshot by construction.
   trace.py    -- per-request spans (`submit -> admit -> enqueue -> flush
                  -> dispatch -> fulfil|shed|fail`) plus fault/shard/tile/
-                 infer events on the same stream; JSONL and Perfetto
-                 (Chrome trace-event) export; `NOOP` when off.
-  profile.py  -- `DispatchProfiler`: every dispatch timed against its
-                 roofline price (`Workload.model_bound`), drift histogram
-                 per (bucket, plan).
+                 infer events on the same stream; the serve worker's
+                 phase spans (`PHASES`: idle, collect, prep, upload,
+                 launch, device_wait, fetch, fulfil), each also a
+                 `jax.profiler.TraceAnnotation` on the device trace's
+                 clock; JSONL and Perfetto (Chrome trace-event) export;
+                 `NOOP` when off.
 
 Operator CLI: `python -m repro.obs.snapshot trace.jsonl [--chrome out]`.
 
-Wiring: `ServerConfig(trace=..., profile=True)` on `ImageFilterServer`
+Wiring: `ServerConfig(trace=...)` on `ImageFilterServer`
 (DESIGN.md §10/§15); standalone components accept `metrics=`/`trace=`
 and default to private registries / the no-op recorder.
 """
 from __future__ import annotations
 
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.profile import DispatchProfiler
 from repro.obs.trace import (
     NOOP,
+    PHASES,
     STAGES,
     TERMINALS,
     NoopRecorder,
@@ -36,16 +37,17 @@ from repro.obs.trace import (
     chrome_trace,
     emit,
     resolve_trace,
+    span,
     trace_scope,
     tracing,
 )
 
 __all__ = [
     "NOOP",
+    "PHASES",
     "STAGES",
     "TERMINALS",
     "Counter",
-    "DispatchProfiler",
     "Gauge",
     "Histogram",
     "MetricsRegistry",
@@ -54,6 +56,7 @@ __all__ = [
     "chrome_trace",
     "emit",
     "resolve_trace",
+    "span",
     "trace_scope",
     "tracing",
 ]

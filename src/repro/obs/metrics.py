@@ -30,8 +30,7 @@ Design points:
     counted in `dropped_series` instead of growing memory without limit
     (the plan-memo LRU lesson of DESIGN.md §13 applied to telemetry).
   * **histograms** -- fixed bucket bounds chosen at creation; `observe`
-    is O(buckets). The §15 drift histograms (`repro.obs.profile`) and
-    request-latency histograms live here.
+    is O(buckets). The serve request-latency histograms live here.
 
 The registry is plain bookkeeping on the caller's thread -- no I/O, no
 background thread -- so leaving it always-on costs what the old dict

@@ -7,10 +7,13 @@
 Reads a JSONL trace (`ServerConfig(trace="trace.jsonl")`, or
 `TraceRecorder.write_jsonl`) and prints the operator roll-up: event
 counts, span/terminal accounting (every submitted request must show
-exactly one fulfil/shed/fail), and per-bucket queue-wait and dispatch
-extents. `--chrome` additionally converts the trace to Chrome
-trace-event JSON -- open the output at https://ui.perfetto.dev to see
-one track per bucket with queued/dispatch slices per request. `--json`
+exactly one fulfil/shed/fail), per-bucket queue-wait and dispatch
+extents, and per-phase extents of the serve worker's leaf spans (idle,
+collect, prep, upload, launch, device_wait, fetch, fulfil). `--chrome`
+additionally converts the trace to Chrome trace-event JSON -- open the
+output at https://ui.perfetto.dev to see one track per bucket with
+queued/dispatch slices per request, and one track per worker thread and
+pool member with its phases as slices. `--json`
 dumps the machine-readable summary instead of the table.
 """
 from __future__ import annotations
@@ -41,7 +44,8 @@ def load_jsonl(path: str) -> list[dict]:
 
 
 def render(summary: dict) -> str:
-    """The human table: counts, terminal accounting, per-bucket extents."""
+    """The human table: counts, terminal accounting, per-bucket extents,
+    and the extents of the worker's phase spans."""
     lines = [f"{'event':<12} count"]
     for name, n in sorted(summary["events"].items()):
         lines.append(f"{name:<12} {n}")
@@ -63,11 +67,18 @@ def render(summary: dict) -> str:
         lines.append("")
         lines.append(f"{title} per bucket (ms):")
         for bucket, ext in sorted(rows.items()):
-            lines.append(f"  {bucket:<48} n={ext['n']:<4} "
-                         f"mean={ext['mean']*1e3:8.2f} "
-                         f"min={ext['min']*1e3:8.2f} "
-                         f"max={ext['max']*1e3:8.2f}")
+            lines.append(_extent_row(bucket, ext))
+    if summary.get("phase_s"):
+        lines.append("")
+        lines.append("phases (ms):")
+        for phase, ext in summary["phase_s"].items():
+            lines.append(_extent_row(phase, ext))
     return "\n".join(lines)
+
+
+def _extent_row(label: str, ext: dict) -> str:
+    return (f"  {label:<48} n={ext['n']:<4} mean={ext['mean']*1e3:8.2f} "
+            f"min={ext['min']*1e3:8.2f} max={ext['max']*1e3:8.2f}")
 
 
 def main(argv: list[str] | None = None) -> int:

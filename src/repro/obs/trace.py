@@ -27,10 +27,28 @@ Two recorders:
     bucket becomes a track of queued/dispatch slices).
 
 Sites that don't hold a recorder reference (the distribute shard/tile
-loops, the fault injector) publish through the module-level scope stack,
-mirroring `runtime.fault`'s `_ACTIVE` pattern: `ImageFilterServer` pushes
-its recorder for its lifetime, tests use `trace_scope(rec)`, and `emit()`
-is a no-op list check when nothing is active.
+loops, the fault injector, the filter pipeline's batch hook) publish
+through the module-level scope stack, mirroring `runtime.fault`'s
+`_ACTIVE` pattern: `ImageFilterServer` pushes its recorder for its
+lifetime, tests use `trace_scope(rec)`, and `emit()` / `span()` are a
+no-op list check when nothing is active.
+
+Phase spans: `TraceRecorder.span(name, **fields)` (and the module-level
+`span()`) time a block of the serve worker's own work. On exit it records
+one 'span' event: `ts` (start), `end`, `span` (the name), `parent` (the
+enclosing span's name on the same thread, or None), `thread`, and its
+fields, which include every field of the enclosing span -- so the leaves
+inside an executor dispatch carry its `batch`, `member`, `skey` and
+`warm`. The leaves are `PHASES`, in the order a batch passes them:
+
+    idle -> collect -> prep -> upload -> launch -> device_wait -> fetch
+    -> fulfil
+
+and they tile the worker thread's time. Each leaf also opens a
+`jax.profiler.TraceAnnotation` named `repro.serve.<phase>`, which puts it
+in a profiler trace on the device trace's clock, so every idle gap of the
+device lies under the host phase that held it up. The enclosing 'batch'
+and 'dispatch' spans stay in the recorder only.
 
 Invariants (tests/test_obs.py; `scripts/check.sh --smoke-obs`):
 every submitted request's span carries exactly one terminal event
@@ -43,8 +61,11 @@ from __future__ import annotations
 import json
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
+from types import MappingProxyType
 from typing import Iterator
+
+from jax.profiler import TraceAnnotation
 
 #: request life-cycle stages, in span order.
 STAGES = ("submit", "admit", "enqueue", "flush", "dispatch",
@@ -54,7 +75,18 @@ STAGES = ("submit", "admit", "enqueue", "flush", "dispatch",
 TERMINALS = ("fulfil", "shed", "fail")
 
 #: non-request event kinds sharing the stream (seq=None or contextual).
-AUX_EVENTS = ("reject", "fault", "shard", "tile", "infer")
+AUX_EVENTS = ("reject", "fault", "shard", "tile", "infer", "span")
+
+#: the serve worker's leaf phases, in the order a batch passes them; only
+#: these open a `TraceAnnotation` (`ANNOTATION_PREFIX + phase`).
+PHASES = ("idle", "collect", "prep", "upload", "launch", "device_wait",
+          "fetch", "fulfil")
+
+ANNOTATION_PREFIX = "repro.serve."
+
+#: what a span context yields when nothing records it.
+_NO_FIELDS = MappingProxyType({})
+_NULL_SPAN = nullcontext(_NO_FIELDS)
 
 #: in-memory event bound; overflow increments `dropped`, never grows.
 DEFAULT_MAX_EVENTS = 200_000
@@ -67,6 +99,9 @@ class NoopRecorder:
 
     def event(self, name: str, **fields) -> None:
         pass
+
+    def span(self, name: str, **fields):
+        return _NULL_SPAN
 
 
 #: the shared off-switch -- `ServerConfig(trace=None)` resolves to this.
@@ -87,6 +122,7 @@ class TraceRecorder:
         self.dropped = 0
         self._lock = threading.Lock()
         self._events: list[dict] = []
+        self._local = threading.local()     # this thread's open spans
         self._file = None
         if self.path is not None:
             self._file = open(self.path, "w", encoding="utf-8")
@@ -111,6 +147,18 @@ class TraceRecorder:
                     self._file.write(json.dumps(ev, default=str) + "\n")
                 except (OSError, ValueError):
                     pass
+
+    def span(self, name: str, **fields) -> "_Span":
+        """Context manager timing one block as a 'span' event (module
+        docstring). Entering yields the span's fields, its parent's
+        included (read-only use)."""
+        return _Span((self,), name, fields)
+
+    def _open_spans(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
     @classmethod
     def from_events(cls, events: list[dict]) -> "TraceRecorder":
@@ -146,8 +194,9 @@ class TraceRecorder:
         return out
 
     def summary(self) -> dict:
-        """Operator roll-up: event counts, terminal accounting, and
-        per-bucket queue-wait / dispatch-to-terminal extents (seconds)."""
+        """Operator roll-up: event counts, terminal accounting,
+        per-bucket queue-wait / dispatch-to-terminal extents, and per-phase
+        extents of the worker's leaf spans (seconds)."""
         evs = self.events()
         counts: dict[str, int] = {}
         for ev in evs:
@@ -169,10 +218,16 @@ class TraceRecorder:
             if "dispatch" in by and term is not None:
                 services.setdefault(bucket, []).append(
                     term["ts"] - by["dispatch"]["ts"])
+        phases: dict[str, list[float]] = {}
+        for ev in evs:
+            if ev["event"] == "span" and ev.get("span") in PHASES:
+                phases.setdefault(ev["span"], []).append(ev["end"] - ev["ts"])
         return {"events": counts, "spans": len(spans),
                 "terminals": terminals, "dropped": self.dropped,
                 "queue_wait_s": {k: _extent(v) for k, v in waits.items()},
-                "dispatch_s": {k: _extent(v) for k, v in services.items()}}
+                "dispatch_s": {k: _extent(v) for k, v in services.items()},
+                "phase_s": {p: _extent(phases[p]) for p in PHASES
+                            if p in phases}}
 
     # ------------------------------------------------------------- exporting
     def write_jsonl(self, path: str) -> int:
@@ -211,19 +266,61 @@ def _extent(vals: list[float]) -> dict:
             "mean": sum(vals) / len(vals)}
 
 
+class _Span:
+    """One span open in one or more recorders (`TraceRecorder.span`)."""
+
+    __slots__ = ("_recs", "_name", "_fields", "_ann")
+
+    def __init__(self, recs: tuple, name: str, fields: dict) -> None:
+        self._recs = recs
+        self._name = name
+        self._fields = fields
+        self._ann = None
+
+    def __enter__(self):
+        yielded = None
+        for rec in self._recs:
+            stack = rec._open_spans()
+            parent = stack[-1] if stack else None
+            fields = dict(parent[1]) if parent else {}
+            fields.update(self._fields)
+            stack.append((self._name, fields, rec.clock(),
+                          parent[0] if parent else None))
+            if yielded is None:
+                yielded = MappingProxyType(fields)
+        if self._name in PHASES:
+            self._ann = TraceAnnotation(ANNOTATION_PREFIX + self._name)
+            self._ann.__enter__()
+        return yielded
+
+    def __exit__(self, *exc) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        thread = threading.current_thread().name
+        for rec in self._recs:
+            name, fields, t0, parent = rec._open_spans().pop()
+            rec.event("span", ts=t0, span=name, end=rec.clock(),
+                      parent=parent, thread=thread, **fields)
+
+
 def chrome_trace(events: list[dict]) -> dict:
     """Fold a flat event list into Chrome trace-event JSON: one Perfetto
     track (tid) per bucket; per request a 'queued' slice (enqueue->flush)
     and a 'dispatch' slice (dispatch->terminal), sheds/fails/faults as
-    instant markers. Timestamps are microseconds relative to the earliest
-    event (Perfetto renders absolute monotonic epochs poorly)."""
+    instant markers. Phase spans become slices on one track per worker
+    thread and pool member (the leaves nest inside their 'batch' and
+    'dispatch' slices). Timestamps are microseconds relative to the
+    earliest event (Perfetto renders absolute monotonic epochs poorly)."""
     order = {s: i for i, s in enumerate(STAGES)}
     spans: dict[int, list[dict]] = {}
+    phase_spans: list[dict] = []
     aux: list[dict] = []
     t0 = min((e["ts"] for e in events), default=0.0)
     for ev in events:
         if ev.get("seq") is not None and ev["event"] in order:
             spans.setdefault(ev["seq"], []).append(ev)
+        elif ev["event"] == "span" and "end" in ev:
+            phase_spans.append(ev)
         else:
             aux.append(ev)
 
@@ -267,6 +364,17 @@ def chrome_trace(events: list[dict]) -> dict:
                 out.append({"name": f"{kind} seq={seq}", "cat": kind,
                             "ph": "i", "ts": us(by[kind]["ts"]), "s": "t",
                             "pid": 1, "tid": tid, "args": args})
+    for ev in phase_spans:
+        member = ev.get("member")
+        track = (f"{ev.get('thread', '?')} member {member}" if member
+                 else ev.get("thread", "?"))
+        out.append({"name": ev["span"],
+                    "cat": "phase" if ev["span"] in PHASES else "span",
+                    "ph": "X", "ts": us(ev["ts"]),
+                    "dur": max(us(ev["end"]) - us(ev["ts"]), 0.0),
+                    "pid": 1, "tid": tid_for(track),
+                    "args": {k: v for k, v in ev.items()
+                             if k not in ("ts", "end", "event", "span")}})
     for ev in aux:
         out.append({"name": ev["event"], "cat": "aux", "ph": "i",
                     "ts": us(ev["ts"]), "s": "g", "pid": 1, "tid": 0,
@@ -321,6 +429,14 @@ def emit(name: str, **fields) -> None:
             rec.event(name, **fields)
 
 
+def span(name: str, **fields):
+    """`TraceRecorder.span` into every active recorder, with one
+    annotation for a leaf; a shared no-op context when none is active."""
+    if not _ACTIVE:
+        return _NULL_SPAN
+    return _Span(tuple(_ACTIVE), name, fields)
+
+
 def resolve_trace(spec, *, clock=time.monotonic):
     """`ServerConfig.trace` -> a recorder: None/False -> `NOOP`, True ->
     in-memory `TraceRecorder`, a path string -> write-through JSONL, an
@@ -337,6 +453,7 @@ def resolve_trace(spec, *, clock=time.monotonic):
                     f"got {type(spec).__name__}")
 
 
-__all__ = ["AUX_EVENTS", "DEFAULT_MAX_EVENTS", "NOOP", "NoopRecorder",
-           "STAGES", "TERMINALS", "TraceRecorder", "chrome_trace", "emit",
-           "pop", "push", "resolve_trace", "trace_scope", "tracing"]
+__all__ = ["ANNOTATION_PREFIX", "AUX_EVENTS", "DEFAULT_MAX_EVENTS", "NOOP",
+           "NoopRecorder", "PHASES", "STAGES", "TERMINALS", "TraceRecorder",
+           "chrome_trace", "emit", "pop", "push", "resolve_trace", "span",
+           "trace_scope", "tracing"]

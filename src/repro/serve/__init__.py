@@ -28,7 +28,7 @@ Layers:
   pool.py       -- `ExecutorPool`, rendezvous-routed executors over
                    device subsets with probe-and-rebuild failover;
   server.py     -- `ImageFilterServer` (worker thread, `submit`, stats;
-                   the §15 `trace=`/`profile=` observability knobs and
+                   the §15 `trace=` observability knob and
                    the one-lock consistent `stats()` snapshot over the
                    shared `repro.obs.MetricsRegistry`);
   warmup.py     -- `python -m repro.serve.warmup` deploy-time pre-compiler.

@@ -73,18 +73,22 @@ Telemetry (DESIGN.md §15): the ledger counters live in a
 `repro.obs.MetricsRegistry` (labelled `member=` so pool members share
 one registry without colliding); the historical attribute API
 (`ex.hits`, `ex.retries`, ...) is preserved as properties reading the
-registry. With a `trace=` recorder, every dispatch emits per-request
-'dispatch' events (serve key, exec mode actually used, traced batch
-size, resolved §11 plan tag) and every fulfilment/isolated failure its
-terminal event. With a `profiler=` (`repro.obs.DispatchProfiler`), every
-workload dispatch is wall-timed against its roofline price -- the §15
-predicted-vs-observed drift histogram. All three default off/no-op.
+registry. Two always-on counters price the host -> device traffic:
+`serve_h2d_bytes_total{member}` (the stacked batch handed to the device,
+pad images included) and `serve_pixels_total{member}` (real pixels
+served). With a `trace=` recorder, every dispatch is a 'dispatch' span
+(its `batch` id inherited from the server's 'batch' span, `member`,
+`skey`, `warm`) around the workload's leaf phase spans (prep, upload,
+launch, device_wait, fetch; `repro.obs.trace.PHASES`), emits
+per-request 'dispatch' events (serve key, exec mode actually used,
+traced batch size, resolved §11 plan tag, batch id), and every
+fulfilment (a 'fulfil' leaf) or isolated failure its terminal event.
+Tracing defaults off (the no-op recorder).
 """
 from __future__ import annotations
 
 import contextlib
 import threading
-import time
 from collections import OrderedDict
 from typing import Callable, Sequence
 
@@ -122,7 +126,7 @@ class BatchExecutor:
                  on_dispatch: Callable[[str, str, bool], None] | None = None,
                  workloads: dict[str, Workload] | None = None,
                  metrics: MetricsRegistry | None = None,
-                 trace=NOOP, profiler=None) -> None:
+                 trace=NOOP) -> None:
         self.interpret = interpret
         self.workloads = resolve_workloads(workloads)
         self.pad_pow2 = pad_pow2
@@ -145,7 +149,6 @@ class BatchExecutor:
         # ------------------------------ §15 telemetry (registry-backed)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._trace = trace
-        self.profiler = profiler
         m = self.metrics
         self._c_hits = m.counter("serve_compile_hits_total")
         self._c_misses = m.counter("serve_compile_misses_total")
@@ -155,6 +158,8 @@ class BatchExecutor:
         self._c_retries = m.counter("serve_retries_total")
         self._c_isolated = m.counter("serve_isolated_total")
         self._c_degraded = m.counter("serve_degraded_total")
+        self._c_h2d = m.counter("serve_h2d_bytes_total")
+        self._c_pixels = m.counter("serve_pixels_total")
         # ------------------------------ §12 fault-tolerance state
         self.failures: dict[str, int] = {}   # bucket -> consecutive failures
         self._fallback: set[str] = set()     # buckets pinned to local exec
@@ -192,6 +197,11 @@ class BatchExecutor:
     def degraded(self) -> dict[str, int]:
         """bucket -> §12 local-fallback dispatch count (this member's)."""
         return self._c_degraded.group_by("bucket", member=self.name)
+
+    def count_transfer(self, h2d_bytes: int, pixels: int) -> None:
+        """One dispatch's host -> device bytes and real pixels (§15)."""
+        self._c_h2d.inc(h2d_bytes, member=self.name)
+        self._c_pixels.inc(pixels, member=self.name)
 
     # -------------------------------------------------- per-bucket plan memo
     def _plan(self, filt: str, method: str, mult_impl: str, n: int, h: int,
@@ -255,9 +265,9 @@ class BatchExecutor:
 
     def _plan_tag(self, mode: str, r0: FilterRequest, traced_n: int) -> str:
         """Compact spelling of the dispatch's resolved execution plan for
-        the §15 trace/drift labels: the §11 PlanConfig for a local filter
+        the §15 'dispatch' events: the §11 PlanConfig for a local filter
         dispatch, the exec mode (+ workload) otherwise. Only computed when
-        tracing or profiling is on; the memo makes it a plan-memo hit."""
+        tracing is on; the memo makes it a plan-memo hit."""
         if mode == "local" and r0.workload == "filter":
             h, w = r0.img.shape
             p = self._plan(r0.filt, r0.method, r0.mult_impl, traced_n, h, w)
@@ -299,23 +309,19 @@ class BatchExecutor:
         if wl is None:
             raise KeyError(f"no workload {r0.workload!r} registered "
                            f"(have: {tuple(self.workloads)})")
-        prof = self.profiler
-        plan = (self._plan_tag(mode, r0, traced_n)
-                if prof is not None or self._trace.enabled else None)
-        if self._trace.enabled:
-            for r in requests:
-                self._trace.event("dispatch", seq=r.seq, bucket=key,
-                                  skey=skey, exec=mode, n=n,
-                                  traced_n=traced_n, plan=plan,
-                                  member=self.name, workload=r0.workload)
-        with self._placed(mode):
-            if prof is None:
+        with self._trace.span("dispatch", member=self.name, skey=skey,
+                              warm=warm) as span:
+            if self._trace.enabled:
+                plan = self._plan_tag(mode, r0, traced_n)
+                for r in requests:
+                    self._trace.event("dispatch", seq=r.seq, bucket=key,
+                                      skey=skey, exec=mode, n=n,
+                                      traced_n=traced_n, plan=plan,
+                                      member=self.name,
+                                      workload=r0.workload,
+                                      batch=span.get("batch"))
+            with self._placed(mode):
                 return wl.execute(self, requests, traced_n, mode)
-            predicted = prof.predicted(wl, key, r0, traced_n)
-            t0 = time.perf_counter()
-            outs = wl.execute(self, requests, traced_n, mode)
-        prof.record(key, plan, predicted, time.perf_counter() - t0)
-        return outs
 
     def _report(self, key: str, mode: str, ok: bool) -> None:
         """Tell the owning pool (if any) how one dispatch went -- the §13
@@ -385,11 +391,12 @@ class BatchExecutor:
             self._fulfil(key, requests[:mid], retry=True)
             self._fulfil(key, requests[mid:], retry=True)
             return
-        for req, out in zip(requests, outs):
-            if not req.future.done():
-                req.future.set_result(out)
-                if self._trace.enabled:
-                    self._trace.event("fulfil", seq=req.seq, bucket=key)
+        with self._trace.span("fulfil"):
+            for req, out in zip(requests, outs):
+                if not req.future.done():
+                    req.future.set_result(out)
+                    if self._trace.enabled:
+                        self._trace.event("fulfil", seq=req.seq, bucket=key)
 
     def run(self, batch: MicroBatch) -> None:
         """Execute and fulfil -- every future resolves exactly once, to its

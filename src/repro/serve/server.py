@@ -65,9 +65,14 @@ Observability (DESIGN.md §15):
     pushed onto `repro.obs.trace`'s active scope for the server's
     lifetime). Export with `self.trace.write_jsonl()` /
     `write_chrome()`, or read back via `python -m repro.obs.snapshot`.
-  * **profiling** (`profile=True`, implied by tracing) -- every dispatch
-    is wall-timed against its roofline price; `stats()["profile"]` is
-    the per-(bucket, plan) drift table.
+  * **phase spans** (with tracing on) -- the worker's time is tiled by
+    leaf spans (`repro.obs.trace.PHASES`): 'idle' around each wait for
+    work, 'collect' around each look at the batcher, the executor's
+    prep / upload / launch / device_wait / fetch inside each batch's
+    'batch' span, and 'fulfil' for set_result, slot release and batch
+    counters. Each leaf is also a `jax.profiler.TraceAnnotation`
+    (`repro.serve.<phase>`), so a profiler trace places every device
+    idle gap under the host phase that held it up.
 
 Tracing never touches payload bytes (served outputs stay bit-identical,
 guarded by `scripts/check.sh --smoke-obs`) and costs <5% throughput when
@@ -75,14 +80,16 @@ on (the `serve_obs_overhead` bench row).
 
 `stats()` reports the per-request counters (now per-priority too), the
 batch occupancy histogram, flush-trigger counts, the warm compile-cache
-ledger, the §13 plan-memo/controller/tenant/pool surfaces, and the §12
-fault counters -- everything the serve benchmarks and the
+ledger, the host -> device `transfer` counters, the §13
+plan-memo/controller/tenant/pool surfaces, and the §12 fault counters --
+everything the serve benchmarks and the
 `--smoke-serve` / `--smoke-fault` / `--smoke-slo` / `--smoke-obs`
 guards read.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Callable, Sequence
@@ -90,7 +97,6 @@ from typing import Callable, Sequence
 from repro.filters.pipeline import EXEC_MODES
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.profile import DispatchProfiler
 from repro.obs.trace import NOOP, resolve_trace
 from repro.serve.admission import (
     AdmissionGate,
@@ -147,8 +153,6 @@ class ServerConfig:
     #                                 the built-in 'filter' (e.g. 'infer')
     # ------------------------------- observability (DESIGN.md §15)
     trace: object = None            # None | True | jsonl path | recorder
-    profile: bool = False           # roofline drift profiling (tracing
-    #                                 implies it)
 
 
 class ImageFilterServer:
@@ -167,9 +171,7 @@ class ImageFilterServer:
         self.trace = resolve_trace(self.config.trace, clock=clock)
         self._owns_trace = (self.trace is not NOOP
                             and self.trace is not self.config.trace)
-        self._profiler = (DispatchProfiler(self.metrics)
-                          if self.config.profile or self.trace.enabled
-                          else None)
+        self._batch_ids = itertools.count(1)    # the trace's batch ids
         m = self.metrics
         self._c_submitted = m.counter("serve_submitted_total")
         self._c_served = m.counter("serve_served_total")
@@ -180,6 +182,8 @@ class ImageFilterServer:
         self._c_batches = m.counter("serve_batches_total")
         self._c_occupancy = m.counter("serve_batch_occupancy_total")
         self._h_latency = m.histogram("serve_request_latency_seconds")
+        self._c_h2d = m.counter("serve_h2d_bytes_total")
+        self._c_pixels = m.counter("serve_pixels_total")
         self._last_error: str | None = None
         # ------------------------------------------------ serving machinery
         self._gate = AdmissionGate(
@@ -204,7 +208,7 @@ class ImageFilterServer:
             degrade_after=self.config.degrade_after,
             plan_memo_max=self.config.plan_memo_max,
             workloads=self._workloads, metrics=self.metrics,
-            trace=self.trace, profiler=self._profiler)
+            trace=self.trace)
         if self.config.pool is not None:
             self._executor: BatchExecutor | ExecutorPool = ExecutorPool(
                 self.config.pool, drain_after=self.config.drain_after,
@@ -349,9 +353,9 @@ class ImageFilterServer:
             self._cond.notify_all()
 
     def stats(self) -> dict:
-        """Counters + occupancy histogram + warm-cache ledger + the §12
-        fault/health surface + the §13 service-level surface + the §15
-        profile table.
+        """Counters + occupancy histogram + warm-cache ledger + the
+        host -> device `transfer` counters (per pool member) + the §12
+        fault/health surface + the §13 service-level surface.
 
         The request conservation counters (submitted / served / failed /
         shed / pending / rejected / tenants) are read under ONE registry
@@ -384,6 +388,8 @@ class ImageFilterServer:
             snap["pressure"] = gate["pressure"]
             snap["rejected"] = gate["rejected"]
             snap["tenants"] = gate["tenants"]
+            snap["transfer"] = {"h2d_bytes": self._c_h2d.group_by("member"),
+                                "pixels": self._c_pixels.group_by("member")}
         ex = self._executor.stats()
         snap["compile"] = {"warmed": ex["warmed"], "hits": ex["hits"],
                            "misses": ex["misses"]}
@@ -393,8 +399,6 @@ class ImageFilterServer:
         if self._controller is not None:
             snap["controller"] = self._controller.stats()
         snap.update(self._executor.fault_stats())
-        if self._profiler is not None:
-            snap["profile"] = self._profiler.summary()
         snap["healthy"] = self._is_healthy()
         snap["state"] = "healthy" if snap["healthy"] else "degraded"
         return snap
@@ -431,26 +435,34 @@ class ImageFilterServer:
             need, self._shed_need = self._shed_need, 0
             self._batcher.shed_overload(need)
 
+    def _collect(self) -> tuple[list[MicroBatch], list]:
+        """One look at the batcher for ready batches and swept requests.
+        Caller holds `self._cond`."""
+        with self.trace.span("collect"):
+            self._shed_for_overload()
+            return self._batcher.ready(self._clock()), self._batcher.take_shed()
+
     def _loop(self) -> None:
+        span = self.trace.span
         while True:
             with self._cond:
-                self._shed_for_overload()
-                batches = self._batcher.ready(self._clock())
-                shed = self._batcher.take_shed()
+                batches, shed = self._collect()
                 while not batches and not shed and not self._closing:
                     deadline = self._batcher.next_deadline()
                     wait = (None if deadline is None
                             else max(deadline - self._clock(), 1e-4))
-                    self._cond.wait(wait)
-                    self._shed_for_overload()
-                    batches = self._batcher.ready(self._clock())
-                    shed = self._batcher.take_shed()
+                    with span("idle"):
+                        self._cond.wait(wait)
+                    batches, shed = self._collect()
                 closing = self._closing
                 if closing and not batches:
-                    batches = self._batcher.drain()
-                    shed += self._batcher.take_shed()
+                    with span("collect"):
+                        batches = self._batcher.drain()
+                        shed += self._batcher.take_shed()
                 drain = self._drain
-            self._fail_shed(shed)
+            if shed:
+                with span("fulfil"):
+                    self._fail_shed(shed)
             if closing and not drain:
                 for b in batches:
                     self._fail_batch(b, ServerClosed("server closed undrained"))
@@ -500,45 +512,49 @@ class ImageFilterServer:
         self._release_batch(batch)
 
     def _run(self, batch: MicroBatch) -> None:
-        t0 = self._clock()
-        try:
-            self._executor.run(batch)    # fulfils every future exactly once
-        except BaseException as err:     # noqa: BLE001 -- §12 catch-all:
-            # run() never raises by contract, but a serving-layer bug must
-            # degrade the server, not hang its futures or leak its slots
-            for req in batch.requests:
-                if not req.future.done():
-                    req.future.set_exception(err)
-                    if self.trace.enabled:
-                        self.trace.event("fail", seq=req.seq,
-                                         bucket=batch.key, cause="worker",
-                                         error=repr(err))
-            with self._cond:
-                self._healthy = False
-            with self.metrics.hold():
-                self._c_errors.inc()
-                self._last_error = repr(err)
-        now = self._clock()
-        if self._controller is not None and batch.requests:
-            # feed the §13 observed-service ledger with the traced batch
-            # size this dispatch actually compiled for
-            n = len(batch.requests)
-            traced = next_pow2(n) if self.config.pad_pow2 else n
-            self._controller.observe(batch.key, batch.requests[0], traced,
-                                     now - t0)
-        served = [r for r in batch.requests if not r.future.failed()]
-        # one lock acquisition for the whole batch outcome (§15): a
-        # concurrent stats() sees all of it or none of it
-        with self.metrics.hold():
-            self._c_batches.inc(reason=batch.reason)
-            self._c_occupancy.inc(n=len(batch.requests))
-            for r in served:
-                self._c_served.inc(priority=r.priority)
-            if len(batch.requests) - len(served):
-                self._c_failed.inc(len(batch.requests) - len(served))
-        for r in served:
-            self._h_latency.observe(now - r.submitted, priority=r.priority)
-        self._release_batch(batch)
+        with self.trace.span("batch", batch=next(self._batch_ids)):
+            t0 = self._clock()
+            try:
+                self._executor.run(batch)  # fulfils every future exactly once
+            except BaseException as err:   # noqa: BLE001 -- §12 catch-all:
+                # run() never raises by contract, but a serving-layer bug
+                # must degrade the server, not hang its futures or leak its
+                # slots
+                for req in batch.requests:
+                    if not req.future.done():
+                        req.future.set_exception(err)
+                        if self.trace.enabled:
+                            self.trace.event("fail", seq=req.seq,
+                                             bucket=batch.key, cause="worker",
+                                             error=repr(err))
+                with self._cond:
+                    self._healthy = False
+                with self.metrics.hold():
+                    self._c_errors.inc()
+                    self._last_error = repr(err)
+            with self.trace.span("fulfil"):
+                now = self._clock()
+                if self._controller is not None and batch.requests:
+                    # feed the §13 observed-service ledger with the traced
+                    # batch size this dispatch actually compiled for
+                    n = len(batch.requests)
+                    traced = next_pow2(n) if self.config.pad_pow2 else n
+                    self._controller.observe(batch.key, batch.requests[0],
+                                             traced, now - t0)
+                served = [r for r in batch.requests if not r.future.failed()]
+                # one lock acquisition for the whole batch outcome (§15): a
+                # concurrent stats() sees all of it or none of it
+                with self.metrics.hold():
+                    self._c_batches.inc(reason=batch.reason)
+                    self._c_occupancy.inc(n=len(batch.requests))
+                    for r in served:
+                        self._c_served.inc(priority=r.priority)
+                    if len(batch.requests) - len(served):
+                        self._c_failed.inc(len(batch.requests) - len(served))
+                for r in served:
+                    self._h_latency.observe(now - r.submitted,
+                                            priority=r.priority)
+                self._release_batch(batch)
 
 
 __all__ = ["ImageFilterServer", "ServerConfig"]

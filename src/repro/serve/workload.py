@@ -28,7 +28,7 @@ import numpy as np
 
 from repro.filters.bank import get_filter
 from repro.filters.conv import MULT_IMPLS
-from repro.filters.pipeline import apply_filter_batch
+from repro.filters.pipeline import BATCH_DTYPE, apply_filter_batch
 from repro.serve.request import FilterRequest, request_weight
 
 
@@ -97,6 +97,9 @@ class FilterWorkload(Workload):
         h, w = r0.img.shape
         kw = executor._exec_kw(exec_mode, r0.filt, r0.method, r0.mult_impl,
                                traced_n, h, w)
+        executor.count_transfer(
+            traced_n * h * w * np.dtype(BATCH_DTYPE).itemsize,
+            len(requests) * h * w)
         return apply_filter_batch(
             [r.img for r in requests], r0.filt, pad_to=traced_n,
             method=r0.method, nbits=r0.nbits,
@@ -108,7 +111,7 @@ class FilterWorkload(Workload):
         h, w = shape
         kw = executor._exec_kw(exec_mode, target, method, mult_impl,
                                traced_n, h, w)
-        apply_filter_batch([np.zeros((h, w), np.int32)] * traced_n, target,
+        apply_filter_batch([np.zeros((h, w), BATCH_DTYPE)] * traced_n, target,
                            method=method, nbits=nbits,
                            interpret=executor.interpret, **kw)
 

@@ -1,9 +1,11 @@
 """Observability-layer tests (DESIGN.md §15): the metrics registry's
 thread-safety/bounding/atomic-snapshot contract, per-request trace span
 invariants on a real server (exactly one terminal per submitted request,
-monotone stage timestamps), the Chrome trace-event export, the one-lock
-`stats()` conservation identity, bit-identity with tracing on, and a
-hypothesis property driving the batcher+recorder through random
+monotone stage timestamps), the worker's phase spans (they tile its time,
+nest inside each dispatch, and cost nothing with tracing off), the
+host -> device transfer counters, the Chrome trace-event export, the
+one-lock `stats()` conservation identity, bit-identity with tracing on,
+and a hypothesis property driving the batcher+recorder through random
 schedules (spans are never lost or duplicated, whatever the interleave).
 
 Like test_serve.py, the deterministic pieces run on a fake clock and
@@ -19,13 +21,17 @@ import pytest
 from repro.filters import apply_filter
 from repro.obs import (
     NOOP,
+    PHASES,
     STAGES,
     TERMINALS,
     MetricsRegistry,
     TraceRecorder,
     chrome_trace,
     resolve_trace,
+    span,
+    trace_scope,
 )
+from repro.obs import trace as obs_trace
 from repro.obs.snapshot import load_jsonl
 from repro.obs.snapshot import main as snapshot_main
 from repro.serve import (
@@ -208,6 +214,48 @@ class TestTraceRecorder:
                 assert e["dur"] >= 0
 
 
+    def test_span_inherits_parent_fields_through_the_scope_stack(self):
+        clk = FakeClock()
+        rec = TraceRecorder(clock=clk)
+        with trace_scope(rec):
+            with rec.span("dispatch", batch=7, member="m0") as fields:
+                assert fields["batch"] == 7
+                clk.t = 1.0
+                with span("prep"):          # the module-level form
+                    clk.t = 3.0
+        prep, dispatch = rec.events("span")
+        assert (prep["span"], prep["parent"], prep["batch"],
+                prep["member"]) == ("prep", "dispatch", 7, "m0")
+        assert (prep["ts"], prep["end"]) == (1.0, 3.0)
+        assert (dispatch["ts"], dispatch["end"], dispatch["parent"]) == (
+            0.0, 3.0, None)
+        assert rec.summary()["phase_s"]["prep"]["max"] == 2.0
+        with span("prep"):                  # nothing active: not recorded
+            pass
+        assert len(rec.events("span")) == 2
+
+    def test_chrome_trace_puts_phases_on_worker_and_member_tracks(self):
+        rec = TraceRecorder(clock=FakeClock())
+        for name, t0, t1, member in (("idle", 0.0, 1.0, None),
+                                     ("batch", 1.0, 4.0, None),
+                                     ("prep", 1.0, 2.0, "m0"),
+                                     ("fetch", 2.0, 3.0, "m1")):
+            fields = {} if member is None else {"member": member}
+            rec.event("span", ts=t0, span=name, end=t1, parent=None,
+                      thread="w", **fields)
+        doc = chrome_trace(rec.events())
+        slices = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
+        assert set(slices) == {"idle", "batch", "prep", "fetch"}
+        assert slices["prep"]["dur"] == 1e6 and slices["prep"]["cat"] == "phase"
+        assert slices["batch"]["cat"] == "span"
+        assert slices["idle"]["tid"] == slices["batch"]["tid"]
+        tracks = {slices[n]["tid"] for n in ("idle", "prep", "fetch")}
+        assert len(tracks) == 3
+        names = {e["args"]["name"] for e in doc["traceEvents"]
+                 if e["ph"] == "M"}
+        assert {"w", "w member m0", "w member m1"} <= names
+
+
 # ------------------------------------------- spans on a real server
 
 def serve_all(srv, futs, timeout=60):
@@ -283,19 +331,84 @@ class TestServerTracing:
         srv.close()            # drain serves the lone sub-max_batch request
         fut.result(60)
         assert srv.trace is NOOP
-        assert "profile" not in srv.stats()
 
-    def test_profile_drift_rows_present(self):
-        cfg = ServerConfig(max_batch=2, max_delay_ms=FAR, profile=True)
-        srv = ImageFilterServer(cfg)
-        fut = srv.submit(image(0), "gaussian3")
-        srv.close()            # drain serves the lone sub-max_batch request
-        fut.result(60)
-        prof = srv.stats()["profile"]
-        assert len(prof) == 1
-        (row,) = prof.values()
-        assert row["n_obs"] == 1 and row["observed_mean_s"] > 0
-        assert "plan" in row and "bucket" in row
+    def test_leaf_spans_tile_the_worker_and_nest_in_each_dispatch(self):
+        # frames large enough that a batch's work dwarfs the executor's
+        # fixed bookkeeping (plan memo, fault probe), as at serving sizes
+        shape = (480, 640)
+        cfg = ServerConfig(max_batch=4, max_delay_ms=1.0, trace=True)
+        with ImageFilterServer(cfg) as srv:
+            srv.warmup([shape], ["gaussian3"], batches=(1, 2, 4))
+            imgs = [image(i, shape) for i in range(8)]
+            for wave in (imgs[:4], imgs[4:]):
+                serve_all(srv, [srv.submit(im, "gaussian3") for im in wave])
+        evs = srv.trace.events("span")
+        worker = [e for e in evs if e["thread"] == "repro-serve-worker"]
+        leaves = sorted(((e["ts"], e["end"]) for e in worker
+                         if e["span"] in PHASES))
+        assert {e["span"] for e in worker} == set(PHASES) | {"batch",
+                                                              "dispatch"}
+        covered, reach = 0.0, leaves[0][0]
+        for t0, t1 in leaves:
+            covered += max(t1 - max(t0, reach), 0.0)
+            reach = max(reach, t1)
+        assert covered >= 0.99 * (reach - leaves[0][0])
+        dispatches = [e for e in worker if e["span"] == "dispatch"]
+        assert len(dispatches) == srv.stats()["batches"]
+        inner = ("prep", "upload", "launch", "device_wait", "fetch")
+        for d in dispatches:
+            kids = [e for e in worker if e.get("batch") == d["batch"]
+                    and e["span"] in inner]
+            assert sorted(e["span"] for e in kids) == sorted(inner)
+            for e in kids:
+                assert e["parent"] == "dispatch"
+                assert d["ts"] <= e["ts"] <= e["end"] <= d["end"]
+            assert (sum(e["end"] - e["ts"] for e in kids)
+                    >= 0.95 * (d["end"] - d["ts"]))
+        # a request's dispatch event names the batch that served it
+        batches = {d["batch"] for d in dispatches}
+        assert {e["batch"] for e in srv.trace.events("dispatch")} == batches
+
+    def test_trace_off_builds_no_span_or_annotation(self, monkeypatch):
+        built = collections.Counter()
+
+        class Counting:
+            def __init__(self, kind, real):
+                self.kind, self.real = kind, real
+
+            def __call__(self, *a, **k):
+                built[self.kind] += 1
+                return self.real(*a, **k)
+
+        monkeypatch.setattr(obs_trace, "_Span",
+                            Counting("span", obs_trace._Span))
+        monkeypatch.setattr(obs_trace, "TraceAnnotation",
+                            Counting("annotation", obs_trace.TraceAnnotation))
+        monkeypatch.setattr(NOOP.__class__, "event",
+                            lambda self, *a, **k: built.update(["event"]))
+        for trace in (None, True):
+            built.clear()
+            with ImageFilterServer(ServerConfig(max_batch=2,
+                                                max_delay_ms=FAR,
+                                                trace=trace)) as srv:
+                serve_all(srv, [srv.submit(image(i), "box3")
+                                for i in range(2)])
+            if trace is None:
+                assert not built, built
+            else:
+                assert built["span"] and built["annotation"]
+
+    def test_transfer_counters_price_pad_slots_and_real_pixels(self):
+        h, w = 24, 20
+        with ImageFilterServer(ServerConfig(max_batch=4,
+                                            max_delay_ms=FAR)) as srv:
+            # a size flush of 4 and a drained batch of 3, traced as 4
+            futs = [srv.submit(image(i, (h, w)), "box3") for i in range(7)]
+            srv.close()
+            serve_all(srv, futs)
+            transfer = srv.stats()["transfer"]
+        assert transfer == {"h2d_bytes": {"": 4 * h * w * 8},
+                            "pixels": {"": h * w * 7}}
 
     def test_snapshot_cli(self, tmp_path, capsys):
         path = str(tmp_path / "t.jsonl")
@@ -307,8 +420,10 @@ class TestServerTracing:
         assert snapshot_main([path, "--chrome", chrome]) == 0
         out = capsys.readouterr().out
         assert "spans: 4" in out and "WARNING" not in out
+        assert "phases (ms):" in out and "device_wait" in out
         doc = json.load(open(chrome))
         assert doc["traceEvents"]
+        assert any(e.get("cat") == "phase" for e in doc["traceEvents"])
 
 
 # ------------------------------------------------- consistent stats()

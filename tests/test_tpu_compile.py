@@ -115,3 +115,29 @@ def test_auto_never_takes_the_kcm_gather(one_chip, name):
     assert_mosaic(compiled_text(
         lambda x: apply_filter(x, name, interpret=False),
         one_chip, ((2, 64, 128), jnp.uint8)))
+
+
+@pytest.mark.parametrize("name", ["refmlm_conv_pass", "refmlm_conv_fused",
+                                  "mitchell_matmul", "karatsuba_matmul"])
+def test_kernels_carry_stable_names(one_chip, name):
+    """Each Pallas kernel names its custom call, so a device trace reads
+    the kernel's name, not the private function that launched it."""
+    i32 = jnp.int32
+    fn, shapes = {
+        "refmlm_conv_pass": (
+            lambda x: conv2d_pass(x, G3, method="refmlm", interpret=False),
+            [((1, 64, 128), i32)]),
+        "refmlm_conv_fused": (
+            lambda x: fused_separable_pass(x, G5.sep_row, G5.sep_col,
+                                           nbits2=16, interpret=False),
+            [((1, 64, 128), i32)]),
+        "mitchell_matmul": (
+            lambda a, b: mitchell_matmul_kernel(a, b, interpret=False),
+            [((64, 256), i32), ((256, 128), i32)]),
+        "karatsuba_matmul": (
+            lambda ah, al, bh, bl: karatsuba_matmul_kernel(
+                ah, al, bh, bl, interpret=False),
+            [((128, 256), i32)] * 2 + [((256, 128), i32)] * 2),
+    }[name]
+    text = compiled_text(fn, one_chip, *shapes)
+    assert f"%{name}" in text and "tpu_custom_call" in text
