@@ -36,6 +36,7 @@ from repro.core.mitchell import babic_ecc as _babic_ecc
 from repro.core.mitchell import mitchell as _mitchell
 from repro.core.odma import odma as _odma
 from repro.core.refmlm import refmlm as _refmlm
+from repro.core.refmlm import refmlm_const as _refmlm_const
 
 METHODS = ("exact", "refmlm", "refmlm_nc", "mitchell", "odma")  # + mitchell_ecc{k}
 
@@ -56,6 +57,22 @@ def tap_multiplier(method: str):
     if method == "odma":
         return lambda a, b, nbits: _odma(a, b, nbits).astype(jnp.int32)
     raise ValueError(f"unknown multiplier method {method!r}")
+
+
+def const_tap_multiplier(method: str):
+    """method -> f(a, c, nbits): `tap_multiplier(method)` with a constant
+    non-negative Python-int coefficient `c`, bit for bit.
+
+    The REFMLM methods run the kom4 recursion with the constant operand
+    (`refmlm_const`: leaves whose coefficient digit is 0 are dropped); every
+    other method gets `c` broadcast, exactly as a runtime coefficient is.
+    """
+    if method == "refmlm":
+        return lambda a, c, nbits: _refmlm_const(a, c, nbits, base="efmlm").astype(jnp.int32)
+    if method == "refmlm_nc":
+        return lambda a, c, nbits: _refmlm_const(a, c, nbits, base="mlm").astype(jnp.int32)
+    mult = tap_multiplier(method)
+    return lambda a, c, nbits: mult(a, jnp.broadcast_to(jnp.int32(c), a.shape), nbits)
 
 
 @lru_cache(maxsize=None)
@@ -105,5 +122,5 @@ def tables_acc_bound(tables: np.ndarray) -> int:
     return int(np.abs(np.asarray(tables, np.int64)).max(axis=-1).sum())
 
 
-__all__ = ["METHODS", "filter_tables", "product_table", "tables_acc_bound",
-           "tap_multiplier"]
+__all__ = ["METHODS", "const_tap_multiplier", "filter_tables", "product_table",
+           "tables_acc_bound", "tap_multiplier"]

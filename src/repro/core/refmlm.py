@@ -36,12 +36,19 @@ Evaluation strategies (DESIGN.md §7):
       recursion by construction, asserted in tests/test_kcm.py.
   flatten=False -- the paper-literal Python-unrolled recursion, kept as the
       structural reference.
+
+  refmlm_const -- kom4 with a constant operand (a filter tap): the walk of
+      the leaves runs on the coefficient as Python ints, so every leaf whose
+      coefficient digit is 0 (an exact 0) is dropped at trace time and the
+      kept leaves fold the coefficient's half of the Mitchell formula.
+      Bit-identical to `refmlm`; asserted in tests/test_core_multipliers.py.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax.numpy as jnp
+import numpy as np
 from jax import Array
 
 from repro.core.bitops import split_halves
@@ -50,13 +57,16 @@ from repro.core.mitchell import _check_width, _prod_dtype
 SUPPORTED_WIDTHS = (2, 4, 8, 16)
 
 
-def mlm2(a: Array, b: Array) -> Array:
+def mlm2(a: Array, b: Array | int) -> Array:
     """Uncorrected 2x2 Mitchell product (paper Table 1 MLMP column).
 
     Closed form on 2-bit operands: the only approximation error is 3*3 -> 8.
     Implemented via the integer Mitchell formula specialized to k in {0, 1}.
+    A Python-int `b` is a constant digit (`_mlm2_const`).
     """
     a = a.astype(jnp.int32)
+    if isinstance(b, int):
+        return _mlm2_const(a, b)
     b = b.astype(jnp.int32)
     k1 = (a >> 1) & 1          # leading-one position for 2-bit operands
     k2 = (b >> 1) & 1
@@ -68,13 +78,37 @@ def mlm2(a: Array, b: Array) -> Array:
     return jnp.where((a == 0) | (b == 0), 0, p)
 
 
-def efmlm2(a: Array, b: Array) -> Array:
+def _mlm2_const(a: Array, c: int) -> Array:
+    """`mlm2(a, c)` for a constant `c`: the same formula, with c's leading
+    one k2 and mantissa x2 worked out in Python, so only a's half (its
+    leading one, mantissa, the m < lead select and the zero detector) is
+    left for the device."""
+    if c == 0:
+        return jnp.zeros_like(a)
+    k2 = (c >> 1) & 1
+    x2 = c - (1 << k2)
+    k1 = (a >> 1) & 1
+    x1 = a - jnp.where(a > 0, jnp.int32(1) << k1, 0)
+    m = x1 << k2 if k2 else x1
+    if x2:
+        m = m + (x2 << k1)
+    lead = jnp.int32(1 << k2) << k1
+    p = jnp.where(m < lead, lead + m, 2 * m)
+    return jnp.where(a == 0, 0, p)
+
+
+def efmlm2(a: Array, b: Array | int) -> Array:
     """Error-Free 2x2 Mitchell multiplier (paper §3.1, eq. 23).
 
     mlm2 plus the single-AND correction term  a1*a0*b1*b0  (adds 1 exactly for
-    the 11b x 11b combination). Exact for all 16 operand combinations.
+    the 11b x 11b combination). Exact for all 16 operand combinations. A
+    Python-int `b` is a constant digit: its half of the AND folds away.
     """
     a = a.astype(jnp.int32)
+    if isinstance(b, int):
+        if (b >> 1) & b & 1:
+            return mlm2(a, b) + ((a >> 1) & a & 1)
+        return mlm2(a, b)
     b = b.astype(jnp.int32)
     correction = (a >> 1) & a & (b >> 1) & b & 1
     return mlm2(a, b) + correction
@@ -219,6 +253,58 @@ def refmlm(
 
 
 refmlm16 = partial(refmlm, nbits=16)
+
+
+@lru_cache(maxsize=None)
+def _const_leaves(c: int, nbits: int) -> tuple[tuple[int, int, int], ...]:
+    """The kom4 leaves of  a * c  whose coefficient digit is non-zero, as
+    (pixel digit shift, coefficient digit, weight) triples.
+
+    Walks `_leaves` itself with `c` as a Python int. The pixel side is a
+    probe: element i of `probe` has digit i set and no other, so each
+    leaf's pixel plane is one-hot at the digit it reads. The split masks
+    both sides to `nbits`, exactly as the runtime recursion does.
+    """
+    probe = np.array([1 << s for s in range(0, nbits, 2)], np.int64)
+    leaves: list = []
+    _leaves(probe, c, nbits, "kom4", 1, None, leaves)
+    return tuple((2 * int(np.argmax(pa)), int(pc), w)
+                 for pa, pc, w, _ in leaves if pc)
+
+
+def const_base_mults(c: int, nbits: int) -> int:
+    """Base products `refmlm_const(a, c, nbits)` evaluates per element."""
+    return len(_const_leaves(int(c), nbits))
+
+
+def refmlm_const(a: Array, c: int, nbits: int = 16, *,
+                 base: str = "efmlm") -> Array:
+    """`refmlm(a, c, nbits, variant='kom4', base=base)` for a constant
+    non-negative Python int `c`, bit for bit.
+
+    A leaf  weight * base(a_i, c_j)  with c_j = 0 is exactly 0 (both bases
+    zero-detect, and the correction term ANDs c_j's bits), so only leaves
+    with a non-zero coefficient digit are evaluated, each through the same
+    base with the digit as a Python int. Dropping exact zeros from the
+    modular sum in the product dtype leaves it unchanged. A constant with
+    no non-zero digit costs no base product at all.
+    """
+    _check_width(nbits)
+    if nbits not in SUPPORTED_WIDTHS:
+        raise ValueError(f"nbits must be one of {SUPPORTED_WIDTHS}, got {nbits}")
+    base_fn = {"efmlm": efmlm2, "mlm": mlm2}[base]
+    a = a.astype(jnp.int32)
+    if nbits == 2:
+        return base_fn(a, int(c))
+    dt = _prod_dtype(nbits)
+    digits: dict[int, Array] = {}         # pixel planes, shared across c_j
+    acc = None
+    for shift, cj, weight in _const_leaves(int(c), nbits):
+        if shift not in digits:
+            digits[shift] = (a >> shift) & 3
+        term = base_fn(digits[shift], cj).astype(dt) << (weight.bit_length() - 1)
+        acc = term if acc is None else acc + term       # kom4 weights are 2**k
+    return jnp.zeros(a.shape, dt) if acc is None else acc
 
 
 def op_counts(nbits: int, variant: str = "kom4") -> dict[str, int]:

@@ -38,9 +38,17 @@ Block shapes default to the per-backend autotune cache
 row/column padding to tile multiples happens here -- callers pass any
 (N, H, W).
 
-The (kh, kw) coefficient table rides in SMEM and is read as scalars, like
-the FPGA's coefficient registers; every tap product routes through the
-selected multiplier via the signed-magnitude contract (DESIGN.md §4):
+Concrete taps (the bank's, host NumPy) are baked into the compiled kernel
+as Python ints, like an FPGA's synthesis-time constants: a zero tap adds
+nothing, and REFMLM runs the kom4 recursion with a constant operand
+(`repro.core.refmlm.refmlm_const`), so every 2x2 base product whose
+coefficient digit is 0 is dropped at trace time (DESIGN.md §7). Traced taps
+(a caller that jits over them) ride in SMEM instead and are read as
+scalars at run time, like the FPGA's coefficient registers. Each REFMLM
+kernel build records its base products per output pixel in the process-wide
+`KERNEL_METRICS` gauge `conv_tap_leaves{kernel, coef="baked"|"smem", ...}`.
+Either way every tap product routes through the selected multiplier via
+the signed-magnitude contract (DESIGN.md §4):
 p = sgn(t)*sgn(c)*mult(|t|,|c|), so negative coefficients (sharpen, Sobel,
 Laplacian) reuse the unsigned paper multipliers unchanged. The in-register
 accumulation is the CSA tree, carried at the narrowest width the exact
@@ -50,8 +58,9 @@ applies the filter's fixed-point normalization ('clip'), gradient-magnitude
 display ('abs'), or nothing ('none', the separable intermediate) in int32.
 
 Tap-product implementations (`mult_impl`, DESIGN.md §7):
-  * 'recurse' -- expand the selected multiplier's dataflow per tap (the
-    digit-plane-flattened KOM recursion for 'refmlm');
+  * 'recurse' -- expand the selected multiplier's dataflow per tap (for
+    'refmlm': the constant-operand kom4 recursion when the taps are baked,
+    the digit-plane-flattened KOM recursion when they ride in SMEM);
   * 'kcm'     -- constant-coefficient fast path: coefficients are trace-time
     constants, so each tap is a `repro.core.kcm` product-table gather
     (sign baked in), bit-identical to 'recurse' for every method;
@@ -74,14 +83,28 @@ from jax import Array
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.kcm import METHODS, filter_tables, tables_acc_bound, tap_multiplier
+from repro.core.kcm import (
+    METHODS,
+    const_tap_multiplier,
+    filter_tables,
+    tables_acc_bound,
+    tap_multiplier,
+)
 from repro.core.platform import grid_compiler_params, resolve_interpret
+from repro.core.refmlm import const_base_mults, op_counts
+from repro.obs.metrics import MetricsRegistry
 from repro.tuning import choose_block_rows, resolve_blocks
 from repro.tuning.blocks import min_block_cols, min_block_rows, round_up
 
 MULT_IMPLS = ("recurse", "kcm", "auto")
 
 _ACC_DTYPES = {"int16": jnp.int16, "int32": jnp.int32}
+
+#: Kernel-build telemetry. Process-wide, like the jit cache whose builds it
+#: records: a kernel traced once serves every later caller.
+KERNEL_METRICS = MetricsRegistry()
+
+_REFMLM_METHODS = ("refmlm", "refmlm_nc")
 
 
 def accumulate_taps(bands, k_ref, acc_shape, *, kh: int, kw: int, w: int,
@@ -90,8 +113,11 @@ def accumulate_taps(bands, k_ref, acc_shape, *, kh: int, kw: int, w: int,
     """Shared CSA-tree body: Σ_taps sgn * mult(|tap|, |coeff|) over a band.
 
     `bands` -- kh arrays of shape (..., w + kw - 1); `k_ref` -- the (kh, kw)
-    SMEM coefficient table. One definition serves every dataflow so the
-    direct / separable / fused paths are bit-exact by construction.
+    SMEM coefficient table, or the taps as nested tuples of Python ints,
+    baked into the kernel: a zero tap is then skipped (sgn(0) = 0) and each
+    product takes `const_tap_multiplier`. One definition serves every
+    dataflow so the direct / separable / fused paths are bit-exact by
+    construction.
 
     With `tables` (a (kh*kw, 2**nbits) KCM ROM stack, coefficient signs
     baked in) each tap product becomes a gather -- `k_ref`/`method` are then
@@ -102,14 +128,24 @@ def accumulate_taps(bands, k_ref, acc_shape, *, kh: int, kw: int, w: int,
     the int32 carry.
     """
     acc = jnp.zeros(acc_shape, acc_dtype)
-    mult = None if tables is not None else tap_multiplier(method)
+    baked = isinstance(k_ref, tuple)
+    if tables is not None:
+        mult = None
+    else:
+        mult = const_tap_multiplier(method) if baked else tap_multiplier(method)
     for di in range(kh):
         band = bands[di]
         for dj in range(kw):
+            if baked and k_ref[di][dj] == 0:
+                continue
             tap = band[..., dj : dj + w]
             if tables is not None:
                 prod = jnp.take(tables[di * kw + dj], jnp.abs(tap), axis=0)
                 term = jnp.sign(tap).astype(acc_dtype) * prod.astype(acc_dtype)
+            elif baked:
+                c = k_ref[di][dj]
+                term = jnp.sign(tap) * mult(jnp.abs(tap), abs(c), nbits)
+                term = (-term if c < 0 else term).astype(acc_dtype)
             else:
                 c = k_ref[di, dj]
                 prod = mult(jnp.abs(tap),
@@ -171,6 +207,37 @@ def _tables_for(method: str, taps, nbits: int):
     return _device_tables(*key), acc
 
 
+def _baked(taps, shape: tuple[int, int]) -> tuple[tuple[int, ...], ...]:
+    """Concrete taps as the hashable (kh, kw) nested int tuples a kernel
+    bakes in (a static jit argument)."""
+    rows = np.asarray(taps, np.int64).reshape(shape)
+    return tuple(tuple(int(v) for v in row) for row in rows)
+
+
+def _record_tap_leaves(kernel: str, method: str, passes) -> None:
+    """Set `conv_tap_leaves`: the 2x2 base products per output pixel of one
+    REFMLM kernel build. `passes` -- (taps, nbits) per pass, the taps either
+    baked (nested int tuples: only non-zero coefficient digits count) or a
+    traced SMEM table (every tap pays the full recursion). Other methods
+    have no base products and record nothing."""
+    if method not in _REFMLM_METHODS:
+        return
+    baked = isinstance(passes[0][0], tuple)
+    if baked:
+        leaves = sum(const_base_mults(abs(c), nb)
+                     for taps, nb in passes for row in taps for c in row)
+        label = " ".join("/".join("_".join(map(str, row)) for row in taps)
+                         for taps, _ in passes)
+    else:
+        leaves = sum(taps.size * op_counts(nb)["base_mults"]
+                     for taps, nb in passes)
+        label = " ".join("x".join(map(str, taps.shape)) for taps, _ in passes)
+    KERNEL_METRICS.gauge("conv_tap_leaves").set(
+        leaves, kernel=kernel, coef="baked" if baked else "smem",
+        method=method, nbits="/".join(str(nb) for _, nb in passes),
+        taps=label)
+
+
 def _is_static(taps) -> bool:
     """True iff `taps` has concrete (trace-time-constant) values."""
     try:
@@ -221,9 +288,15 @@ def _unfold_batch(out: Array, n: int, h: int, ph: int) -> Array:
 
 # ---------------------------------------------------------------- single pass
 
-def _kernel(coef_ref, *refs, kh: int, kw: int, method: str, nbits: int,
-            shift: int, post: str, kcm: bool, tiled: bool, acc: str):
+def _kernel(*refs, kh: int, kw: int, method: str, nbits: int, shift: int,
+            post: str, kcm: bool, taps, tiled: bool, acc: str):
+    """`taps` -- the baked (kh, kw) int tuples; None when the first ref is
+    the coefficient operand (the SMEM table, or with `kcm` the ROM stack)."""
     *band_refs, o_ref = refs
+    tables = None
+    if taps is None:
+        coef_ref, *band_refs = band_refs
+        taps, tables = (None, coef_ref[...]) if kcm else (coef_ref, None)
     bc = o_ref.shape[-1]
     if tiled:
         # paired column-block views j / j+1: their concatenation holds the
@@ -232,17 +305,19 @@ def _kernel(coef_ref, *refs, kh: int, kw: int, method: str, nbits: int,
                                  axis=-1)[:, : bc + kw - 1] for di in range(kh)]
     else:
         bands = [band_refs[di][0] for di in range(kh)]  # each (br, bc + kw - 1)
-    tacc = accumulate_taps(bands, None if kcm else coef_ref, o_ref.shape[1:],
-                           kh=kh, kw=kw, w=bc, method=method, nbits=nbits,
-                           tables=coef_ref[...] if kcm else None,
+    tacc = accumulate_taps(bands, taps, o_ref.shape[1:], kh=kh, kw=kw, w=bc,
+                           method=method, nbits=nbits, tables=tables,
                            acc_dtype=_ACC_DTYPES[acc])
     o_ref[...] = apply_post(tacc, post=post, shift=shift)[None]
 
 
-def _pass_call(imgs: Array, coef: Array, coef_spec, kernel, *, kh: int,
-               kw: int, block_rows: int, bc: int, tiled: bool,
+def _pass_call(imgs: Array, coefs: tuple, coef_specs: tuple, kernel, *,
+               kh: int, kw: int, block_rows: int, bc: int, tiled: bool,
                interpret: bool) -> Array:
     """Shared pallas_call plumbing for one tiled convolution pass.
+
+    `coefs` -- the coefficient operand ahead of the image views (the SMEM
+    table or the KCM ROM stack), or () when the kernel bakes its taps.
 
     `bc`/`tiled` come pre-derived from `_dispatch` (the single source): the
     kernel's static band-unpacking mode must match the spec layout built
@@ -274,29 +349,38 @@ def _pass_call(imgs: Array, coef: Array, coef_spec, kernel, *, kh: int,
         out_shape=jax.ShapeDtypeStruct((n, h2, w2), jnp.int32),
         grid=grid,
         name="refmlm_conv_pass",
-        in_specs=[coef_spec, *specs],
+        in_specs=[*coef_specs, *specs],
         out_specs=pl.BlockSpec((1, br, bc), lambda nn, i, j: (nn, i, j)),
         compiler_params=grid_compiler_params(
             ("parallel", "parallel", "parallel"), interpret),
         interpret=interpret,
-    )(coef, *views)
+    )(*coefs, *views)
     return out[:, :h, :w]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "method", "nbits", "shift", "post", "block_rows", "block_cols",
+    "taps", "method", "nbits", "shift", "post", "block_rows", "block_cols",
     "batch_fold", "interpret"))
-def _conv2d_recurse(imgs, taps, *, method, nbits, shift, post, block_rows,
-                    block_cols, batch_fold, interpret):
-    kh, kw = taps.shape
-    spec = pl.BlockSpec((kh, kw), lambda nn, i, j: (0, 0),
-                        memory_space=pltpu.SMEM)
+def _conv2d_recurse(imgs, coef, *, taps, method, nbits, shift, post,
+                    block_rows, block_cols, batch_fold, interpret):
+    """`taps` -- the (kh, kw) coefficients as int tuples, baked into the
+    kernel; or None, and `coef`, the traced (kh, kw) table, rides in SMEM."""
+    if taps is None:
+        kh, kw = coef.shape
+        coefs = (coef,)
+        specs = (pl.BlockSpec((kh, kw), lambda nn, i, j: (0, 0),
+                              memory_space=pltpu.SMEM),)
+    else:
+        kh, kw = len(taps), len(taps[0])
+        coefs = specs = ()
+    _record_tap_leaves("refmlm_conv_pass", method,
+                       [(coef if taps is None else taps, nbits)])
 
     def call(x, bc, tiled):
         k = functools.partial(_kernel, kh=kh, kw=kw, method=method,
                               nbits=nbits, shift=shift, post=post, kcm=False,
-                              tiled=tiled, acc="int32")
-        return _pass_call(x, taps, spec, k, kh=kh, kw=kw,
+                              taps=taps, tiled=tiled, acc="int32")
+        return _pass_call(x, coefs, specs, k, kh=kh, kw=kw,
                           block_rows=block_rows, bc=bc, tiled=tiled,
                           interpret=interpret)
 
@@ -313,9 +397,9 @@ def _conv2d_kcm(imgs, tables, *, kh, kw, shift, post, block_rows, block_cols,
 
     def call(x, bc, tiled):
         k = functools.partial(_kernel, kh=kh, kw=kw, method="", nbits=0,
-                              shift=shift, post=post, kcm=True, tiled=tiled,
-                              acc=acc)
-        return _pass_call(x, tables, spec, k, kh=kh, kw=kw,
+                              shift=shift, post=post, kcm=True, taps=None,
+                              tiled=tiled, acc=acc)
+        return _pass_call(x, (tables,), (spec,), k, kh=kh, kw=kw,
                           block_rows=block_rows, bc=bc, tiled=tiled,
                           interpret=interpret)
 
@@ -381,8 +465,11 @@ def conv2d_pass(
                            block_cols=cfg.block_cols,
                            batch_fold=cfg.batch_fold, interpret=interpret,
                            acc=acc)
-    return _conv2d_recurse(imgs, jnp.asarray(taps, jnp.int32), method=method,
-                           nbits=nbits, shift=shift, post=post,
+    baked = _is_static(taps)
+    return _conv2d_recurse(imgs,
+                           None if baked else jnp.asarray(taps, jnp.int32),
+                           taps=_baked(taps, (kh, kw)) if baked else None,
+                           method=method, nbits=nbits, shift=shift, post=post,
                            block_rows=cfg.block_rows,
                            block_cols=cfg.block_cols,
                            batch_fold=cfg.batch_fold, interpret=interpret)
@@ -390,10 +477,14 @@ def conv2d_pass(
 
 # ------------------------------------------------------------ fused separable
 
-def _fused_kernel(row_ref, col_ref, *refs, kh: int, kw: int, method: str,
-                  nbits: int, nbits2: int, shift: int, post: str, kcm: bool,
+def _fused_kernel(*refs, kh: int, kw: int, method: str, nbits: int,
+                  nbits2: int, shift: int, post: str, kcm: bool, taps,
                   tiled: bool):
     """Both separable passes on one tile (DESIGN.md §7/§8 halo math).
+
+    `taps` -- the baked ((1, kw), (kh, 1)) int tuples of the row and column
+    filters; None when the first two refs are their coefficient operands
+    (SMEM tables, or with `kcm` the ROM stacks).
 
     The band refs are block views of the same padded image whose
     concatenation holds the (br + 2*ph, bc + 2*pw) input window feeding this
@@ -402,6 +493,14 @@ def _fused_kernel(row_ref, col_ref, *refs, kh: int, kw: int, method: str,
     accumulator never leaves VMEM.
     """
     *band_refs, o_ref = refs
+    row_tab = col_tab = None
+    if taps is None:
+        row_ref, col_ref, *band_refs = band_refs
+        if kcm:
+            row_tab, col_tab = row_ref[...], col_ref[...]
+        else:
+            taps = (row_ref, col_ref)
+    row_taps, col_taps = (None, None) if taps is None else taps
     rows, bc = o_ref.shape[1], o_ref.shape[2]
     ph, pw = kh // 2, kw // 2
     if tiled:
@@ -413,20 +512,19 @@ def _fused_kernel(row_ref, col_ref, *refs, kh: int, kw: int, method: str,
     else:
         full = jnp.concatenate((band_refs[0][0], band_refs[1][0]),
                                axis=0)[: rows + 2 * ph]
-    hacc = accumulate_taps([full], None if kcm else row_ref,
-                           (rows + 2 * ph, bc), kh=1, kw=kw, w=bc,
-                           method=method, nbits=nbits,
-                           tables=row_ref[...] if kcm else None)
+    hacc = accumulate_taps([full], row_taps, (rows + 2 * ph, bc), kh=1, kw=kw,
+                           w=bc, method=method, nbits=nbits, tables=row_tab)
     vbands = [hacc[di : di + rows] for di in range(kh)]
-    acc = accumulate_taps(vbands, None if kcm else col_ref, (rows, bc),
-                          kh=kh, kw=1, w=bc, method=method, nbits=nbits2,
-                          tables=col_ref[...] if kcm else None)
+    acc = accumulate_taps(vbands, col_taps, (rows, bc), kh=kh, kw=1, w=bc,
+                          method=method, nbits=nbits2, tables=col_tab)
     o_ref[...] = apply_post(acc, post=post, shift=shift)[None]
 
 
-def _fused_call(imgs: Array, row, col, row_spec, col_spec, kernel, *,
+def _fused_call(imgs: Array, coefs: tuple, coef_specs: tuple, kernel, *,
                 kh: int, kw: int, block_rows: int, bc: int, tiled: bool,
                 interpret: bool) -> Array:
+    """`coefs` -- the row and column coefficient operands, or () when the
+    kernel bakes its taps (as in `_pass_call`)."""
     n, h, w = imgs.shape
     br = block_rows
     ph, pw = kh // 2, kw // 2
@@ -458,31 +556,44 @@ def _fused_call(imgs: Array, row, col, row_spec, col_spec, kernel, *,
         out_shape=jax.ShapeDtypeStruct((n, h2, w2), jnp.int32),
         grid=(n, nb, ncb),
         name="refmlm_conv_fused",
-        in_specs=[row_spec, col_spec, *view_specs],
+        in_specs=[*coef_specs, *view_specs],
         out_specs=pl.BlockSpec((1, br, bc), lambda nn, i, j: (nn, i, j)),
         compiler_params=grid_compiler_params(
             ("parallel", "parallel", "parallel"), interpret),
         interpret=interpret,
-    )(row, col, *[padded] * len(view_specs))
+    )(*coefs, *[padded] * len(view_specs))
     return out[:, :h, :w]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "method", "nbits", "nbits2", "shift", "post", "block_rows", "block_cols",
-    "batch_fold", "interpret"))
-def _fused_sep_recurse(imgs, row, col, *, method, nbits, nbits2, shift, post,
-                       block_rows, block_cols, batch_fold, interpret):
-    kh, kw = col.shape[0], row.shape[1]
-    smem = functools.partial(pl.BlockSpec, index_map=lambda nn, i, j: (0, 0),
-                             memory_space=pltpu.SMEM)
+    "taps", "method", "nbits", "nbits2", "shift", "post", "block_rows",
+    "block_cols", "batch_fold", "interpret"))
+def _fused_sep_recurse(imgs, row, col, *, taps, method, nbits, nbits2, shift,
+                       post, block_rows, block_cols, batch_fold, interpret):
+    """`taps` -- the ((1, kw), (kh, 1)) row and column filters as int
+    tuples, baked into the kernel; or None, and the traced `row` (1, kw) and
+    `col` (kh, 1) tables ride in SMEM."""
+    if taps is None:
+        kh, kw = col.shape[0], row.shape[1]
+        smem = functools.partial(pl.BlockSpec,
+                                 index_map=lambda nn, i, j: (0, 0),
+                                 memory_space=pltpu.SMEM)
+        coefs, specs = (row, col), (smem((1, kw)), smem((kh, 1)))
+        passes = [(row, nbits), (col, nbits2)]
+    else:
+        kh, kw = len(taps[1]), len(taps[0][0])
+        coefs = specs = ()
+        passes = [(taps[0], nbits), (taps[1], nbits2)]
+    _record_tap_leaves("refmlm_conv_fused", method, passes)
 
     def call(x, bc, tiled):
         kernel = functools.partial(_fused_kernel, kh=kh, kw=kw, method=method,
                                    nbits=nbits, nbits2=nbits2, shift=shift,
-                                   post=post, kcm=False, tiled=tiled)
-        return _fused_call(x, row, col, smem((1, kw)), smem((kh, 1)), kernel,
-                           kh=kh, kw=kw, block_rows=block_rows, bc=bc,
-                           tiled=tiled, interpret=interpret)
+                                   post=post, kcm=False, taps=taps,
+                                   tiled=tiled)
+        return _fused_call(x, coefs, specs, kernel, kh=kh, kw=kw,
+                           block_rows=block_rows, bc=bc, tiled=tiled,
+                           interpret=interpret)
 
     return _dispatch(imgs, call, kh=kh, kw=kw, batch_fold=batch_fold,
                      block_cols=block_cols)
@@ -499,8 +610,8 @@ def _fused_sep_kcm(imgs, row_tables, col_tables, *, kh, kw, shift, post,
     def call(x, bc, tiled):
         kernel = functools.partial(_fused_kernel, kh=kh, kw=kw, method="",
                                    nbits=0, nbits2=0, shift=shift, post=post,
-                                   kcm=True, tiled=tiled)
-        return _fused_call(x, row_tables, col_tables, rspec, cspec, kernel,
+                                   kcm=True, taps=None, tiled=tiled)
+        return _fused_call(x, (row_tables, col_tables), (rspec, cspec), kernel,
                            kh=kh, kw=kw, block_rows=block_rows, bc=bc,
                            tiled=tiled, interpret=interpret)
 
@@ -555,10 +666,16 @@ def fused_separable_pass(
                               block_rows=cfg.block_rows,
                               block_cols=cfg.block_cols,
                               batch_fold=cfg.batch_fold, interpret=interpret)
-    row = jnp.asarray(row, jnp.int32).reshape(1, -1)
-    col = jnp.asarray(col, jnp.int32).reshape(-1, 1)
-    return _fused_sep_recurse(imgs, row, col, method=method, nbits=nbits,
-                              nbits2=nbits2, shift=shift, post=post,
+    if _is_static(row) and _is_static(col):
+        taps = (_baked(row, (1, kw)), _baked(col, (kh, 1)))
+        row = col = None
+    else:
+        taps = None
+        row = jnp.asarray(row, jnp.int32).reshape(1, -1)
+        col = jnp.asarray(col, jnp.int32).reshape(-1, 1)
+    return _fused_sep_recurse(imgs, row, col, taps=taps, method=method,
+                              nbits=nbits, nbits2=nbits2, shift=shift,
+                              post=post,
                               block_rows=cfg.block_rows,
                               block_cols=cfg.block_cols,
                               batch_fold=cfg.batch_fold, interpret=interpret)
@@ -582,6 +699,7 @@ __all__ = [
     "accumulate_taps",
     "apply_post",
     "choose_block_rows",
+    "KERNEL_METRICS",
     "conv2d_pass",
     "fused_separable_pass",
     "second_pass_nbits",

@@ -94,6 +94,7 @@ import threading
 import time
 from typing import Callable, Sequence
 
+from repro.filters.conv import KERNEL_METRICS
 from repro.filters.pipeline import EXEC_MODES
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import MetricsRegistry
@@ -354,8 +355,10 @@ class ImageFilterServer:
 
     def stats(self) -> dict:
         """Counters + occupancy histogram + warm-cache ledger + the
-        host -> device `transfer` counters (per pool member) + the §12
-        fault/health surface + the §13 service-level surface.
+        host -> device `transfer` counters (per pool member) + the
+        `conv_tap_leaves` gauge (base products per output pixel of each
+        REFMLM conv kernel the process built) + the §12 fault/health
+        surface + the §13 service-level surface.
 
         The request conservation counters (submitted / served / failed /
         shed / pending / rejected / tenants) are read under ONE registry
@@ -394,6 +397,10 @@ class ImageFilterServer:
         snap["compile"] = {"warmed": ex["warmed"], "hits": ex["hits"],
                            "misses": ex["misses"]}
         snap["plan_memo"] = ex["plan_memo"]
+        # process-wide, like the jit cache: every REFMLM conv kernel built
+        # so far, its series labelled coef="baked" or "smem"
+        snap["conv_tap_leaves"] = KERNEL_METRICS.snapshot()["gauges"].get(
+            "conv_tap_leaves", {})
         if "pool" in ex:
             snap["pool"] = ex["pool"]
         if self._controller is not None:

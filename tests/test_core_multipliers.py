@@ -8,7 +8,9 @@ from repro.core.lns import decode, encode, lns_multiply
 from repro.core.mitchell import (babic_bb, babic_ecc, mitchell,
                                  mitchell_corrected, mitchell_residual_operands)
 from repro.core.odma import odma, odma_exact_identity
-from repro.core.refmlm import efmlm2, mlm2, refmlm
+from repro.core.refmlm import (const_base_mults, efmlm2, mlm2, refmlm,
+                               refmlm_const)
+from repro.filters import FILTER_NAMES, get_filter
 
 A8 = jnp.arange(256, dtype=jnp.int32)[:, None]
 B8 = jnp.arange(256, dtype=jnp.int32)[None, :]
@@ -69,6 +71,69 @@ class TestREFMLM:
         p = refmlm(a, b, 16).astype(jnp.uint32)
         true = (a.astype(jnp.uint32) * b.astype(jnp.uint32))
         assert bool((p == true).all())
+
+
+#: every |coefficient| the filter bank multiplies by, 2-D and separable
+BANK_COEFFS = sorted({abs(int(c)) for name in FILTER_NAMES
+                      for taps in (get_filter(name).taps,
+                                   get_filter(name).sep_row,
+                                   get_filter(name).sep_col)
+                      if taps is not None for c in np.ravel(taps)})
+
+
+def _assert_const_equals_runtime(a, c, nbits, base):
+    want = refmlm(a, jnp.full_like(a, c), nbits, base=base)
+    got = refmlm_const(a, c, nbits, base=base)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                  err_msg=f"c={c} nbits={nbits} base={base}")
+
+
+class TestREFMLMConst:
+    """`refmlm_const` (a constant coefficient, zero-digit leaves dropped)
+    is the runtime kom4 `refmlm` bit for bit, for both bases."""
+
+    @pytest.mark.parametrize("base", ["efmlm", "mlm"])
+    @pytest.mark.parametrize("nbits", [2, 4, 8])
+    def test_every_pair(self, nbits, base):
+        a, b = _grid(nbits)
+        want = np.asarray(refmlm(a, b, nbits, base=base))
+        col = a[:, 0]
+        for c in range(1 << nbits):
+            np.testing.assert_array_equal(
+                np.asarray(refmlm_const(col, c, nbits, base=base)),
+                want[:, c], err_msg=f"c={c}")
+
+    @pytest.mark.parametrize("base", ["efmlm", "mlm"])
+    def test_16bit_every_a_for_each_bank_coefficient(self, base):
+        a = jnp.arange(1 << 16, dtype=jnp.int32)
+        for c in BANK_COEFFS:
+            _assert_const_equals_runtime(a, c, 16, base)
+
+    @pytest.mark.parametrize("base", ["efmlm", "mlm"])
+    def test_16bit_seeded_sample_of_c(self, base):
+        rng = np.random.default_rng(16)
+        a = jnp.arange(1 << 16, dtype=jnp.int32)
+        for c in rng.integers(0, 1 << 16, 24):
+            _assert_const_equals_runtime(a, int(c), 16, base)
+
+    @pytest.mark.parametrize("base", ["efmlm", "mlm"])
+    @pytest.mark.parametrize("nbits", [2, 4])
+    def test_operands_wider_than_nbits(self, nbits, base):
+        """8-bit pixels and coefficients through a 2- or 4-bit multiplier
+        (the camera control runs REFMLM at 4 bits): the constant path
+        masks both operands exactly as the runtime recursion does."""
+        a = jnp.arange(256, dtype=jnp.int32)
+        for c in range(64):
+            _assert_const_equals_runtime(a, c, nbits, base)
+
+    @pytest.mark.parametrize("nbits", [2, 4, 8, 16])
+    def test_base_mults_count_the_non_zero_digits(self, nbits):
+        for c in (0, 1, 2, 3, 4, 6, 36, 160, 255, 4095, 65535):
+            digits = [(c >> s) & 3 for s in range(0, nbits, 2)]
+            want = (int(c != 0) if nbits == 2
+                    else sum(map(bool, digits)) * nbits // 2)
+            assert const_base_mults(c, nbits) == want, c
 
 
 class TestMitchellFamily:

@@ -56,6 +56,7 @@ STATS_KEYS = {
     "flush_reasons", "served_priority", "pending", "pressure",
     "rejected", "tenants", "compile", "plan_memo", "healthy", "state",
     "degraded", "isolated", "retries", "dispatch_failures",
+    "conv_tap_leaves",
 }
 
 

@@ -11,6 +11,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -115,6 +116,57 @@ def test_auto_never_takes_the_kcm_gather(one_chip, name):
     assert_mosaic(compiled_text(
         lambda x: apply_filter(x, name, interpret=False),
         one_chip, ((2, 64, 128), jnp.uint8)))
+
+
+def coef_operands(text: str, kernel: str) -> list[str]:
+    """The rank-2 operands (coefficient tables) of the `kernel` custom call:
+    image views are rank 3, so a kernel with baked taps has none."""
+    line = next(ln for ln in text.splitlines()
+                if f"%{kernel}" in ln and "custom-call(" in ln)
+    shapes = re.search(r"operand_layout_constraints=\{(.*?\})\}", line)[1]
+    return re.findall(r"s32\[\d+,\d+\]", shapes)
+
+
+SERVED = {"gaussian5": (8, 480, 640), "gaussian3": (1, 1080, 1920),
+          "sobel_x": (8, 480, 640)}
+
+
+@pytest.mark.parametrize("name", list(SERVED))
+@pytest.mark.parametrize("kind", ["direct", "fused"])
+def test_baked_taps_compile_under_the_kernel_names(one_chip, name, kind):
+    """Concrete taps are baked into the kernel: no coefficient operand
+    reaches the custom call, whose name the device trace reads."""
+    spec = get_filter(name)
+    if kind == "direct":
+        kernel = "refmlm_conv_pass"
+        fn = lambda x: conv2d_pass(x, spec.taps, method="refmlm",
+                                   shift=spec.shift, post=spec.post,
+                                   interpret=False)
+    else:
+        kernel = "refmlm_conv_fused"
+        fn = lambda x: fused_separable_pass(x, spec.sep_row, spec.sep_col,
+                                            method="refmlm", nbits2=16,
+                                            shift=spec.shift, post=spec.post,
+                                            interpret=False)
+    text = compiled_text(fn, one_chip, (SERVED[name], jnp.int32))
+    assert_mosaic(text)
+    assert coef_operands(text, kernel) == []
+
+
+@pytest.mark.parametrize("kind", ["direct", "fused"])
+def test_traced_taps_compile_on_the_smem_path(one_chip, kind):
+    """Taps passed as jit arguments stay run-time SMEM operands."""
+    if kind == "direct":
+        kernel, taps = "refmlm_conv_pass", [((3, 3), jnp.int32)]
+        fn = lambda x, t: conv2d_pass(x, t, method="refmlm", interpret=False)
+        want = ["s32[3,3]"]
+    else:
+        kernel, taps = "refmlm_conv_fused", [((3,), jnp.int32)] * 2
+        fn = lambda x, r, c: fused_separable_pass(x, r, c, method="refmlm",
+                                                  nbits2=16, interpret=False)
+        want = ["s32[1,3]", "s32[3,1]"]
+    text = compiled_text(fn, one_chip, ((8, 480, 640), jnp.int32), *taps)
+    assert coef_operands(text, kernel) == want
 
 
 @pytest.mark.parametrize("name", ["refmlm_conv_pass", "refmlm_conv_fused",
