@@ -3,8 +3,8 @@
 Everything that belongs to one configuration, traffic mix or metric is a
 file of its own, found by the name `BENCHMARK.json` gives it:
 
-- `configs/<config>.json` -- the deployment: image shapes, filters with
-  their taps, method, server settings, chips;
+- `configs/<config>.json` -- the deployment: image shapes and bit depth,
+  filters with their taps, method, server settings, chips;
 - `traffic/<mix>.json` -- parameters of the one general generator
   (`loadgen.py`);
 - `metrics/<metric>.py` -- a reader `read(run) -> float | None` over the
@@ -13,7 +13,8 @@ file of its own, found by the name `BENCHMARK.json` gives it:
 A run builds one `ImageFilterServer`, warms up the cell's own shapes,
 filters and batch sizes, drives the traffic through `submit` for the
 window, drains, and then checks a seeded sample of the answers against the
-plain reference (`reference.py`).
+plain reference of the configuration's method and bit depth
+(`reference.py`).
 """
 from __future__ import annotations
 
@@ -34,7 +35,7 @@ import numpy as np
 import devtrace
 import inputs
 import loadgen
-from reference import filter_ref, kernel_size
+from reference import CORRELATE, filter_ref, kernel_size, pixel_dtype
 
 CHIPBENCH = Path(__file__).resolve().parent
 REPO = CHIPBENCH.parent
@@ -52,8 +53,8 @@ class CellError(Exception):
 
 
 class DeviceError(Exception):
-    """The machine lacks what the cell needs: a TPU, enough chips, or peaks
-    for its device kind."""
+    """The machine lacks what the cell needs: a TPU, enough chips, peaks
+    for its device kind, or the allocator settings its traffic names."""
 
 
 # ------------------------------------------------------------------ spec
@@ -98,9 +99,41 @@ def load_cell(name: str, spec: dict | None = None, *, repo: Path = REPO,
         config = json.load(f)
     with open(traffic_file, encoding="utf-8") as f:
         traffic = json.load(f)
+    if config["method"] not in CORRELATE:
+        raise CellError(f"cell {name!r}: config {cell['config']!r} names "
+                        f"method {config['method']!r}, which has no plain "
+                        f"reference; methods with one: {sorted(CORRELATE)}")
+    try:
+        pixel_dtype(config["bit_depth"])
+    except ValueError as err:
+        raise CellError(f"cell {name!r}: {err}") from None
+    for key, value in traffic.get("malloc", {}).items():
+        if key not in MALLOPT or not 0 <= int(value) < 1 << 31:
+            raise CellError(f"cell {name!r}: traffic {cell['traffic']!r} "
+                            f"sets malloc {key}={value!r}; known: "
+                            f"{sorted(MALLOPT)}, each 0 to 2**31 - 1")
     return Cell(name, config, traffic, int(cell["chips"]),
                 [m for m in spec["end_to_end"] if applies(m, name)],
                 [m for m in spec["per_layer"] if applies(m, name)])
+
+
+#: glibc `mallopt` parameters a traffic mix may set under "malloc".
+MALLOPT = {"trim_threshold": -1, "top_pad": -2, "mmap_threshold": -3}
+
+
+def set_malloc(params: dict) -> None:
+    """Apply a traffic mix's allocator settings to this process through
+    glibc's `mallopt`, before set-up allocates; DeviceError where the C
+    library lacks it or refuses one."""
+    import ctypes
+
+    libc = ctypes.CDLL(None)
+    if params and not hasattr(libc, "mallopt"):
+        raise DeviceError("the traffic sets malloc parameters, and this "
+                          "C library has no mallopt")
+    for key, value in params.items():
+        if libc.mallopt(MALLOPT[key], int(value)) != 1:
+            raise DeviceError(f"mallopt refused {key}={value}")
 
 
 def load_reader(metric: str, *, bench: Path = CHIPBENCH):
@@ -151,10 +184,15 @@ class Kind:
     filter: str
     spec: dict
     shape: tuple[int, int]
+    dtype: np.dtype = np.dtype(np.uint8)   # the pixels' container
 
     @property
     def pixels(self) -> int:
         return self.shape[0] * self.shape[1]
+
+    @property
+    def bytes_per_px(self) -> int:
+        return self.dtype.itemsize
 
     @property
     def ksize(self) -> tuple[int, int]:
@@ -177,7 +215,7 @@ class RunRecord:
     events: list[dict] | None      # obs trace events (traced runs)
     device: devtrace.Reduction | None
     peak: dict                     # the chip's published peaks
-    pools: dict                    # image name -> (count, H, W) uint8
+    pools: dict                    # image name -> (count, H, W) pixels
 
     def latencies_ms(self) -> list[float]:
         """done - due of every request, failed ones at their failure."""
@@ -196,8 +234,9 @@ class RunRecord:
 
 
 class Answers:
-    """Every answer's shape is checked as it comes; a seeded reservoir keeps
-    `k` answers for the comparison with the reference after the window."""
+    """Every answer's shape and dtype (the kind's pixel container) is
+    checked as it comes; a seeded reservoir keeps `k` answers for the
+    comparison with the reference after the window."""
 
     def __init__(self, kinds: list[Kind], k: int, seed: int) -> None:
         self.kinds = kinds
@@ -212,7 +251,7 @@ class Answers:
         kind = self.kinds[req.kind]
         out = np.asarray(out)
         with self._lock:
-            if out.shape != kind.shape or out.dtype != np.uint8:
+            if out.shape != kind.shape or out.dtype != kind.dtype:
                 self.malformed += 1
                 return
             self.seen += 1
@@ -262,13 +301,14 @@ def memory_peak(devices) -> int:
 
 def resolve_kinds(cell: Cell) -> list[Kind]:
     cfg = cell.config
+    dtype = pixel_dtype(cfg["bit_depth"])
     kinds = []
     for m in cell.traffic["mix"]:
         if m["image"] not in cfg["images"] or m["filter"] not in cfg["filters"]:
             raise CellError(f"mix entry {m} names an image or filter that "
                             f"config {cfg['name']!r} does not state")
         kinds.append(Kind(m["image"], m["filter"], cfg["filters"][m["filter"]],
-                          tuple(cfg["images"][m["image"]])))
+                          tuple(cfg["images"][m["image"]]), dtype))
     return kinds
 
 
@@ -284,12 +324,15 @@ def server_config(cell: Cell, trace: bool):
 
 def multiplier(cell: Cell, control: bool = False) -> dict:
     """The multiplier the cell serves with: the configuration's method at
-    the pixels' bit depth, or with `control` the configuration's control
-    (a path of the program that breaks the exactness it states)."""
+    the pixels' bit depth up to 8 bits, and at 16 bits, the width of
+    their container, above; or with `control` the configuration's control
+    (a path of the program that breaks the guarantee it states)."""
     cfg = cell.config
     if control:
         return dict(cfg["control"])
-    return {"method": cfg["method"], "nbits": int(cfg["bit_depth"])}
+    bit_depth = int(cfg["bit_depth"])
+    return {"method": cfg["method"],
+            "nbits": bit_depth if bit_depth <= 8 else 16}
 
 
 def drive(srv, cell: Cell, kinds: list[Kind], pools: dict, mult: dict,
@@ -333,7 +376,8 @@ def prepare(cell: Cell, seed: int, mult: dict, *, trace: bool = False):
     kinds = resolve_kinds(cell)
     names = sorted({k.image for k in kinds})
     pools = inputs.image_pools({n: cfg["images"][n] for n in names},
-                               int(cfg["distinct_images"]), seed)
+                               int(cfg["distinct_images"]), seed,
+                               int(cfg["bit_depth"]))
     srv = ImageFilterServer(server_config(cell, trace))
     try:
         for shape, filt in sorted({(k.shape, k.filter) for k in kinds}):
@@ -409,7 +453,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, *, trace: bool = False,
 def check_answers(record: RunRecord, answers: Answers) -> dict:
     """The numbers compared, each with its limit: every request answered,
     every answer well formed, and the sampled answers equal to the plain
-    reference pixel for pixel."""
+    reference of the configuration's method and bit depth, pixel for
+    pixel."""
+    cfg = record.cell.config
     refs: dict[tuple[int, int], np.ndarray] = {}
     mismatched = 0
     for req, out in answers.sample:
@@ -417,7 +463,8 @@ def check_answers(record: RunRecord, answers: Answers) -> dict:
         if key not in refs:
             kind = record.kinds[req.kind]
             refs[key] = filter_ref(record.pools[kind.image][req.image],
-                                   kind.spec)
+                                   kind.spec, method=cfg["method"],
+                                   bit_depth=int(cfg["bit_depth"]))
         mismatched += int(np.count_nonzero(out != refs[key]))
     unanswered = sum(1 for r in record.requests if not r.ok)
     return {

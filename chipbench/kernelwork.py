@@ -1,7 +1,8 @@
 """The work a filter needs, whatever implements it, and its least time.
 
-A filter over one 8-bit pixel reads one byte and writes one byte, and
-takes kh*kw multiplies and as many adds: 2*kh*kw operations. Pad images,
+A filter over one pixel reads it and writes one in the container of the
+configuration's bit depth (1 byte up to 8 bits, else 2), and takes kh*kw
+multiplies and as many adds: 2*kh*kw operations. Pad images,
 halos, intermediate passes and the width of the implementation's integer
 types are not work the user asked for, so they are not counted. The least
 time of a piece of work is the larger of its bytes over the HBM peak and
@@ -22,10 +23,11 @@ def load_peaks(path: Path = PEAKS_FILE) -> dict:
         return json.load(f)
 
 
-def filter_work(pixels: int, kh: int, kw: int) -> tuple[float, float]:
-    """(operations, bytes) of filtering `pixels` 8-bit pixels with a kh x kw
-    filter."""
-    return 2.0 * kh * kw * pixels, 2.0 * pixels
+def filter_work(pixels: int, kh: int, kw: int,
+                bytes_per_px: int = 1) -> tuple[float, float]:
+    """(operations, bytes) of filtering `pixels` pixels of `bytes_per_px`
+    bytes each with a kh x kw filter."""
+    return 2.0 * kh * kw * pixels, 2.0 * bytes_per_px * pixels
 
 
 def least_time(ops: float, nbytes: float, peak: dict) -> tuple[float, str]:
@@ -45,6 +47,7 @@ def roofline_pct(run, group: str) -> float | None:
     for r in run.requests:
         if r.ok:
             kind = run.kinds[r.kind]
-            ops, nbytes = filter_work(kind.pixels, *kind.ksize)
+            ops, nbytes = filter_work(kind.pixels, *kind.ksize,
+                                      kind.bytes_per_px)
             least += least_time(ops, nbytes, run.peak)[0]
     return 100.0 * least / run.device.kernel_s[group]

@@ -60,6 +60,7 @@ def main(argv=None) -> int:
         return 2
     peaks = kernelwork.load_peaks()
     try:
+        harness.set_malloc(cell.traffic.get("malloc", {}))
         devices = harness.check_device(cell.chips, peaks)
     except harness.DeviceError as err:
         print(f"chipbench: {err}", file=sys.stderr)

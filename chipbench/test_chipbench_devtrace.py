@@ -145,13 +145,16 @@ def test_least_time_can_be_ops_bound():
 
 
 def test_roofline_pct_counts_answered_requests_only():
-    kind = SimpleNamespace(pixels=1000, ksize=(5, 5))
+    kind = SimpleNamespace(pixels=1000, ksize=(5, 5), bytes_per_px=1)
     reqs = [SimpleNamespace(ok=True, kind=0), SimpleNamespace(ok=False, kind=0)]
     peak = {"int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9}
     run = SimpleNamespace(requests=reqs, kinds=[kind], peak=peak,
                           device=SimpleNamespace(kernel_s={"conv": 1e-6}))
     want = 100 * (2000 / 819e9) / 1e-6
     assert kernelwork.roofline_pct(run, "conv") == pytest.approx(want)
+    kind.bytes_per_px = 2                    # 16-bit containers: twice
+    assert kernelwork.roofline_pct(run, "conv") == pytest.approx(2 * want)
+    kind.bytes_per_px = 1
     run.device.kernel_s = {"conv": 0.0}
     assert kernelwork.roofline_pct(run, "conv") is None
     run.device = None

@@ -2,7 +2,7 @@
 is not. Each case drives a whole run of a cell -- server, warm-up, traffic,
 drain, comparison with the plain reference -- on the CPU at a size a test
 run can hold, past the harness's look for a chip. The control serves with
-a path of the program that breaks the exactness the configuration
+a path of the program that breaks the guarantee the configuration
 states; the faults are
 planted under the server, in the filter dispatch every request goes
 through."""
@@ -41,7 +41,8 @@ def run(cell: harness.Cell, seed: int = 2**32 + 5, **kw) -> dict:
 
 @pytest.mark.parametrize("name", ["fvc2004.enrol_backlog",
                                   "fvc2004.verify_open", "camera1080.stream",
-                                  "fvc2004_pool4.verify_open"])
+                                  "fvc2004_pool4.verify_open",
+                                  "fvc2004_mitchell.enrol_backlog"])
 def test_sound_run_is_correct(name):
     line = run(tiny(name))
     assert line["correct"], line["checks"]
@@ -53,11 +54,13 @@ def test_sound_run_is_correct(name):
 
 
 @pytest.mark.parametrize("name", ["fvc2004.enrol_backlog",
-                                  "camera1080.stream"])
+                                  "camera1080.stream",
+                                  "fvc2004_mitchell.enrol_backlog"])
 def test_control_is_not_correct(name):
     """fvc2004's control is the Mitchell multiplier; camera1080's gaussian3
     taps are powers of two, on which Mitchell is exact, so its control is
-    REFMLM at 4 bits (int4 for int8)."""
+    REFMLM at 4 bits (int4 for int8); fvc2004_mitchell's is exact REFMLM,
+    which differs from Mitchell on gaussian5's tap 6."""
     line = run(tiny(name), control=True)
     assert not line["correct"]
     assert line["checks"]["mismatched_px"]["value"] > 0
@@ -98,6 +101,9 @@ def _altered(imgs, outs):
     ("camera1080.stream", _altered),
     ("fvc2004.verify_open", _altered),
     ("fvc2004_pool4.verify_open", _altered),
+    ("fvc2004_mitchell.enrol_backlog", _unchanged),
+    ("fvc2004_mitchell.enrol_backlog", _half_left_out),
+    ("fvc2004_mitchell.enrol_backlog", _altered),
 ], ids=lambda v: v if isinstance(v, str) else v.__name__.strip("_"))
 def test_planted_fault_is_not_correct(monkeypatch, name, fault):
     import repro.serve.workload as workload
@@ -128,3 +134,29 @@ def test_reference_matches_the_program_oracle():
                                             method="exact"))
         got = np.stack([filter_ref(im, spec) for im in imgs])
         assert (got == want).all() and (got == exact).all(), filt
+
+
+def test_mitchell_reference_matches_the_program_oracle():
+    """The plain Mitchell reference (eq. 8, row pass then column pass) and
+    the program's own jnp oracle agree on every filter fvc2004_mitchell
+    states, all-0 and all-255 images among them; gaussian5's tap 6 makes
+    it differ from exact filtering."""
+    import jax.numpy as jnp
+    from repro.filters.ref import apply_filter_ref
+
+    rng = np.random.default_rng(1)
+    imgs = rng.integers(0, 256, (4, 19, 23), dtype=np.uint8)
+    imgs[0] = 0
+    imgs[1] = 255
+    cell = harness.load_cell("fvc2004_mitchell.enrol_backlog")
+    assert cell.config["method"] == "mitchell"
+    for filt, spec in cell.config["filters"].items():
+        want = np.asarray(apply_filter_ref(jnp.asarray(imgs), filt,
+                                           method="mitchell"))
+        got = np.stack([filter_ref(im, spec, method="mitchell")
+                        for im in imgs])
+        assert got.dtype == np.uint8
+        assert (got == want).all(), filt
+    exact = filter_ref(imgs[2], cell.config["filters"]["gaussian5"])
+    assert (filter_ref(imgs[2], cell.config["filters"]["gaussian5"],
+                       method="mitchell") != exact).any()

@@ -119,6 +119,163 @@ def test_a_new_cell_is_new_files_only(tmp_path):
     assert kinds[0].shape == (364, 328) and kinds[0].ksize == (3, 3)
 
 
+def _files_only_repo(tmp_path) -> tuple[Path, Path, dict]:
+    """A copy of the benchmark's data files and spec under `tmp_path`."""
+    repo = tmp_path / "repo"
+    bench = repo / "chipbench"
+    for sub in ("configs", "traffic", "metrics"):
+        shutil.copytree(CHIPBENCH / sub, bench / sub)
+    return repo, bench, json.loads(json.dumps(SPEC))
+
+
+def _ct12_cell(tmp_path) -> harness.Cell:
+    """A 12-bit deployment stored in 16-bit containers and served on the
+    16-bit multiplier, added as files and entries only."""
+    repo, bench, spec = _files_only_repo(tmp_path)
+    (bench / "configs" / "ct12.json").write_text(json.dumps({
+        "name": "ct12", "bit_depth": 12,
+        "images": {"slice": [24, 32]}, "distinct_images": 2,
+        "filters": {"sobel_x": {"row": [-1, 0, 1], "col": [1, 2, 1],
+                                "shift": 0, "post": "abs"}},
+        "method": "refmlm", "control": {"method": "refmlm", "nbits": 8},
+        "server": {"max_batch": 2, "max_delay_ms": 2.0},
+        "warm_batches": [1, 2]}))
+    (bench / "traffic" / "ct_backlog.json").write_text(json.dumps({
+        "loop": "closed", "in_flight": 2, "check_sample": 8,
+        "mix": [{"image": "slice", "filter": "sobel_x", "share": 1}]}))
+    spec["configs"].append({"name": "ct12", "source": "new",
+                            "file": "chipbench/configs/ct12.json",
+                            "reduced": [], "why": "new"})
+    spec["workloads"].append({"name": "ct12.backlog", "config": "ct12",
+                              "traffic": "ct_backlog", "chips": 1,
+                              "why": "new"})
+    for m in spec["end_to_end"]:
+        if m["name"] == "mpix_per_s":
+            m["workloads"].append("ct12.backlog")
+    (repo / "BENCHMARK.json").write_text(json.dumps(spec))
+    return harness.load_cell("ct12.backlog", repo=repo, bench=bench)
+
+
+def test_a_12_bit_cell_is_new_files_only(tmp_path):
+    """Pixels wider than 8 bits come from the configuration alone: pools,
+    reference, answer dtype, roofline bytes and multiplier width."""
+    import inputs
+    import kernelwork
+    from reference import filter_ref
+
+    cell = _ct12_cell(tmp_path)
+    assert harness.multiplier(cell) == {"method": "refmlm", "nbits": 16}
+    (kind,) = harness.resolve_kinds(cell)
+    assert kind.dtype == np.uint16 and kind.bytes_per_px == 2
+    ops, nbytes = kernelwork.filter_work(kind.pixels, *kind.ksize,
+                                         kind.bytes_per_px)
+    assert nbytes == 4 * kind.pixels and ops == 18 * kind.pixels
+
+    pools = inputs.image_pools(cell.config["images"], 2, 2**33 + 1,
+                               cell.config["bit_depth"])
+    pool = pools["slice"]
+    assert pool.dtype == np.uint16 and pool.shape == (2, 24, 32)
+    assert pool.max() == 4095 and (pool > 255).any()
+
+    ramp = np.broadcast_to(np.arange(32, dtype=np.uint16) * 128, (24, 32))
+    out = filter_ref(ramp, kind.spec, bit_depth=12)
+    assert out.dtype == np.uint16
+    assert out[5, 5] == 4 * 256               # inside: kept above 255
+    assert out[5, 31] == 4095                 # 4 x 3840 at the edge: clipped
+    assert (filter_ref(pool[0], kind.spec, bit_depth=12) > 255).any()
+
+    answers = harness.Answers([kind], 4, seed=1)
+    answers(loadgen.Request(0, 0, 0, 0.0), np.zeros((24, 32), np.uint8))
+    answers(loadgen.Request(1, 0, 0, 0.0), np.zeros((24, 32), np.uint16))
+    assert answers.malformed == 1 and answers.seen == 1
+
+
+def test_a_12_bit_cell_on_an_8_bit_program_is_not_correct(tmp_path):
+    """Today's program answers in uint8 whatever the pixels' width, so a
+    12-bit cell run on it reads `correct` false."""
+    cell = _ct12_cell(tmp_path)
+    line = harness.run_cell(cell, 2**32 + 3, 0.3,
+                            peak={"int8_ops_per_s": 393e12,
+                                  "hbm_bytes_per_s": 819e9})
+    assert not line["correct"]
+    assert line["checks"]["malformed"]["value"] > 0
+
+
+def test_a_method_without_a_reference_is_refused(tmp_path):
+    repo, bench, spec = _files_only_repo(tmp_path)
+    cfg = json.loads((bench / "configs" / "fvc2004.json").read_text())
+    (bench / "configs" / "fvc2004.json").write_text(
+        json.dumps(dict(cfg, method="babic_bb1")))
+    (repo / "BENCHMARK.json").write_text(json.dumps(spec))
+    with pytest.raises(harness.CellError, match="babic_bb1"):
+        harness.load_cell("fvc2004.enrol_backlog", repo=repo, bench=bench)
+
+
+@pytest.mark.parametrize("malloc", [{"arena_max": 2},
+                                    {"trim_threshold": 1 << 31}])
+def test_a_mix_names_only_known_malloc_settings(tmp_path, malloc):
+    repo, bench, spec = _files_only_repo(tmp_path)
+    mix = json.loads((bench / "traffic" / "enrol_backlog.json").read_text())
+    (bench / "traffic" / "enrol_backlog.json").write_text(
+        json.dumps(dict(mix, malloc=malloc)))
+    (repo / "BENCHMARK.json").write_text(json.dumps(spec))
+    with pytest.raises(harness.CellError, match="malloc"):
+        harness.load_cell("fvc2004.enrol_backlog", repo=repo, bench=bench)
+
+
+def test_the_enrolment_mix_sets_the_allocator_before_set_up():
+    """Both enrolment cells keep freed buffers for reuse; the setting is
+    applied in a process of its own, as `run.py` applies it."""
+    for name in ("fvc2004.enrol_backlog", "fvc2004_mitchell.enrol_backlog"):
+        assert harness.load_cell(name).traffic["malloc"] == {
+            "mmap_threshold": 1 << 25, "trim_threshold": (1 << 31) - 1,
+            "top_pad": 1 << 28}
+    code = ("import harness; harness.set_malloc(harness.load_cell("
+            "'fvc2004.enrol_backlog').traffic['malloc']); print('set')")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=CHIPBENCH,
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode == 0 and proc.stdout.strip() == "set", proc.stderr
+
+
+#: sha256 over the four first cells' image pools (2 images of each shape
+#: at seed 2**33 + 7), the reference output of each kind on the second
+#: image, and each kind's `filter_work`, as the 8-bit harness made them.
+EIGHT_BIT_DIGEST = ("18a1f816145b9201cbcd1d694045125966c2619d3d8e0d0d"
+                    "36097e04386626b0")
+
+
+def test_8_bit_pools_references_and_work_are_unchanged():
+    import hashlib
+
+    import inputs
+    import kernelwork
+    from reference import filter_ref
+
+    digest = hashlib.sha256()
+    for name in ("fvc2004.enrol_backlog", "fvc2004.verify_open",
+                 "camera1080.stream", "fvc2004_pool4.verify_open"):
+        cell = harness.load_cell(name)
+        cfg = cell.config
+        kinds = harness.resolve_kinds(cell)
+        names = sorted({k.image for k in kinds})
+        pools = inputs.image_pools({n: cfg["images"][n] for n in names}, 2,
+                                   2**33 + 7, cfg["bit_depth"])
+        for n in names:
+            p = pools[n]
+            digest.update(f"{name}/{n}/{p.dtype}/{p.shape}".encode())
+            digest.update(p.tobytes())
+        for k in kinds:
+            out = filter_ref(pools[k.image][1], k.spec, method=cfg["method"],
+                             bit_depth=cfg["bit_depth"])
+            digest.update(f"{k.filter}/{out.dtype}/{out.shape}".encode())
+            digest.update(out.tobytes())
+            digest.update(repr(kernelwork.filter_work(
+                k.pixels, *k.ksize, k.bytes_per_px)).encode())
+    assert digest.hexdigest() == EIGHT_BIT_DIGEST
+
+
 def test_unknown_cell_is_refused():
     with pytest.raises(harness.CellError):
         harness.load_cell("no_such.cell")
