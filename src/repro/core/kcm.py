@@ -33,12 +33,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.mitchell import babic_ecc as _babic_ecc
+from repro.core.mitchell import MAX_NBITS
 from repro.core.mitchell import mitchell as _mitchell
 from repro.core.odma import odma as _odma
 from repro.core.refmlm import refmlm as _refmlm
+from repro.core.refmlm import SUPPORTED_WIDTHS
 from repro.core.refmlm import refmlm_const as _refmlm_const
 
 METHODS = ("exact", "refmlm", "refmlm_nc", "mitchell", "odma")  # + mitchell_ecc{k}
+
+#: widest multiplier a ROM is built for: 2**16 entries a coefficient. A
+#: 32-bit pass (the column pass of 16-bit pixels) has no ROM.
+KCM_MAX_NBITS = 16
 
 
 def tap_multiplier(method: str):
@@ -57,6 +63,19 @@ def tap_multiplier(method: str):
     if method == "odma":
         return lambda a, b, nbits: _odma(a, b, nbits).astype(jnp.int32)
     raise ValueError(f"unknown multiplier method {method!r}")
+
+
+def method_max_nbits(method: str) -> int | None:
+    """The widest multiplier `method` has: REFMLM's 32-bit recursion, the
+    paper's 16 for the Mitchell family and ODMA, and None for 'exact',
+    whose int32 product is exact wherever the conv's accumulator bound
+    holds."""
+    tap_multiplier(method)                   # unknown methods fail here
+    if method == "exact":
+        return None
+    if method in ("refmlm", "refmlm_nc"):
+        return max(SUPPORTED_WIDTHS)
+    return MAX_NBITS
 
 
 def const_tap_multiplier(method: str):
@@ -87,6 +106,9 @@ def product_table(method: str, coeff: int, nbits: int) -> np.ndarray:
     (e.g. under `shard_map` in the distributed path, DESIGN.md §9, where
     ops on constants would otherwise become tracers).
     """
+    if nbits > KCM_MAX_NBITS:
+        raise ValueError(f"a KCM ROM has at most {KCM_MAX_NBITS}-bit "
+                         f"operands, got {nbits}")
     mult = tap_multiplier(method)
     with jax.ensure_compile_time_eval():
         xs = jnp.arange(1 << nbits, dtype=jnp.int32)
@@ -122,5 +144,6 @@ def tables_acc_bound(tables: np.ndarray) -> int:
     return int(np.abs(np.asarray(tables, np.int64)).max(axis=-1).sum())
 
 
-__all__ = ["METHODS", "const_tap_multiplier", "filter_tables", "product_table",
+__all__ = ["KCM_MAX_NBITS", "METHODS", "const_tap_multiplier",
+           "filter_tables", "method_max_nbits", "product_table",
            "tables_acc_bound", "tap_multiplier"]

@@ -35,13 +35,16 @@ from repro.core.bitops import leading_one_position
 MAX_NBITS = 16
 
 
-def _check_width(nbits: int) -> None:
-    if not (2 <= nbits <= MAX_NBITS):
-        raise ValueError(f"nbits must be in [2, {MAX_NBITS}], got {nbits}")
+def _check_width(nbits: int, max_nbits: int = MAX_NBITS) -> None:
+    """`nbits` within [2, `max_nbits`]: Mitchell's family stops at the
+    paper's 16; REFMLM passes its own 32 (`repro.core.refmlm`)."""
+    if not (2 <= nbits <= max_nbits):
+        raise ValueError(f"nbits must be in [2, {max_nbits}], got {nbits}")
 
 
 def _prod_dtype(nbits: int):
-    # 2*nbits-bit products: int32 lanes while they fit, else uint32.
+    # 2*nbits-bit products: int32 lanes while they fit, else uint32 --
+    # exact up to 16 bits, modulo 2**32 above (REFMLM's 32-bit width).
     return jnp.int32 if 2 * nbits <= 31 else jnp.uint32
 
 

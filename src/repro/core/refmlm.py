@@ -22,8 +22,13 @@ Base variants:
   mlm   -- uncorrected 2x2 Mitchell  => error propagates through the
            recursion ('Proposed Without Error Correction', AER 1.76% @ 4x4).
 
-Widths: nbits in {2, 4, 8, 16} (paper max is 16x16). Products are exact in
-uint32 lanes at 16 bits, so no x64 mode is required.
+Widths: nbits in {2, 4, 8, 16, 32}. The paper's largest is 16x16; 32x32 is
+the same radix-2 recursion one order up, built from the same 2x2 base, for
+the column pass of 16-bit pixels (DESIGN.md §5). Products are carried in
+uint32 lanes from 16 bits up, so no x64 mode is required: exact at 16
+bits, and modulo 2**32 at 32 bits -- exact wherever the true product is
+below 2**32. A leaf whose weight is 2**32 or more contributes 0 modulo
+2**32 and is never evaluated.
 
 Evaluation strategies (DESIGN.md §7):
 
@@ -54,7 +59,10 @@ from jax import Array
 from repro.core.bitops import split_halves
 from repro.core.mitchell import _check_width, _prod_dtype
 
-SUPPORTED_WIDTHS = (2, 4, 8, 16)
+SUPPORTED_WIDTHS = (2, 4, 8, 16, 32)
+
+#: the product lanes' modulus from 16 bits up (uint32, `_prod_dtype`).
+_LANE = 1 << 32
 
 
 def mlm2(a: Array, b: Array | int) -> Array:
@@ -115,35 +123,36 @@ def efmlm2(a: Array, b: Array | int) -> Array:
 
 
 def _recurse(a: Array, b: Array, nbits: int, base_fn, variant: str) -> Array:
-    """Exact-structure KOM recursion; returns the 2*nbits-bit product."""
+    """Exact-structure KOM recursion; returns the 2*nbits-bit product
+    (modulo 2**32 at 32 bits, where a term shifted by 32 or more is 0)."""
     if nbits == 2:
         return base_fn(a, b)
     half = nbits // 2
     dt = _prod_dtype(nbits)
     a_h, a_l = split_halves(a.astype(jnp.int32), nbits)
     b_h, b_l = split_halves(b.astype(jnp.int32), nbits)
-    low = _recurse(a_l, b_l, half, base_fn, variant).astype(jnp.int32)
-    high = _recurse(a_h, b_h, half, base_fn, variant).astype(jnp.int32)
+
+    def sub(x, y):
+        return _recurse(x, y, half, base_fn, variant).astype(dt)
+
+    low = sub(a_l, b_l)
+    high = sub(a_h, b_h)
     if variant == "kom4":
         # Paper Table 2 steps 5-8: mid1 = a_H*b_L, mid2 = a_L*b_H.
-        mid1 = _recurse(a_h, b_l, half, base_fn, variant).astype(jnp.int32)
-        mid2 = _recurse(a_l, b_h, half, base_fn, variant).astype(jnp.int32)
-        mid = mid1 + mid2
+        mid = sub(a_h, b_l) + sub(a_l, b_h)
     elif variant == "kom3":
         # Eq. 18/19: a_L*b_H + a_H*b_L = low + high + (a_L - a_H)(b_H - b_L),
-        # with the cross term sign-tracked so the base stays unsigned.
+        # with the cross term sign-tracked so the base stays unsigned; the
+        # negative case subtracts, which wraps like the rest in uint32.
         dl = a_l - a_h
         dr = b_h - b_l
         sign = jnp.sign(dl) * jnp.sign(dr)
-        t = _recurse(jnp.abs(dl), jnp.abs(dr), half, base_fn, variant)
-        mid = low + high + sign * t.astype(jnp.int32)
+        t = sub(jnp.abs(dl), jnp.abs(dr))
+        mid = low + high + jnp.where(sign < 0, jnp.zeros_like(t) - t, t)
     else:
         raise ValueError(f"unknown variant {variant!r}")
-    return (
-        low.astype(dt)
-        + (mid.astype(dt) << half)
-        + (high.astype(dt) << nbits)
-    )
+    out = low + (mid << half)
+    return out + (high << nbits) if nbits < 32 else out
 
 
 def _leaves(a: Array, b: Array, nbits: int, variant: str, weight: int,
@@ -190,7 +199,10 @@ def _recurse_flat(a: Array, b: Array, nbits: int, base_fn, variant: str) -> Arra
     Bit-identical to `_recurse`: the leaf weights are exactly the composed
     shifts of the recursion, the base products are <= 9, and the combining
     arithmetic is carried in the same product dtype (int32 below 16 bits,
-    uint32 at 16) where the recursion's adds are already modular. kom3's
+    uint32 from 16) where the recursion's adds are already modular. In
+    uint32 a weight counts modulo 2**32, and a leaf whose weight is 0 there
+    (at 32 bits, every digit pair whose shifts sum to 32 or more) is never
+    stacked. kom3's
     data-dependent sign is applied to the small leaf product first and split
     into positive/negative accumulators so no signed value is ever cast to
     uint32.
@@ -203,10 +215,13 @@ def _recurse_flat(a: Array, b: Array, nbits: int, base_fn, variant: str) -> Arra
     leaves: list = []
     _leaves(jnp.broadcast_to(a, shape), jnp.broadcast_to(b, shape),
             nbits, variant, 1, None, leaves)
+    dt = _prod_dtype(nbits)
+    if dt == jnp.uint32:
+        leaves = [(la, lb, w % _LANE, s) for la, lb, w, s in leaves
+                  if w % _LANE]
     planes_a = jnp.stack([la for la, _, _, _ in leaves])
     planes_b = jnp.stack([lb for _, lb, _, _ in leaves])
     prods = base_fn(planes_a, planes_b)          # ONE (L, ...) base multiply
-    dt = _prod_dtype(nbits)
     pos = jnp.zeros(shape, dt)
     neg = jnp.zeros(shape, dt)
     for i, (_, _, weight, sign) in enumerate(leaves):
@@ -233,7 +248,7 @@ def refmlm(
 
     Args:
       a, b: non-negative integer arrays with values < 2**nbits.
-      nbits: operand width, one of 2/4/8/16.
+      nbits: operand width, one of 2/4/8/16/32.
       variant: 'kom4' (paper-faithful 4-product split) or 'kom3' (true
         Karatsuba 3-product split).
       base: 'efmlm' (error-free base => exact product) or 'mlm' (uncorrected
@@ -242,9 +257,10 @@ def refmlm(
         (default; bit-identical, far fewer kernel-visible ops) or as the
         paper-literal unrolled recursion.
     Returns:
-      The 2*nbits-bit product (exact iff base='efmlm').
+      The 2*nbits-bit product (exact iff base='efmlm'); at 32 bits the
+      product modulo 2**32 in uint32.
     """
-    _check_width(nbits)
+    _check_width(nbits, max(SUPPORTED_WIDTHS))
     if nbits not in SUPPORTED_WIDTHS:
         raise ValueError(f"nbits must be one of {SUPPORTED_WIDTHS}, got {nbits}")
     base_fn = {"efmlm": efmlm2, "mlm": mlm2}[base]
@@ -263,13 +279,15 @@ def _const_leaves(c: int, nbits: int) -> tuple[tuple[int, int, int], ...]:
     Walks `_leaves` itself with `c` as a Python int. The pixel side is a
     probe: element i of `probe` has digit i set and no other, so each
     leaf's pixel plane is one-hot at the digit it reads. The split masks
-    both sides to `nbits`, exactly as the runtime recursion does.
+    both sides to `nbits`, exactly as the runtime recursion does. A leaf
+    whose weight is 2**32 or more adds 0 to the uint32 product (32 bits
+    only) and is dropped with the zero digits.
     """
     probe = np.array([1 << s for s in range(0, nbits, 2)], np.int64)
     leaves: list = []
     _leaves(probe, c, nbits, "kom4", 1, None, leaves)
     return tuple((2 * int(np.argmax(pa)), int(pc), w)
-                 for pa, pc, w, _ in leaves if pc)
+                 for pa, pc, w, _ in leaves if pc and w < _LANE)
 
 
 def const_base_mults(c: int, nbits: int) -> int:
@@ -286,10 +304,11 @@ def refmlm_const(a: Array, c: int, nbits: int = 16, *,
     zero-detect, and the correction term ANDs c_j's bits), so only leaves
     with a non-zero coefficient digit are evaluated, each through the same
     base with the digit as a Python int. Dropping exact zeros from the
-    modular sum in the product dtype leaves it unchanged. A constant with
-    no non-zero digit costs no base product at all.
+    modular sum in the product dtype leaves it unchanged, as does dropping a
+    leaf whose weight is 0 modulo 2**32. A constant with no non-zero digit
+    costs no base product at all.
     """
-    _check_width(nbits)
+    _check_width(nbits, max(SUPPORTED_WIDTHS))
     if nbits not in SUPPORTED_WIDTHS:
         raise ValueError(f"nbits must be one of {SUPPORTED_WIDTHS}, got {nbits}")
     base_fn = {"efmlm": efmlm2, "mlm": mlm2}[base]
@@ -305,6 +324,14 @@ def refmlm_const(a: Array, c: int, nbits: int = 16, *,
         term = base_fn(digits[shift], cj).astype(dt) << (weight.bit_length() - 1)
         acc = term if acc is None else acc + term       # kom4 weights are 2**k
     return jnp.zeros(a.shape, dt) if acc is None else acc
+
+
+def flat_base_mults(nbits: int) -> int:
+    """Base products the flattened kom4 `refmlm` stacks per element: every
+    digit pair but those whose weight is 0 modulo 2**32 (only at 32 bits,
+    where 136 of the recursion's 256 remain)."""
+    digits = range(nbits // 2)
+    return sum(1 for i in digits for j in digits if 2 * (i + j) < 32)
 
 
 def op_counts(nbits: int, variant: str = "kom4") -> dict[str, int]:

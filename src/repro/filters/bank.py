@@ -22,9 +22,11 @@ product of the integer row/column vectors -- not an independently rounded
 2-D sampling -- so with an exact multiplier ('exact', 'refmlm') the two-pass
 path is bit-identical to the direct path (asserted in tests).
 
-All coefficient magnitudes fit 8 bits, matching the paper's 8x8 REFMLM; the
-separable second pass sees up to 16-bit intermediates and therefore runs the
-16x16 recursion (DESIGN.md §5).
+All coefficient magnitudes fit 8 bits, matching the paper's 8x8 REFMLM. The
+separable second pass is sized from the row pass's worst intermediate
+(`max_intermediate`), which follows the top of the pixels' container: 8-bit
+pixels give up to 16-bit intermediates and the 16x16 recursion, 16-bit
+pixels up to 21-bit ones and the 32x32 recursion (DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -121,7 +123,9 @@ def get_filter(name: str, *, sigma: float | None = None) -> FilterSpec:
 
 
 def max_intermediate(spec: FilterSpec, pixel_max: int = 255) -> int:
-    """Worst-case |row-pass accumulator| -- sizes the second-pass multiplier."""
+    """Worst-case |row-pass accumulator| -- sizes the second-pass multiplier.
+    `pixel_max` is the top of the pixels' container (255, or 65535 for
+    16-bit containers)."""
     if not spec.separable:
         return 0
     return int(pixel_max * np.abs(spec.sep_row.astype(np.int64)).sum())

@@ -55,7 +55,9 @@ accumulation is the CSA tree, carried at the narrowest width the exact
 table-bound analysis admits (int16 when every |partial sum| < 2**15, the
 direct-path analogue of `second_pass_nbits`; DESIGN.md §8); `post` then
 applies the filter's fixed-point normalization ('clip'), gradient-magnitude
-display ('abs'), or nothing ('none', the separable intermediate) in int32.
+display ('abs'), or nothing ('none', the separable intermediate) in int32,
+clipping to `top`, the top of the pixels' container (255 for uint8, 65535
+for uint16; DESIGN.md §5).
 
 Tap-product implementations (`mult_impl`, DESIGN.md §7):
   * 'recurse' -- expand the selected multiplier's dataflow per tap (for
@@ -64,10 +66,12 @@ Tap-product implementations (`mult_impl`, DESIGN.md §7):
   * 'kcm'     -- constant-coefficient fast path: coefficients are trace-time
     constants, so each tap is a `repro.core.kcm` product-table gather
     (sign baked in), bit-identical to 'recurse' for every method;
-  * 'auto'    -- 'kcm' whenever the taps are static (not traced) and the
-    pass runs under the interpreter, else 'recurse'. Compiled TPU passes
-    always take 'recurse': Mosaic lowers only 2-D gathers, not the 1-D
-    ROM lookup.
+  * 'auto'    -- 'kcm' whenever the taps are static (not traced), the
+    pass runs under the interpreter and its widest multiplier has at most
+    `KCM_MAX_NBITS` bits, else 'recurse'. Compiled TPU passes always take
+    'recurse': Mosaic lowers only 2-D gathers, not the 1-D ROM lookup. A
+    32-bit pass (the column pass of 16-bit pixels) always takes it too: its
+    ROM would need 2**32 entries a tap.
 
 Multiplier methods: 'exact', 'refmlm', 'refmlm_nc', 'mitchell',
 'mitchell_ecc{k}', 'odma' -- see repro/core and DESIGN.md §1.
@@ -84,6 +88,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.kcm import (
+    KCM_MAX_NBITS,
     METHODS,
     const_tap_multiplier,
     filter_tables,
@@ -91,7 +96,7 @@ from repro.core.kcm import (
     tap_multiplier,
 )
 from repro.core.platform import grid_compiler_params, resolve_interpret
-from repro.core.refmlm import const_base_mults, op_counts
+from repro.core.refmlm import const_base_mults, flat_base_mults
 from repro.obs.metrics import MetricsRegistry
 from repro.tuning import choose_block_rows, resolve_blocks
 from repro.tuning.blocks import min_block_cols, min_block_rows, round_up
@@ -155,11 +160,12 @@ def accumulate_taps(bands, k_ref, acc_shape, *, kh: int, kw: int, w: int,
     return acc
 
 
-def apply_post(acc: Array, *, post: str, shift: int) -> Array:
+def apply_post(acc: Array, *, post: str, shift: int, top: int = 255) -> Array:
     """Fixed-point epilogue: rounding shift + clip / abs / raw (DESIGN.md §5).
 
     Always widens to int32 first so a narrow accumulator keeps rounding
     headroom (the carry bound covers the sum, not the +2**(shift-1) bias).
+    'clip' and 'abs' clip to [0, top], the top of the pixels' container.
     """
     acc = acc.astype(jnp.int32)
     if post == "none":
@@ -168,7 +174,7 @@ def apply_post(acc: Array, *, post: str, shift: int) -> Array:
         acc = jnp.abs(acc)
     rounded = (acc + (1 << (shift - 1))) >> shift if shift > 0 else acc
     if post in ("clip", "abs"):
-        return jnp.clip(rounded, 0, 255)
+        return jnp.clip(rounded, 0, top)
     raise ValueError(f"unknown post {post!r}")
 
 
@@ -229,8 +235,7 @@ def _record_tap_leaves(kernel: str, method: str, passes) -> None:
         label = " ".join("/".join("_".join(map(str, row)) for row in taps)
                          for taps, _ in passes)
     else:
-        leaves = sum(taps.size * op_counts(nb)["base_mults"]
-                     for taps, nb in passes)
+        leaves = sum(taps.size * flat_base_mults(nb) for taps, nb in passes)
         label = " ".join("x".join(map(str, taps.shape)) for taps, _ in passes)
     KERNEL_METRICS.gauge("conv_tap_leaves").set(
         leaves, kernel=kernel, coef="baked" if baked else "smem",
@@ -247,15 +252,22 @@ def _is_static(taps) -> bool:
         return False
 
 
-def _resolve_mult_impl(mult_impl: str, *tap_arrays, interpret: bool) -> str:
-    """'auto' -> 'kcm' for static taps under the interpreter, else
-    'recurse'. Compiled (Mosaic) passes never take 'kcm': its ROM lookup is
-    a 1-D gather, which Mosaic does not lower."""
+def _resolve_mult_impl(mult_impl: str, *tap_arrays, interpret: bool,
+                       nbits: int = 8) -> str:
+    """'auto' -> 'kcm' for static taps under the interpreter at widths up to
+    `KCM_MAX_NBITS`, else 'recurse'. `nbits` is the widest multiplier of the
+    pass. Compiled (Mosaic) passes never take 'kcm': its ROM lookup is a
+    1-D gather, which Mosaic does not lower."""
     if mult_impl not in MULT_IMPLS:
         raise ValueError(f"mult_impl must be one of {MULT_IMPLS}, got {mult_impl!r}")
     static = all(_is_static(t) for t in tap_arrays)
+    narrow = nbits <= KCM_MAX_NBITS
     if mult_impl == "auto":
-        return "kcm" if static and interpret else "recurse"
+        return "kcm" if static and interpret and narrow else "recurse"
+    if mult_impl == "kcm" and not narrow:
+        raise ValueError(f"mult_impl='kcm' builds ROMs of up to "
+                         f"{KCM_MAX_NBITS} bits, and this pass multiplies at "
+                         f"{nbits}; use 'recurse' (bit-identical) or 'auto'")
     if mult_impl == "kcm" and not interpret:
         raise ValueError("mult_impl='kcm' runs only under the Pallas "
                          "interpreter: Mosaic cannot lower the KCM table "
@@ -289,7 +301,7 @@ def _unfold_batch(out: Array, n: int, h: int, ph: int) -> Array:
 # ---------------------------------------------------------------- single pass
 
 def _kernel(*refs, kh: int, kw: int, method: str, nbits: int, shift: int,
-            post: str, kcm: bool, taps, tiled: bool, acc: str):
+            post: str, top: int, kcm: bool, taps, tiled: bool, acc: str):
     """`taps` -- the baked (kh, kw) int tuples; None when the first ref is
     the coefficient operand (the SMEM table, or with `kcm` the ROM stack)."""
     *band_refs, o_ref = refs
@@ -308,7 +320,7 @@ def _kernel(*refs, kh: int, kw: int, method: str, nbits: int, shift: int,
     tacc = accumulate_taps(bands, taps, o_ref.shape[1:], kh=kh, kw=kw, w=bc,
                            method=method, nbits=nbits, tables=tables,
                            acc_dtype=_ACC_DTYPES[acc])
-    o_ref[...] = apply_post(tacc, post=post, shift=shift)[None]
+    o_ref[...] = apply_post(tacc, post=post, shift=shift, top=top)[None]
 
 
 def _pass_call(imgs: Array, coefs: tuple, coef_specs: tuple, kernel, *,
@@ -359,9 +371,9 @@ def _pass_call(imgs: Array, coefs: tuple, coef_specs: tuple, kernel, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "taps", "method", "nbits", "shift", "post", "block_rows", "block_cols",
-    "batch_fold", "interpret"))
-def _conv2d_recurse(imgs, coef, *, taps, method, nbits, shift, post,
+    "taps", "method", "nbits", "shift", "post", "top", "block_rows",
+    "block_cols", "batch_fold", "interpret"))
+def _conv2d_recurse(imgs, coef, *, taps, method, nbits, shift, post, top,
                     block_rows, block_cols, batch_fold, interpret):
     """`taps` -- the (kh, kw) coefficients as int tuples, baked into the
     kernel; or None, and `coef`, the traced (kh, kw) table, rides in SMEM."""
@@ -378,8 +390,8 @@ def _conv2d_recurse(imgs, coef, *, taps, method, nbits, shift, post,
 
     def call(x, bc, tiled):
         k = functools.partial(_kernel, kh=kh, kw=kw, method=method,
-                              nbits=nbits, shift=shift, post=post, kcm=False,
-                              taps=taps, tiled=tiled, acc="int32")
+                              nbits=nbits, shift=shift, post=post, top=top,
+                              kcm=False, taps=taps, tiled=tiled, acc="int32")
         return _pass_call(x, coefs, specs, k, kh=kh, kw=kw,
                           block_rows=block_rows, bc=bc, tiled=tiled,
                           interpret=interpret)
@@ -389,16 +401,16 @@ def _conv2d_recurse(imgs, coef, *, taps, method, nbits, shift, post,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "kh", "kw", "shift", "post", "block_rows", "block_cols", "batch_fold",
-    "interpret", "acc"))
-def _conv2d_kcm(imgs, tables, *, kh, kw, shift, post, block_rows, block_cols,
-                batch_fold, interpret, acc):
+    "kh", "kw", "shift", "post", "top", "block_rows", "block_cols",
+    "batch_fold", "interpret", "acc"))
+def _conv2d_kcm(imgs, tables, *, kh, kw, shift, post, top, block_rows,
+                block_cols, batch_fold, interpret, acc):
     spec = pl.BlockSpec(tables.shape, lambda nn, i, j: (0, 0))  # whole ROM, VMEM
 
     def call(x, bc, tiled):
         k = functools.partial(_kernel, kh=kh, kw=kw, method="", nbits=0,
-                              shift=shift, post=post, kcm=True, taps=None,
-                              tiled=tiled, acc=acc)
+                              shift=shift, post=post, top=top, kcm=True,
+                              taps=None, tiled=tiled, acc=acc)
         return _pass_call(x, (tables,), (spec,), k, kh=kh, kw=kw,
                           block_rows=block_rows, bc=bc, tiled=tiled,
                           interpret=interpret)
@@ -433,6 +445,7 @@ def conv2d_pass(
     nbits: int = 8,
     shift: int = 8,
     post: str = "clip",
+    top: int = 255,
     block_rows: int | None = None,
     block_cols: int | None = None,
     batch_fold: bool | None = None,
@@ -447,11 +460,14 @@ def conv2d_pass(
     cache, then the heuristic (`repro.tuning.resolve_blocks`, DESIGN.md §8);
     explicit values always win. Input may be signed (the separable
     intermediate); `nbits` must cover the widest |operand| on either side of
-    each tap product. interpret=None autodetects the backend (DESIGN.md §7);
-    mult_impl picks the tap-product implementation (module docstring).
+    each tap product. `top` is the clip of 'clip' and 'abs', the top of
+    the pixels' container. interpret=None autodetects the backend
+    (DESIGN.md §7); mult_impl picks the tap-product implementation (module
+    docstring).
     """
     interpret = resolve_interpret(interpret)
-    impl = _resolve_mult_impl(mult_impl, taps, interpret=interpret)
+    impl = _resolve_mult_impl(mult_impl, taps, interpret=interpret,
+                              nbits=nbits)
     n, h, w = imgs.shape
     kh, kw = np.shape(taps)     # list/tuple taps accepted, Tracers untouched
     cfg = resolve_blocks("direct", n, h, w, kh, kw, impl,
@@ -461,7 +477,7 @@ def conv2d_pass(
         taps_np = np.asarray(taps)
         tables, acc = _tables_for(method, taps_np, nbits)
         return _conv2d_kcm(imgs, tables, kh=kh, kw=kw, shift=shift, post=post,
-                           block_rows=cfg.block_rows,
+                           top=top, block_rows=cfg.block_rows,
                            block_cols=cfg.block_cols,
                            batch_fold=cfg.batch_fold, interpret=interpret,
                            acc=acc)
@@ -470,7 +486,7 @@ def conv2d_pass(
                            None if baked else jnp.asarray(taps, jnp.int32),
                            taps=_baked(taps, (kh, kw)) if baked else None,
                            method=method, nbits=nbits, shift=shift, post=post,
-                           block_rows=cfg.block_rows,
+                           top=top, block_rows=cfg.block_rows,
                            block_cols=cfg.block_cols,
                            batch_fold=cfg.batch_fold, interpret=interpret)
 
@@ -478,8 +494,8 @@ def conv2d_pass(
 # ------------------------------------------------------------ fused separable
 
 def _fused_kernel(*refs, kh: int, kw: int, method: str, nbits: int,
-                  nbits2: int, shift: int, post: str, kcm: bool, taps,
-                  tiled: bool):
+                  nbits2: int, shift: int, post: str, top: int, kcm: bool,
+                  taps, tiled: bool):
     """Both separable passes on one tile (DESIGN.md §7/§8 halo math).
 
     `taps` -- the baked ((1, kw), (kh, 1)) int tuples of the row and column
@@ -517,7 +533,7 @@ def _fused_kernel(*refs, kh: int, kw: int, method: str, nbits: int,
     vbands = [hacc[di : di + rows] for di in range(kh)]
     acc = accumulate_taps(vbands, col_taps, (rows, bc), kh=kh, kw=1, w=bc,
                           method=method, nbits=nbits2, tables=col_tab)
-    o_ref[...] = apply_post(acc, post=post, shift=shift)[None]
+    o_ref[...] = apply_post(acc, post=post, shift=shift, top=top)[None]
 
 
 def _fused_call(imgs: Array, coefs: tuple, coef_specs: tuple, kernel, *,
@@ -566,10 +582,11 @@ def _fused_call(imgs: Array, coefs: tuple, coef_specs: tuple, kernel, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "taps", "method", "nbits", "nbits2", "shift", "post", "block_rows",
-    "block_cols", "batch_fold", "interpret"))
+    "taps", "method", "nbits", "nbits2", "shift", "post", "top",
+    "block_rows", "block_cols", "batch_fold", "interpret"))
 def _fused_sep_recurse(imgs, row, col, *, taps, method, nbits, nbits2, shift,
-                       post, block_rows, block_cols, batch_fold, interpret):
+                       post, top, block_rows, block_cols, batch_fold,
+                       interpret):
     """`taps` -- the ((1, kw), (kh, 1)) row and column filters as int
     tuples, baked into the kernel; or None, and the traced `row` (1, kw) and
     `col` (kh, 1) tables ride in SMEM."""
@@ -589,7 +606,7 @@ def _fused_sep_recurse(imgs, row, col, *, taps, method, nbits, nbits2, shift,
     def call(x, bc, tiled):
         kernel = functools.partial(_fused_kernel, kh=kh, kw=kw, method=method,
                                    nbits=nbits, nbits2=nbits2, shift=shift,
-                                   post=post, kcm=False, taps=taps,
+                                   post=post, top=top, kcm=False, taps=taps,
                                    tiled=tiled)
         return _fused_call(x, coefs, specs, kernel, kh=kh, kw=kw,
                            block_rows=block_rows, bc=bc, tiled=tiled,
@@ -600,9 +617,9 @@ def _fused_sep_recurse(imgs, row, col, *, taps, method, nbits, nbits2, shift,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "kh", "kw", "shift", "post", "block_rows", "block_cols", "batch_fold",
-    "interpret"))
-def _fused_sep_kcm(imgs, row_tables, col_tables, *, kh, kw, shift, post,
+    "kh", "kw", "shift", "post", "top", "block_rows", "block_cols",
+    "batch_fold", "interpret"))
+def _fused_sep_kcm(imgs, row_tables, col_tables, *, kh, kw, shift, post, top,
                    block_rows, block_cols, batch_fold, interpret):
     rspec = pl.BlockSpec(row_tables.shape, lambda nn, i, j: (0, 0))
     cspec = pl.BlockSpec(col_tables.shape, lambda nn, i, j: (0, 0))
@@ -610,7 +627,7 @@ def _fused_sep_kcm(imgs, row_tables, col_tables, *, kh, kw, shift, post,
     def call(x, bc, tiled):
         kernel = functools.partial(_fused_kernel, kh=kh, kw=kw, method="",
                                    nbits=0, nbits2=0, shift=shift, post=post,
-                                   kcm=True, taps=None, tiled=tiled)
+                                   top=top, kcm=True, taps=None, tiled=tiled)
         return _fused_call(x, (row_tables, col_tables), (rspec, cspec), kernel,
                            kh=kh, kw=kw, block_rows=block_rows, bc=bc,
                            tiled=tiled, interpret=interpret)
@@ -629,6 +646,7 @@ def fused_separable_pass(
     nbits2: int = 16,
     shift: int = 8,
     post: str = "clip",
+    top: int = 255,
     block_rows: int | None = None,
     block_cols: int | None = None,
     batch_fold: bool | None = None,
@@ -642,11 +660,13 @@ def fused_separable_pass(
     kh//2-row halo) just stays in VMEM instead of round-tripping through
     HBM (DESIGN.md §7). `row` is the (kw,) horizontal filter at width
     `nbits`, `col` the (kh,) vertical filter at width `nbits2`
-    (see `second_pass_nbits`). Grid fields default through the autotune
-    cache exactly like `conv2d_pass` (DESIGN.md §8).
+    (see `second_pass_nbits`); `top` is the clip, as in `conv2d_pass`.
+    Grid fields default through the autotune cache exactly like
+    `conv2d_pass` (DESIGN.md §8).
     """
     interpret = resolve_interpret(interpret)
-    impl = _resolve_mult_impl(mult_impl, row, col, interpret=interpret)
+    impl = _resolve_mult_impl(mult_impl, row, col, interpret=interpret,
+                              nbits=max(nbits, nbits2))
     n, h, w = imgs.shape
     kh = int(np.asarray(col).size) if _is_static(col) else col.shape[-1]
     kw = int(np.asarray(row).size) if _is_static(row) else row.shape[-1]
@@ -662,7 +682,7 @@ def fused_separable_pass(
         rt = _tables_for(method, row, nbits)[0]
         ct = _tables_for(method, col, nbits2)[0]
         return _fused_sep_kcm(imgs, rt, ct, kh=ct.shape[0], kw=rt.shape[0],
-                              shift=shift, post=post,
+                              shift=shift, post=post, top=top,
                               block_rows=cfg.block_rows,
                               block_cols=cfg.block_cols,
                               batch_fold=cfg.batch_fold, interpret=interpret)
@@ -675,7 +695,7 @@ def fused_separable_pass(
         col = jnp.asarray(col, jnp.int32).reshape(-1, 1)
     return _fused_sep_recurse(imgs, row, col, taps=taps, method=method,
                               nbits=nbits, nbits2=nbits2, shift=shift,
-                              post=post,
+                              post=post, top=top,
                               block_rows=cfg.block_rows,
                               block_cols=cfg.block_cols,
                               batch_fold=cfg.batch_fold, interpret=interpret)
@@ -684,13 +704,14 @@ def fused_separable_pass(
 def second_pass_nbits(intermediate_max: int, coeff_max: int) -> int:
     """Multiplier width for the separable column pass: the narrowest
     supported width covering both the row-pass accumulator magnitude and the
-    column coefficients (8 for narrow filters, 16 in general)."""
+    column coefficients (8 for narrow filters, 16 for 8-bit pixels, 32 for
+    16-bit pixels)."""
     need = max(int(intermediate_max), int(coeff_max))
-    for nb in (2, 4, 8, 16):
+    for nb in (2, 4, 8, 16, 32):
         if need < (1 << nb):
             return nb
     raise ValueError(
-        f"separable intermediate {need} exceeds the 16-bit REFMLM datapath")
+        f"separable intermediate {need} exceeds the 32-bit REFMLM datapath")
 
 
 __all__ = [
@@ -699,6 +720,7 @@ __all__ = [
     "accumulate_taps",
     "apply_post",
     "choose_block_rows",
+    "KCM_MAX_NBITS",
     "KERNEL_METRICS",
     "conv2d_pass",
     "fused_separable_pass",

@@ -6,7 +6,11 @@
 
 Accepts a single (H, W) image or an (N, H, W) batch (NHWC with a trailing
 unit channel axis is also accepted and squeezed -- the datapath is
-grayscale, like the paper's fingerprint experiment). The direct-vs-separable
+grayscale, like the paper's fingerprint experiment). Pixels come in a
+container (`CONTAINERS`, DESIGN.md §5): uint16 images are answered in
+uint16, clipped to 65535, with the separable column pass sized for their
+row sums (the 32-bit REFMLM); every other dtype keeps the 8-bit contract,
+answered in uint8 and clipped to 255. The direct-vs-separable
 dataflow choice is handled here; tile padding and the grid organization
 (row bands x column tiles, batch fold) live in the conv passes, defaulted
 from the per-backend autotune cache (DESIGN.md §8).
@@ -26,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import Array
 
+from repro.core.kcm import KCM_MAX_NBITS, method_max_nbits
 from repro.core.platform import resolve_interpret
 from repro.filters.bank import (
     FILTER_NAMES,
@@ -43,6 +48,52 @@ from repro.tuning.plans import PlanConfig, resolve_plan
 
 #: the dtype `apply_filter_batch` stacks a batch in for the device.
 BATCH_DTYPE = np.int32
+
+#: the pixel containers, by name: a request is answered in its own.
+CONTAINERS = {"uint8": np.uint8, "uint16": np.uint16}
+
+
+def container_of(img) -> str:
+    """The container `img` is answered in: 'uint16' for uint16 pixels,
+    'uint8' for any other dtype (the 8-bit contract)."""
+    dtype = np.dtype(getattr(img, "dtype", np.uint8))
+    return "uint16" if dtype == np.uint16 else "uint8"
+
+
+def container_top(container: str) -> int:
+    """The largest pixel of `container`: the clip of every answer."""
+    return int(np.iinfo(CONTAINERS[container]).max)
+
+
+def _container(container, imgs) -> str:
+    """An explicit container's name, checked; None -> `container_of`."""
+    if container is None:
+        return container_of(imgs)
+    name = np.dtype(container).name
+    if name not in CONTAINERS:
+        raise ValueError(f"container must be one of {tuple(CONTAINERS)}, "
+                         f"got {name!r}")
+    return name
+
+
+def filter_nbits(spec: FilterSpec, nbits: int, container: str) -> tuple:
+    """The multiplier widths `spec` runs at on `container` pixels: (nbits,)
+    for a direct filter; with the separable column pass's width appended,
+    sized from the container's top (`second_pass_nbits`)."""
+    if not spec.separable:
+        return (int(nbits),)
+    return (int(nbits),
+            second_pass_nbits(max_intermediate(spec, container_top(container)),
+                              int(np.abs(spec.sep_col).max())))
+
+
+def check_method_nbits(method: str, widths) -> None:
+    """ValueError, naming the method and the width, where `method` has no
+    multiplier as wide as the widest of `widths` (`method_max_nbits`)."""
+    widest, top = max(widths), method_max_nbits(method)
+    if top is not None and widest > top:
+        raise ValueError(f"method {method!r} has no {widest}-bit multiplier "
+                         f"(its widest is {top} bits)")
 
 
 def _normalize(imgs: Array) -> tuple[Array, tuple[int, ...]]:
@@ -70,17 +121,18 @@ def _restore(out: Array, orig: tuple[int, ...]) -> Array:
 def _apply(imgs: Array, spec: FilterSpec, method: str, nbits: int,
            separable: bool, fused: bool, mult_impl: str,
            block_rows: int | None, block_cols: int | None,
-           batch_fold: bool | None, interpret: bool | None) -> Array:
+           batch_fold: bool | None, interpret: bool | None,
+           container: str) -> Array:
     blocks = dict(block_rows=block_rows, block_cols=block_cols,
                   batch_fold=batch_fold)
+    top = container_top(container)
     if separable:
-        nb2 = second_pass_nbits(max_intermediate(spec),
-                                int(np.abs(spec.sep_col).max()))
+        nb2 = filter_nbits(spec, nbits, container)[1]
         if fused:
             out = fused_separable_pass(
                 imgs, spec.sep_row, spec.sep_col, method=method,
                 nbits=nbits, nbits2=nb2, shift=spec.shift, post=spec.post,
-                interpret=interpret, mult_impl=mult_impl, **blocks)
+                top=top, interpret=interpret, mult_impl=mult_impl, **blocks)
         else:
             run = partial(conv2d_pass, interpret=interpret,
                           mult_impl=mult_impl, **blocks)
@@ -92,13 +144,13 @@ def _apply(imgs: Array, spec: FilterSpec, method: str, nbits: int,
             tmp = run(imgs, row, method=method, nbits=nbits, shift=0,
                       post="none")
             out = run(tmp, col, method=method, nbits=nb2, shift=spec.shift,
-                      post=spec.post)
+                      post=spec.post, top=top)
     else:
         out = conv2d_pass(imgs, np.asarray(spec.taps, np.int32),
                           method=method, nbits=nbits, shift=spec.shift,
-                          post=spec.post, interpret=interpret,
+                          post=spec.post, top=top, interpret=interpret,
                           mult_impl=mult_impl, **blocks)
-    return out.astype(jnp.uint8)
+    return out.astype(CONTAINERS[container])
 
 
 EXEC_MODES = ("local", "sharded", "streamed")
@@ -126,8 +178,16 @@ def apply_filter(
     out=None,
     journal=None,
     resume: bool = False,
+    container: str | None = None,
 ):
     """Run one bank filter over an image batch through the selected multiplier.
+
+    The answer comes in the pixels' `container` (DESIGN.md §5): 'uint8'
+    or 'uint16', by default the input's own (`container_of`: uint16
+    images are answered in uint16, anything else in uint8). The output is
+    clipped to the container's top and the separable column pass is sized
+    from it; a method without a multiplier that wide raises ValueError.
+    Only exec='local' serves a 'uint16' container.
 
     The execution plan -- dataflow, tap-product implementation and grid
     organization -- resolves through the per-backend plan cache
@@ -161,6 +221,10 @@ def apply_filter(
     """
     if exec not in EXEC_MODES:
         raise ValueError(f"exec must be one of {EXEC_MODES}, got {exec!r}")
+    container = _container(container, imgs)
+    if container != "uint8" and exec != "local":
+        raise ValueError(f"exec={exec!r} answers uint8 pixels only; "
+                         f"{container} pixels take exec='local'")
     filter_kw = dict(method=method, nbits=nbits, separable=separable,
                      fused=fused, mult_impl=mult_impl, block_rows=block_rows,
                      block_cols=block_cols, batch_fold=batch_fold,
@@ -193,6 +257,8 @@ def apply_filter(
         raise ValueError(f"filter {spec.name!r} has no separable decomposition")
     if fused and (separable is False or not spec.separable):
         raise ValueError("fused=True requires the separable dataflow")
+    widths = filter_nbits(spec, nbits, container)
+    check_method_nbits(method, widths)
     arr, orig = _normalize(imgs)
     n, h, w = arr.shape
     kh, kw = spec.ksize
@@ -200,10 +266,11 @@ def apply_filter(
                         separable_ok=spec.separable, mult_impl=mult_impl,
                         separable=separable, fused=fused,
                         block_rows=block_rows, block_cols=block_cols,
-                        batch_fold=batch_fold, interpret=interpret)
+                        batch_fold=batch_fold, interpret=interpret,
+                        kcm_ok=max(widths) <= KCM_MAX_NBITS)
     out = _apply(arr, spec, method, nbits, plan.dataflow != "direct",
                  plan.dataflow == "fused", plan.mult_impl, plan.block_rows,
-                 plan.block_cols, plan.batch_fold, interpret)
+                 plan.block_cols, plan.batch_fold, interpret, container)
     return _restore(out, orig)
 
 
@@ -262,10 +329,13 @@ def resolve_filter_plan(
     separable: bool | None = None,
     fused: bool | None = None,
     interpret: bool | None = None,
+    nbits: int = 8,
+    container: str = "uint8",
 ) -> PlanConfig:
     """The fully-concrete execution plan `apply_filter` would run for an
-    (n, h, w) batch of `filt`: dataflow, resolved mult_impl and grid
-    organization, one plan-cache consult total (DESIGN.md §11).
+    (n, h, w) batch of `filt` on `container` pixels at `nbits`: dataflow,
+    resolved mult_impl and grid organization, one plan-cache consult total
+    (DESIGN.md §11).
 
     This is the serving layer's per-bucket memoisation hook (DESIGN.md
     §10): resolve once per (bucket, coalesced batch size), then pin every
@@ -282,9 +352,11 @@ def resolve_filter_plan(
 
     interpret = resolve_interpret(interpret)
     spec = get_filter(filt) if isinstance(filt, str) else filt
+    widest = max(filter_nbits(spec, nbits, container))
     plan = resolve_plan(spec.name, n, h, w, *spec.ksize,
                         separable_ok=spec.separable, mult_impl=mult_impl,
-                        separable=separable, fused=fused, interpret=interpret)
+                        separable=separable, fused=fused, interpret=interpret,
+                        kcm_ok=widest <= KCM_MAX_NBITS)
     if plan.dataflow == "fused":
         kind = "fused"
         kh, kw = len(spec.sep_col), len(spec.sep_row)
@@ -300,7 +372,7 @@ def resolve_filter_plan(
         kh, kw = spec.ksize
         tap_arrays = (spec.taps,)
     impl = _resolve_mult_impl(plan.mult_impl, *tap_arrays,
-                              interpret=interpret)
+                              interpret=interpret, nbits=widest)
     if None in (plan.block_rows, plan.block_cols, plan.batch_fold):
         base = resolve_blocks_cached(kind, n, h, w, kh, kw, impl, interpret)
         plan = PlanConfig(
@@ -333,6 +405,10 @@ def apply_filter_batch(
     image's own zero halo, so batch neighbors (and zero pads) can never
     leak into a request's pixels (asserted in tests/test_serve.py).
 
+    The stack to `BATCH_DTYPE` drops the images' dtype, so their
+    container (`container_of` the first image) is handed to
+    `apply_filter` explicitly, and the outputs come back in it.
+
     The steps are the serve worker's phase spans (`repro.obs.trace`,
     recorded only while a recorder is active): 'prep' (stack, cast to
     `BATCH_DTYPE`, pad), 'upload' (`jax.device_put` to the default
@@ -354,6 +430,7 @@ def apply_filter_batch(
     if len(shape) != 2:
         raise ValueError(f"expected (H, W) images, got shape {shape}")
     n = len(imgs)
+    container = container_of(imgs[0])
     with obs_trace.span("prep"):
         batch = np.stack([np.asarray(im) for im in imgs]).astype(BATCH_DTYPE)
         if pad_to is not None and pad_to > n:
@@ -366,7 +443,7 @@ def apply_filter_batch(
             if obs_trace.tracing():
                 batch.block_until_ready()
     with obs_trace.span("launch"):
-        out = apply_filter(batch, filt, **kw)
+        out = apply_filter(batch, filt, container=container, **kw)
     if local:
         with obs_trace.span("device_wait"):
             out.block_until_ready()
@@ -382,12 +459,14 @@ def filter_bank_apply(
     method: str = "refmlm",
     **kw,
 ) -> dict[str, Array]:
-    """Run many filters over one batch: name -> uint8 output batch."""
+    """Run many filters over one batch: name -> output batch, in the
+    pixels' container (`apply_filter`)."""
     names = FILTER_NAMES if filters is None else tuple(filters)
     return {name: apply_filter(imgs, name, method=method, **kw)
             for name in names}
 
 
-__all__ = ["BATCH_DTYPE", "EXEC_MODES", "apply_filter", "apply_filter_batch",
-           "filter_bank_apply", "resolve_filter_blocks",
-           "resolve_filter_plan"]
+__all__ = ["BATCH_DTYPE", "CONTAINERS", "EXEC_MODES", "apply_filter",
+           "apply_filter_batch", "check_method_nbits", "container_of",
+           "container_top", "filter_bank_apply", "filter_nbits",
+           "resolve_filter_blocks", "resolve_filter_plan"]

@@ -4,6 +4,11 @@ implementations of the same dataflow (DESIGN.md §5).
 
 Bit-exact contract: integer in, integer out, same accumulator dtype and
 same fixed-point epilogue as the Pallas path.
+
+`plain_filter_ref` is the plainest of them: int64 NumPy correlation with
+the filter's 2-D taps, the rounding shift, and abs/clip to the top of the
+pixels' container. It uses no multiplier, kernel or dataflow of the
+program, so it holds every exact path to the same answer (DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -74,4 +79,31 @@ def apply_filter_ref(
     return out.astype(jnp.uint8)
 
 
-__all__ = ["apply_filter_ref", "conv2d_ref"]
+def plain_filter_ref(imgs, filt: FilterSpec | str) -> np.ndarray:
+    """Exact filtering of an (H, W) image or (N, H, W) batch, in int64
+    NumPy: 'same'-size correlation with the filter's 2-D taps over zero
+    padding, then `abs` for derivative filters, the rounding shift, and
+    the clip to the top of the pixels' container -- uint16 for uint16
+    images, else uint8 -- returned in that dtype."""
+    spec = get_filter(filt) if isinstance(filt, str) else filt
+    arr = np.asarray(imgs)
+    dtype = np.dtype(np.uint16 if arr.dtype == np.uint16 else np.uint8)
+    x = arr.astype(np.int64).reshape((-1,) + arr.shape[-2:])
+    taps = np.asarray(spec.taps, np.int64)
+    kh, kw = taps.shape
+    n, h, w = x.shape
+    padded = np.pad(x, ((0, 0), (kh // 2, kh // 2), (kw // 2, kw // 2)))
+    acc = np.zeros((n, h, w), np.int64)
+    for di in range(kh):
+        for dj in range(kw):
+            if taps[di, dj]:
+                acc += taps[di, dj] * padded[:, di:di + h, dj:dj + w]
+    if spec.post == "abs":
+        acc = np.abs(acc)
+    if spec.shift > 0:
+        acc = (acc + (1 << (spec.shift - 1))) >> spec.shift
+    out = np.clip(acc, 0, np.iinfo(dtype).max).astype(dtype)
+    return out.reshape(arr.shape)
+
+
+__all__ = ["apply_filter_ref", "conv2d_ref", "plain_filter_ref"]

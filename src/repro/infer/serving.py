@@ -137,7 +137,7 @@ class InferWorkload(Workload):
 
     def warm(self, executor, shape: tuple[int, int], target: str, *,
              method: str, mult_impl: str, exec_mode: str, nbits: int,
-             traced_n: int) -> None:
+             traced_n: int, container: str = "uint8") -> None:
         cal = self.models.get(target)
         if cal is None:
             raise ValueError(f"unknown infer model {target!r}")

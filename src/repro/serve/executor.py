@@ -73,10 +73,11 @@ Telemetry (DESIGN.md §15): the ledger counters live in a
 `repro.obs.MetricsRegistry` (labelled `member=` so pool members share
 one registry without colliding); the historical attribute API
 (`ex.hits`, `ex.retries`, ...) is preserved as properties reading the
-registry. Two always-on counters price the host -> device traffic:
+registry. Always-on counters price the host <-> device traffic:
 `serve_h2d_bytes_total{member}` (the stacked batch handed to the device,
-pad images included) and `serve_pixels_total{member}` (real pixels
-served). With a `trace=` recorder, every dispatch is a 'dispatch' span
+pad images included), `serve_d2h_bytes_total{member, container}` (the
+answers fetched back, pad images included, in their pixel container) and
+`serve_pixels_total{member}` (real pixels served). With a `trace=` recorder, every dispatch is a 'dispatch' span
 (its `batch` id inherited from the server's 'batch' span, `member`,
 `skey`, `warm`) around the workload's leaf phase spans (prep, upload,
 launch, device_wait, fetch; `repro.obs.trace.PHASES`), emits
@@ -112,6 +113,17 @@ SCALE_OUT_MODES = ("sharded", "streamed")
 
 def next_pow2(n: int) -> int:
     return 1 << max(0, (int(n) - 1)).bit_length()
+
+
+def warm_container(nbits: int, workload: str = "filter",
+                   exec_mode: str = "local") -> str:
+    """The pixel container a warm-up at `nbits` compiles for when none is
+    named: a multiplier wider than 8 bits is asked for by pixels wider
+    than 8 bits, which come in uint16 (DESIGN.md §5) and are served on
+    exec='local'; everything else, and every workload but 'filter', in
+    uint8."""
+    wide = workload == "filter" and exec_mode == "local" and nbits > 8
+    return "uint16" if wide else "uint8"
 
 
 class BatchExecutor:
@@ -159,6 +171,7 @@ class BatchExecutor:
         self._c_isolated = m.counter("serve_isolated_total")
         self._c_degraded = m.counter("serve_degraded_total")
         self._c_h2d = m.counter("serve_h2d_bytes_total")
+        self._c_d2h = m.counter("serve_d2h_bytes_total")
         self._c_pixels = m.counter("serve_pixels_total")
         # ------------------------------ §12 fault-tolerance state
         self.failures: dict[str, int] = {}   # bucket -> consecutive failures
@@ -203,11 +216,17 @@ class BatchExecutor:
         self._c_h2d.inc(h2d_bytes, member=self.name)
         self._c_pixels.inc(pixels, member=self.name)
 
+    def count_fetch(self, d2h_bytes: int, container: str) -> None:
+        """One dispatch's device -> host bytes, by pixel container (§15)."""
+        self._c_d2h.inc(d2h_bytes, member=self.name, container=container)
+
     # -------------------------------------------------- per-bucket plan memo
     def _plan(self, filt: str, method: str, mult_impl: str, n: int, h: int,
-              w: int) -> dict:
+              w: int, *, nbits: int = 8, container: str = "uint8") -> dict:
         """Explicit plan fields for a local-exec (n, h, w) dispatch of
-        `filt` -- the full `PlanConfig` (dataflow, resolved mult_impl, grid
+        `filt` on `container` pixels at `nbits` (their widths decide
+        whether a KCM ROM exists) -- the full `PlanConfig` (dataflow,
+        resolved mult_impl, grid
         organization, DESIGN.md §11) resolved once per (bucket, traced
         batch size), pinned on every later call (the §10 hot-path
         memoisation: all-explicit fields take `resolve_plan`'s fast path).
@@ -217,7 +236,7 @@ class BatchExecutor:
         server's lifetime, and is LRU-bounded at `plan_memo_max` entries
         so long-tail shape traffic cannot grow it without limit
         (DESIGN.md §13)."""
-        memo_key = (filt, method, mult_impl, n, h, w)
+        memo_key = (filt, method, mult_impl, n, h, w, nbits, container)
         with self._lock:
             gen = cache_generation()
             if gen != self._plans_gen:
@@ -231,7 +250,8 @@ class BatchExecutor:
             self._c_plan_misses.inc(member=self.name)
         cfg = resolve_filter_plan(filt, n, h, w, method=method,
                                   mult_impl=mult_impl,
-                                  interpret=self.interpret)
+                                  interpret=self.interpret, nbits=nbits,
+                                  container=container)
         plan = {"separable": cfg.dataflow != "direct",
                 "fused": cfg.dataflow == "fused",
                 "mult_impl": cfg.mult_impl,
@@ -247,11 +267,13 @@ class BatchExecutor:
         return plan
 
     def _exec_kw(self, exec_mode: str, filt: str, method: str,
-                 mult_impl: str, n: int, h: int, w: int) -> dict:
+                 mult_impl: str, n: int, h: int, w: int, *, nbits: int = 8,
+                 container: str = "uint8") -> dict:
         """Complete per-dispatch kwargs, mult_impl included (the local plan
         pins its resolved impl; scale-out modes forward the request's)."""
         if exec_mode == "local":
-            return dict(self._plan(filt, method, mult_impl, n, h, w))
+            return dict(self._plan(filt, method, mult_impl, n, h, w,
+                                   nbits=nbits, container=container))
         if exec_mode == "sharded":
             return {"exec": "sharded", "devices": self.devices,
                     "mult_impl": mult_impl}
@@ -270,7 +292,8 @@ class BatchExecutor:
         tracing is on; the memo makes it a plan-memo hit."""
         if mode == "local" and r0.workload == "filter":
             h, w = r0.img.shape
-            p = self._plan(r0.filt, r0.method, r0.mult_impl, traced_n, h, w)
+            p = self._plan(r0.filt, r0.method, r0.mult_impl, traced_n, h, w,
+                           nbits=r0.nbits, container=r0.container)
             df = ("fused" if p["fused"]
                   else "two_pass" if p["separable"] else "direct")
             tag = (f"{df}/{p['mult_impl']}"
@@ -454,19 +477,22 @@ class BatchExecutor:
         executable underneath is priority-blind and shared. `workload`
         selects the §14 workload class doing the compiling (filter by
         default; `filt` then names that workload's target, e.g. an infer
-        model)."""
+        model). The pixel container is the one `nbits` is for
+        (`warm_container`, DESIGN.md §5)."""
         h, w = shape
         traced_n = next_pow2(n) if self.pad_pow2 else n
+        container = warm_container(nbits, workload, exec_mode)
         key = bucket_key(filt, method, mult_impl, exec_mode, nbits, h, w,
-                         priority, workload)
+                         priority, workload, container)
         with self._placed(exec_mode):
             self.workloads[workload].warm(
                 self, (h, w), filt, method=method, mult_impl=mult_impl,
-                exec_mode=exec_mode, nbits=nbits, traced_n=traced_n)
+                exec_mode=exec_mode, nbits=nbits, traced_n=traced_n,
+                container=container)
         skey = serve_key(key, traced_n)
         with self._lock:
             self.warmed.add(skey)
         return skey
 
 
-__all__ = ["BatchExecutor", "SCALE_OUT_MODES", "next_pow2"]
+__all__ = ["BatchExecutor", "SCALE_OUT_MODES", "next_pow2", "warm_container"]

@@ -59,7 +59,11 @@ import jax
 
 from repro.obs.metrics import MetricsRegistry
 from repro.serve.batcher import MicroBatch
-from repro.serve.executor import SCALE_OUT_MODES, BatchExecutor
+from repro.serve.executor import (
+    SCALE_OUT_MODES,
+    BatchExecutor,
+    warm_container,
+)
 from repro.serve.request import bucket_key
 
 #: pool-member lifecycle states.
@@ -274,7 +278,8 @@ class ExecutorPool:
         `ImageFilterServer.warmup()` drive pools unchanged)."""
         h, w = shape
         key = bucket_key(filt, method, mult_impl, exec_mode, nbits, h, w,
-                         priority, workload)
+                         priority, workload,
+                         warm_container(nbits, workload, exec_mode))
         return self.route(key).executor.warm(
             (h, w), filt, method=method, mult_impl=mult_impl,
             exec_mode=exec_mode, nbits=nbits, n=n, priority=priority,

@@ -2,7 +2,8 @@
 
 A `FilterRequest` is one client image plus its full datapath routing --
 the bank filter, multiplier method, tap-product implementation, pixel
-width and execution mode -- and, since §13, its *service level*: a
+width, execution mode and pixel container (uint8, or uint16 for pixels
+wider than 8 bits, DESIGN.md §5) -- and, since §13, its *service level*: a
 priority class, a tenant, and an optional latency SLO. The micro-batcher
 coalesces concurrent requests whose `bucket_key` agrees -- same (H, W),
 same routing, same priority class -- into one (N, H, W) batch riding the
@@ -67,16 +68,20 @@ class DeadlineExceeded(RuntimeError):
 
 def bucket_key(filt: str, method: str, mult_impl: str, exec_mode: str,
                nbits: int, h: int, w: int, priority: str = "normal",
-               workload: str = "filter") -> str:
+               workload: str = "filter", container: str = "uint8") -> str:
     """Coalescing key: requests sharing it may ride one micro-batch.
     Priority is part of the key (DESIGN.md §13): classes never coalesce,
     so shedding or deprioritising 'low' can never touch a 'high' batch.
-    A non-default workload class (DESIGN.md §14) is appended as a suffix --
-    filter keys keep their historical spelling, and the exec mode stays
-    the 4th segment (the pool's `_native_mode` contract) -- so distinct
+    A pixel container other than uint8 (DESIGN.md §5), then a non-default
+    workload class (DESIGN.md §14), are appended as suffixes -- uint8
+    filter keys keep their historical spelling, which the pool's
+    rendezvous routing hashes, and the exec mode stays the 4th segment
+    (the pool's `_native_mode` contract) -- so distinct containers and
     workloads can never share a batch."""
     key = (f"{filt}/{method}/{mult_impl}/{exec_mode}/b{nbits}/{h}x{w}"
            f"/{priority}")
+    if container != "uint8":
+        key = f"{key}/{container}"
     return key if workload == "filter" else f"{key}/{workload}"
 
 
@@ -152,12 +157,14 @@ class FilterRequest:
     slo: float | None = None     # absolute SLO instant (controller target)
     weight: int = 1              # weighted admission slots (request_weight)
     workload: str = "filter"     # serving workload class (DESIGN.md §14)
+    container: str = "uint8"     # the answer's pixel dtype (DESIGN.md §5)
 
     @property
     def key(self) -> str:
         h, w = self.img.shape
         return bucket_key(self.filt, self.method, self.mult_impl, self.exec,
-                          self.nbits, h, w, self.priority, self.workload)
+                          self.nbits, h, w, self.priority, self.workload,
+                          self.container)
 
     @property
     def rank(self) -> int:

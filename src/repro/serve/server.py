@@ -80,7 +80,7 @@ on (the `serve_obs_overhead` bench row).
 
 `stats()` reports the per-request counters (now per-priority too), the
 batch occupancy histogram, flush-trigger counts, the warm compile-cache
-ledger, the host -> device `transfer` counters, the §13
+ledger, the host <-> device `transfer` counters, the §13
 plan-memo/controller/tenant/pool surfaces, and the §12 fault counters --
 everything the serve benchmarks and the
 `--smoke-serve` / `--smoke-fault` / `--smoke-slo` / `--smoke-obs`
@@ -184,6 +184,7 @@ class ImageFilterServer:
         self._c_occupancy = m.counter("serve_batch_occupancy_total")
         self._h_latency = m.histogram("serve_request_latency_seconds")
         self._c_h2d = m.counter("serve_h2d_bytes_total")
+        self._c_d2h = m.counter("serve_d2h_bytes_total")
         self._c_pixels = m.counter("serve_pixels_total")
         self._last_error: str | None = None
         # ------------------------------------------------ serving machinery
@@ -313,7 +314,8 @@ class ImageFilterServer:
                                 submitted=now, seq=self._seq,
                                 deadline=deadline, priority=priority,
                                 tenant=tenant, slo=slo, weight=weight,
-                                workload=workload)
+                                workload=workload,
+                                container=wl.container(arr))
             if self.trace.enabled:
                 # stamped with the instants buffered before the seq existed
                 key = req.key
@@ -355,7 +357,8 @@ class ImageFilterServer:
 
     def stats(self) -> dict:
         """Counters + occupancy histogram + warm-cache ledger + the
-        host -> device `transfer` counters (per pool member) + the
+        `transfer` counters (host -> device bytes and real pixels per pool
+        member, device -> host bytes per pixel container) + the
         `conv_tap_leaves` gauge (base products per output pixel of each
         REFMLM conv kernel the process built) + the §12 fault/health
         surface + the §13 service-level surface.
@@ -392,7 +395,8 @@ class ImageFilterServer:
             snap["rejected"] = gate["rejected"]
             snap["tenants"] = gate["tenants"]
             snap["transfer"] = {"h2d_bytes": self._c_h2d.group_by("member"),
-                                "pixels": self._c_pixels.group_by("member")}
+                                "pixels": self._c_pixels.group_by("member"),
+                                "d2h_bytes": self._c_d2h.group_by("container")}
         ex = self._executor.stats()
         snap["compile"] = {"warmed": ex["warmed"], "hits": ex["hits"],
                            "misses": ex["misses"]}

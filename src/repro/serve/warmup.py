@@ -48,7 +48,9 @@ def sweep(executor: BatchExecutor, shapes, filters, methods, mult_impls,
     cross product (§13 buckets are per-class); the compiled executables
     are priority-blind, so extra classes cost bookkeeping, not compiles.
     `workload` selects the §14 class being warmed ('filter' by default;
-    `filters` then names that workload's targets)."""
+    `filters` then names that workload's targets). The pixel container
+    follows `nbits` (`repro.serve.executor.warm_container`): a width above
+    8 bits warms the uint16 container of wider pixels (DESIGN.md §5)."""
     keys = []
     for (h, w), filt, method, impl, em, n, pri in itertools.product(
             shapes, filters, methods, mult_impls, execs, batches,

@@ -1,12 +1,13 @@
 """Pluggable serving workload classes (DESIGN.md §14).
 
 A `Workload` is everything the serving machinery does NOT need to know
-about the work it coalesces: payload validation, the actual device
-dispatch of a flushed bucket, deploy-time warmup, and the cost-model hook
-the adaptive controller prices flushes with. Everything else -- admission,
-weighted quotas, shape-bucketed batching, priorities, SLO-adaptive flush
-policy, bisection fault isolation, the elastic pool -- operates on
-`FilterRequest`/`MicroBatch` alone and carries over unchanged (§10-§13).
+about the work it coalesces: payload validation and its pixel container,
+the actual device dispatch of a flushed bucket, deploy-time warmup, and
+the cost-model hook the adaptive controller prices flushes with.
+Everything else -- admission, weighted quotas, shape-bucketed batching,
+priorities, SLO-adaptive flush policy, bisection fault isolation, the
+elastic pool -- operates on `FilterRequest`/`MicroBatch` alone and
+carries over unchanged (§10-§13).
 
 Two instances ship:
 
@@ -28,7 +29,14 @@ import numpy as np
 
 from repro.filters.bank import get_filter
 from repro.filters.conv import MULT_IMPLS
-from repro.filters.pipeline import BATCH_DTYPE, apply_filter_batch
+from repro.filters.pipeline import (
+    BATCH_DTYPE,
+    CONTAINERS,
+    apply_filter_batch,
+    check_method_nbits,
+    container_of,
+    filter_nbits,
+)
 from repro.serve.request import FilterRequest, request_weight
 
 
@@ -49,6 +57,11 @@ class Workload:
         """Weighted admission slots this payload occupies (§13)."""
         return request_weight(*arr.shape[:2])
 
+    def container(self, arr: np.ndarray) -> str:
+        """The pixel container the validated payload is answered in
+        (`FilterRequest.container`, part of its bucket key)."""
+        return "uint8"
+
     def execute(self, executor, requests: tuple[FilterRequest, ...],
                 traced_n: int, exec_mode: str) -> list[np.ndarray]:
         """One dispatch of a coalesced bucket slice on `executor`'s
@@ -58,7 +71,7 @@ class Workload:
 
     def warm(self, executor, shape: tuple[int, int], target: str, *,
              method: str, mult_impl: str, exec_mode: str, nbits: int,
-             traced_n: int) -> None:
+             traced_n: int, container: str = "uint8") -> None:
         """Compile one (bucket, traced batch size) point with dummy data."""
         raise NotImplementedError
 
@@ -73,7 +86,10 @@ class Workload:
 class FilterWorkload(Workload):
     """The image-filter path: one micro-batch becomes one
     `apply_filter_batch` call riding the §8 batch fold, planned by the
-    executor's §11 plan memo, routed by the §9 exec modes."""
+    executor's §11 plan memo, routed by the §9 exec modes. A uint16
+    payload is answered in uint16 (DESIGN.md §5) on exec='local' only, by
+    a method that has the widths its filter needs there; every other
+    payload keeps the uint8 contract."""
 
     name = "filter"
 
@@ -89,31 +105,48 @@ class FilterWorkload(Workload):
         if arr.ndim != 2:
             raise ValueError(f"expected one (H, W) image per request, got "
                              f"shape {arr.shape}")
+        container = container_of(arr)
+        if container != "uint8":
+            if exec_mode != "local":
+                raise ValueError(f"exec={exec_mode!r} answers uint8 pixels "
+                                 f"only; {container} pixels take "
+                                 f"exec='local'")
+            check_method_nbits(method, filter_nbits(get_filter(target),
+                                                    nbits, container))
         return arr
+
+    def container(self, arr: np.ndarray) -> str:
+        return container_of(arr)
 
     def execute(self, executor, requests: tuple[FilterRequest, ...],
                 traced_n: int, exec_mode: str) -> list[np.ndarray]:
         r0 = requests[0]
         h, w = r0.img.shape
         kw = executor._exec_kw(exec_mode, r0.filt, r0.method, r0.mult_impl,
-                               traced_n, h, w)
+                               traced_n, h, w, nbits=r0.nbits,
+                               container=r0.container)
         executor.count_transfer(
             traced_n * h * w * np.dtype(BATCH_DTYPE).itemsize,
             len(requests) * h * w)
-        return apply_filter_batch(
+        outs = apply_filter_batch(
             [r.img for r in requests], r0.filt, pad_to=traced_n,
             method=r0.method, nbits=r0.nbits,
             interpret=executor.interpret, **kw)
+        # the fetched batch, pad images included, in the answers' dtype
+        executor.count_fetch(traced_n * h * w * outs[0].dtype.itemsize,
+                             r0.container)
+        return outs
 
     def warm(self, executor, shape: tuple[int, int], target: str, *,
              method: str, mult_impl: str, exec_mode: str, nbits: int,
-             traced_n: int) -> None:
+             traced_n: int, container: str = "uint8") -> None:
         h, w = shape
         kw = executor._exec_kw(exec_mode, target, method, mult_impl,
-                               traced_n, h, w)
-        apply_filter_batch([np.zeros((h, w), BATCH_DTYPE)] * traced_n, target,
-                           method=method, nbits=nbits,
-                           interpret=executor.interpret, **kw)
+                               traced_n, h, w, nbits=nbits,
+                               container=container)
+        zeros = np.zeros((h, w), CONTAINERS[container])
+        apply_filter_batch([zeros] * traced_n, target, method=method,
+                           nbits=nbits, interpret=executor.interpret, **kw)
 
     def model_bound(self, req: FilterRequest, n: int, *,
                     backend: str | None = None) -> float | None:
@@ -123,7 +156,8 @@ class FilterWorkload(Workload):
         h, w = req.img.shape
         spec = get_filter(req.filt)
         plan = resolve_filter_plan(spec, n, h, w, method=req.method,
-                                   mult_impl=req.mult_impl)
+                                   mult_impl=req.mult_impl, nbits=req.nbits,
+                                   container=req.container)
         kh, kw = ((len(spec.sep_col), len(spec.sep_row))
                   if plan.dataflow == "fused" else spec.ksize)
         cost = plan_cost(plan.dataflow, plan.mult_impl, n, h, w, kh, kw,

@@ -144,6 +144,7 @@ def resolve_plan(
     block_cols: int | None = None,
     batch_fold: bool | None = None,
     interpret: bool | None = None,
+    kcm_ok: bool = True,
 ) -> PlanConfig:
     """The single plan lookup path: explicit > cached > pre-plan defaults.
 
@@ -163,7 +164,9 @@ def resolve_plan(
         cache/heuristic inside the conv passes.
 
     A cached 'kcm' plan is dropped when the passes compile (`interpret`
-    resolves False): Mosaic cannot lower the KCM gather.
+    resolves False): Mosaic cannot lower the KCM gather. It is dropped too
+    where `kcm_ok` is False: a pass wider than a KCM ROM (the 32-bit column
+    pass of 16-bit pixels).
     """
     allowed = allowed_dataflows(separable_ok, separable, fused)
     if (len(allowed) == 1 and mult_impl != "auto"
@@ -181,7 +184,7 @@ def resolve_plan(
         if cand is not None and cand.dataflow not in allowed:
             cand = None
         if (cand is not None and cand.mult_impl == "kcm"
-                and not resolve_interpret(interpret)):
+                and not (kcm_ok and resolve_interpret(interpret))):
             cand = None
         if cand is not None:
             if mult_impl != "auto" and cand.mult_impl != mult_impl:

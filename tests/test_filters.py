@@ -169,8 +169,11 @@ class TestShapesAndSpecs:
     def test_second_pass_nbits(self):
         assert second_pass_nbits(200, 8) == 8
         assert second_pass_nbits(4080, 16) == 16
-        with pytest.raises(ValueError):
-            second_pass_nbits(1 << 16, 1)
+        # 16-bit pixels: gaussian5's row sums reach 65535 * 16 = 1048560
+        assert second_pass_nbits(1 << 16, 1) == 32
+        assert second_pass_nbits(65535 * 16, 6) == 32
+        with pytest.raises(ValueError, match="32-bit"):
+            second_pass_nbits(1 << 32, 1)
 
     def test_unknown_filter_raises(self):
         with pytest.raises(ValueError, match="unknown filter"):

@@ -409,7 +409,8 @@ class TestServerTracing:
             serve_all(srv, futs)
             transfer = srv.stats()["transfer"]
         assert transfer == {"h2d_bytes": {"": 4 * h * w * 8},
-                            "pixels": {"": h * w * 7}}
+                            "pixels": {"": h * w * 7},
+                            "d2h_bytes": {"uint8": 1 * h * w * 8}}
 
     def test_snapshot_cli(self, tmp_path, capsys):
         path = str(tmp_path / "t.jsonl")

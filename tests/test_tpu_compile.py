@@ -81,6 +81,20 @@ def test_16_bit_second_pass(one_chip):
         one_chip, ((8, 480, 640), jnp.int32)))
 
 
+def test_16_bit_container_fused_gaussian5(one_chip):
+    """A served batch of 8 CT slices of 512 x 512 in uint16: the 16-bit
+    row pass and the 32-bit column pass of gaussian5 in the one fused
+    kernel, baked taps, answered in uint16."""
+    text = compiled_text(
+        lambda x: apply_filter(x, "gaussian5", method="refmlm", nbits=16,
+                               container="uint16", interpret=False),
+        one_chip, ((8, 512, 512), jnp.int32))
+    assert_mosaic(text)
+    assert "%refmlm_conv_fused" in text
+    assert coef_operands(text, "refmlm_conv_fused") == []
+    assert re.search(r"ROOT \S+ = u16\[8,512,512\]", text)
+
+
 @pytest.mark.parametrize("kind", ["direct", "fused"])
 def test_folded_small_batch_default_blocks(one_chip, kind):
     """The heuristic's folded band for a served 8 x 128 x 128 batch fits
