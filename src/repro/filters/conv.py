@@ -453,6 +453,7 @@ def conv2d_pass(
     mult_impl: str = "auto",
 ) -> Array:
     """One batched convolution pass: (N, H, W) int32 -> (N, H, W) int32.
+    uint8 and uint16 input is widened to int32 inside the jit.
 
     Any (N, H, W) is accepted: the pass pads rows/columns to tile multiples
     internally and crops the output back. Unset grid fields (`block_rows`,

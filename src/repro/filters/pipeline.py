@@ -46,11 +46,14 @@ from repro.filters.conv import (
 from repro.obs import trace as obs_trace
 from repro.tuning.plans import PlanConfig, resolve_plan
 
-#: the dtype `apply_filter_batch` stacks a batch in for the device.
+#: the dtype `apply_filter_batch` stacks a batch in for the device when
+#: its pixels are not all in one container (`batch_dtype`).
 BATCH_DTYPE = np.int32
 
 #: the pixel containers, by name: a request is answered in its own.
 CONTAINERS = {"uint8": np.uint8, "uint16": np.uint16}
+
+_NARROW = tuple(np.dtype(d) for d in CONTAINERS.values())
 
 
 def container_of(img) -> str:
@@ -96,8 +99,22 @@ def check_method_nbits(method: str, widths) -> None:
                          f"(its widest is {top} bits)")
 
 
+def batch_dtype(imgs) -> np.dtype:
+    """The dtype `apply_filter_batch` sends `imgs` to the device in: their
+    container when every image is an ndarray of one container's dtype
+    (uint8 or uint16; the jitted conv passes widen it to int32 on the
+    device), else `BATCH_DTYPE`, so an int32 pixel above 255 never wraps."""
+    dtype = getattr(imgs[0], "dtype", None)
+    if dtype in _NARROW and all(isinstance(im, np.ndarray)
+                                and im.dtype == dtype for im in imgs):
+        return np.dtype(dtype)
+    return np.dtype(BATCH_DTYPE)
+
+
 def _normalize(imgs: Array) -> tuple[Array, tuple[int, ...]]:
-    """-> ((N, H, W) int32, original shape). Accepts (H,W)/(N,H,W)/(N,H,W,1)."""
+    """-> ((N, H, W), original shape). Accepts (H,W)/(N,H,W)/(N,H,W,1).
+    uint8 and uint16 pixels keep their dtype, which the jitted conv passes
+    widen to int32 inside the jit; any other dtype is cast to int32."""
     orig = imgs.shape
     if imgs.ndim == 4:
         if orig[-1] != 1:
@@ -107,6 +124,8 @@ def _normalize(imgs: Array) -> tuple[Array, tuple[int, ...]]:
         imgs = imgs[None]
     elif imgs.ndim != 3:
         raise ValueError(f"expected (H,W), (N,H,W) or (N,H,W,1), got {orig}")
+    if np.dtype(imgs.dtype) in _NARROW:
+        return imgs, orig
     return imgs.astype(jnp.int32), orig
 
 
@@ -405,13 +424,15 @@ def apply_filter_batch(
     image's own zero halo, so batch neighbors (and zero pads) can never
     leak into a request's pixels (asserted in tests/test_serve.py).
 
-    The stack to `BATCH_DTYPE` drops the images' dtype, so their
+    The batch crosses to the device in `batch_dtype(imgs)`: the pixels'
+    own container (1 or 2 bytes a pixel) when they share one, widened to
+    int32 inside the jitted conv passes; `BATCH_DTYPE` otherwise. Their
     container (`container_of` the first image) is handed to
     `apply_filter` explicitly, and the outputs come back in it.
 
     The steps are the serve worker's phase spans (`repro.obs.trace`,
-    recorded only while a recorder is active): 'prep' (stack, cast to
-    `BATCH_DTYPE`, pad), 'upload' (`jax.device_put` to the default
+    recorded only while a recorder is active): 'prep' (copy the images
+    into one zero-padded batch), 'upload' (`jax.device_put` to the default
     device, which the executor sets to its pool member's), 'launch' (the
     `apply_filter` dispatch), 'device_wait' (`block_until_ready`) and
     'fetch' (the copy back and the split). The scale-out modes place
@@ -432,10 +453,10 @@ def apply_filter_batch(
     n = len(imgs)
     container = container_of(imgs[0])
     with obs_trace.span("prep"):
-        batch = np.stack([np.asarray(im) for im in imgs]).astype(BATCH_DTYPE)
-        if pad_to is not None and pad_to > n:
-            batch = np.concatenate(
-                [batch, np.zeros((pad_to - n, *shape), BATCH_DTYPE)])
+        batch = np.empty((max(n, pad_to or 0), *shape), batch_dtype(imgs))
+        for i, im in enumerate(imgs):
+            batch[i] = im
+        batch[n:] = 0
     local = kw.get("exec", "local") == "local"
     if local:
         with obs_trace.span("upload"):
@@ -467,6 +488,6 @@ def filter_bank_apply(
 
 
 __all__ = ["BATCH_DTYPE", "CONTAINERS", "EXEC_MODES", "apply_filter",
-           "apply_filter_batch", "check_method_nbits", "container_of",
-           "container_top", "filter_bank_apply", "filter_nbits",
-           "resolve_filter_blocks", "resolve_filter_plan"]
+           "apply_filter_batch", "batch_dtype", "check_method_nbits",
+           "container_of", "container_top", "filter_bank_apply",
+           "filter_nbits", "resolve_filter_blocks", "resolve_filter_plan"]

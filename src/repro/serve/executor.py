@@ -75,8 +75,10 @@ one registry without colliding); the historical attribute API
 (`ex.hits`, `ex.retries`, ...) is preserved as properties reading the
 registry. Always-on counters price the host <-> device traffic:
 `serve_h2d_bytes_total{member}` (the stacked batch handed to the device,
-pad images included), `serve_d2h_bytes_total{member, container}` (the
-answers fetched back, pad images included, in their pixel container) and
+pad images included, in the dtype it crosses in: 1 or 2 bytes a slot for
+uint8 or uint16 pixels, 4 for the int32 batch of any other dtype),
+`serve_d2h_bytes_total{member, container}` (the answers fetched back,
+pad images included, in their pixel container) and
 `serve_pixels_total{member}` (real pixels served). With a `trace=` recorder, every dispatch is a 'dispatch' span
 (its `batch` id inherited from the server's 'batch' span, `member`,
 `skey`, `warm`) around the workload's leaf phase spans (prep, upload,

@@ -30,9 +30,9 @@ import numpy as np
 from repro.filters.bank import get_filter
 from repro.filters.conv import MULT_IMPLS
 from repro.filters.pipeline import (
-    BATCH_DTYPE,
     CONTAINERS,
     apply_filter_batch,
+    batch_dtype,
     check_method_nbits,
     container_of,
     filter_nbits,
@@ -125,11 +125,13 @@ class FilterWorkload(Workload):
         kw = executor._exec_kw(exec_mode, r0.filt, r0.method, r0.mult_impl,
                                traced_n, h, w, nbits=r0.nbits,
                                container=r0.container)
+        imgs = [r.img for r in requests]
+        # the batch as uploaded, pad images included, in its wire dtype
         executor.count_transfer(
-            traced_n * h * w * np.dtype(BATCH_DTYPE).itemsize,
+            traced_n * h * w * batch_dtype(imgs).itemsize,
             len(requests) * h * w)
         outs = apply_filter_batch(
-            [r.img for r in requests], r0.filt, pad_to=traced_n,
+            imgs, r0.filt, pad_to=traced_n,
             method=r0.method, nbits=r0.nbits,
             interpret=executor.interpret, **kw)
         # the fetched batch, pad images included, in the answers' dtype

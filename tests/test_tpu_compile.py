@@ -95,6 +95,26 @@ def test_16_bit_container_fused_gaussian5(one_chip):
     assert re.search(r"ROOT \S+ = u16\[8,512,512\]", text)
 
 
+@pytest.mark.parametrize("name,method,nbits,shape,dtype", [
+    ("gaussian5", "refmlm", 8, (8, 480, 640), jnp.uint8),
+    ("sobel_y", "mitchell", 8, (8, 480, 640), jnp.uint8),
+    ("gaussian3", "refmlm", 8, (1, 1080, 1920), jnp.uint8),
+    ("gaussian5", "refmlm", 16, (8, 512, 512), jnp.uint16),
+])
+def test_served_batch_compiles_in_its_container(one_chip, name, method,
+                                                nbits, shape, dtype):
+    """A served batch reaches the device in its pixel container and is
+    widened to int32 on the device, ahead of the kernel."""
+    text = compiled_text(
+        lambda x: apply_filter(x, name, method=method, nbits=nbits,
+                               interpret=False),
+        one_chip, (shape, dtype))
+    assert_mosaic(text)
+    wire = "u8" if dtype == jnp.uint8 else "u16"
+    dims = ",".join(map(str, shape))
+    assert re.search(rf"%x\S* = {wire}\[{dims}\]\S* parameter\(0\)", text)
+
+
 @pytest.mark.parametrize("kind", ["direct", "fused"])
 def test_folded_small_batch_default_blocks(one_chip, kind):
     """The heuristic's folded band for a served 8 x 128 x 128 batch fits
