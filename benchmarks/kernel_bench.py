@@ -5,8 +5,6 @@ Reports us/call + achieved element-throughput for the three kernels across
 block-size variants, plus the batched filter-bank pipeline across filters x
 batch sizes and the before/after pairs of DESIGN.md §7/§8:
 
-  * recursion-vs-KCM      -- per-tap KOM recursion vs constant-coefficient
-                             product-table gather (the FPGA KCM analogue);
   * fused-vs-two-pass     -- one-kernel separable (VMEM halo band) vs two
                              kernels with an HBM int32 intermediate;
   * separable-vs-direct   -- kh+kw vs kh*kw tap products per pixel;
@@ -25,16 +23,13 @@ host-device mesh -- requires the process to start with
 device the sharded rows are skipped) vs streamed (out-of-core tiles) on
 the n=32 batch, with bit-identity recorded alongside throughput.
 
-``--smoke`` runs the reduced-size regression guards used by
-scripts/check.sh: the KCM path must not lose to the recursion path, and
-batched throughput (n=8) must not fall below single-image throughput for
-any guarded bank filter. ``--smoke-dist`` is the multi-device guard:
+``--smoke`` runs the reduced-size regression guard used by
+scripts/check.sh: batched throughput (n=8) must not fall below
+single-image throughput for any guarded bank filter. ``--smoke-dist`` is the multi-device guard:
 sharded output must be bit-identical to local and sharded n=32 throughput
 must not fall below local n=32 on any guarded filter. ``--smoke-tune`` is
-the §11 plan-tuning guard: the committed gaussian5 dataflow winner must
-beat the losing alternatives (within jitter slack) and a pruned replay of
-an exhaustive sweep must keep the same winner while timing strictly fewer
-candidates.
+the §11 plan-tuning guard: a pruned replay of an exhaustive sweep must
+keep the same winner while timing strictly fewer candidates.
 """
 from __future__ import annotations
 
@@ -61,41 +56,33 @@ def _img_batch(rng, batch: int, h: int = 128, w: int = 128):
 
 
 def _bank_variants(imgs, *, tag: str):
-    """The §7 before/after pairs on the 5x5 Gaussian refmlm path."""
+    """The §7 dataflow pairs on the 5x5 Gaussian refmlm path: direct
+    (kh·kw taps), two-pass and fused separable (kh+kw taps)."""
     npix = imgs.shape[0] * imgs.shape[1] * imgs.shape[2]
     out = {}
-    for impl in ("recurse", "kcm"):
+    for name, kw in (("direct", dict(separable=False)),
+                     ("two_pass", dict(separable=True, fused=False)),
+                     ("fused", dict(separable=True, fused=True))):
         us = time_fn(lambda x: apply_filter(x, "gaussian5", method="refmlm",
-                                            separable=False, mult_impl=impl),
+                                            **kw),
                      imgs, iters=3)
-        emit(f"kernel_{tag}gaussian5_refmlm_{impl}", us,
-             f"mpix_s={npix/us:.2f}")
-        out[impl] = us
-    for name, fused in (("two_pass", False), ("fused", True)):
-        us = time_fn(lambda x: apply_filter(x, "gaussian5", method="refmlm",
-                                            separable=True, fused=fused),
-                     imgs, iters=3)
-        emit(f"kernel_{tag}gaussian5_sep_{name}", us, f"mpix_s={npix/us:.2f}")
+        row = name if name == "direct" else f"sep_{name}"
+        emit(f"kernel_{tag}gaussian5_{row}", us, f"mpix_s={npix/us:.2f}")
         out[name] = us
-    emit(f"kernel_{tag}gaussian5_kcm_speedup", out["recurse"] / out["kcm"],
-         "x_vs_recurse")
     emit(f"kernel_{tag}gaussian5_fused_speedup",
          out["two_pass"] / out["fused"], "x_vs_two_pass")
-    # §11: the default call resolves the committed per-shape plan. Report
-    # it against the best forced row of a *different* dataflow so the
-    # speedup reads "dataflow winner vs best losing alternative" -- ~1.0x
-    # or better whenever the cache still matches this machine (guarded by
-    # scripts/check.sh --smoke-tune).
+    # §11: the default call resolves the per-shape plan (the cached one
+    # where the backend has a tuning cache, else the heuristic). Report it
+    # against the best forced row of a *different* dataflow so the speedup
+    # reads "dataflow winner vs best losing alternative".
     plan = resolve_filter_plan("gaussian5", *imgs.shape, method="refmlm")
     us = time_fn(lambda x: apply_filter(x, "gaussian5", method="refmlm"),
                  imgs, iters=3)
     emit(f"kernel_{tag}gaussian5_dataflow_winner", us,
-         f"mpix_s={npix/us:.2f}", dataflow=plan.dataflow,
-         mult_impl=plan.mult_impl)
+         f"mpix_s={npix/us:.2f}", dataflow=plan.dataflow)
     out["winner"] = us
-    forced = {"direct": "kcm", "two_pass": "two_pass", "fused": "fused"}
-    best_loser = min(out[k] for df, k in forced.items()
-                     if df != plan.dataflow)
+    best_loser = min(us for df, us in out.items()
+                     if df not in (plan.dataflow, "winner"))
     emit(f"kernel_{tag}gaussian5_winner_speedup", best_loser / us,
          "x_vs_best_losing_dataflow")
     return out
@@ -203,8 +190,8 @@ def main():
     emit("kernel_limb_matmul_scratch_speedup",
          us / matmul_us["limb_True"], "x_vs_output_accum")
 
-    # legacy single-image shim: must ride the KCM fast path (auto), not the
-    # per-tap recursion its old jit-traced taps forced (§8 satellite fix).
+    # legacy single-image shim: its concrete taps are baked into the kernel
+    # (§7), not traced into SMEM as its old outer jit forced.
     img = jnp.asarray(rng.integers(0, 256, (256, 256)), jnp.int32)
     kern = jnp.asarray(gaussian_kernel_3x3())
     for meth in ("exact", "refmlm", "mitchell"):
@@ -219,32 +206,19 @@ def main():
     # execution-mode rows (§9): local vs sharded vs streamed at n=32.
     _dist_variants(rng, tag="dist_")
 
-    imgs = _img_batch(rng, 4)
-    # separable (k+k taps) vs direct (k*k taps) on the 5x5 Gaussian.
-    for sep in (True, False):
-        us = time_fn(lambda x: apply_filter(x, "gaussian5", method="refmlm",
-                                            separable=sep), imgs, iters=3)
-        emit(f"kernel_bank_gaussian5_{'sep' if sep else 'direct'}", us,
-             f"mpix_s={4*128*128/us:.2f}")
-    # the §7 before/after pairs: recursion-vs-KCM, fused-vs-two-pass.
-    _bank_variants(imgs, tag="bank_")
+    # the §7 dataflow pairs on the 5x5 Gaussian: direct (k*k taps) vs
+    # separable (k+k taps), two-pass vs fused, and the default call.
+    _bank_variants(_img_batch(rng, 4), tag="bank_")
 
 
 def smoke(threshold: float = 1.0) -> int:
-    """Reduced-size perf regression guards (scripts/check.sh).
+    """Reduced-size perf regression guard (scripts/check.sh).
 
-    Fails when (a) the KCM path is slower than the recursion path on the
-    5x5 Gaussian, or (b) n=8 batched throughput (mpix/s) falls below n=1
-    for any guarded bank filter -- the §8 batch-scaling guarantee. The
-    generous 1.0x thresholds only catch a fast path *losing*, not noise."""
+    Fails when n=8 batched throughput (mpix/s) falls below n=1 for any
+    guarded bank filter -- the §8 batch-scaling guarantee. The generous
+    1.0x threshold only catches batching *losing*, not noise."""
     rng = np.random.default_rng(0)
-    out = _bank_variants(_img_batch(rng, 2, 64, 64), tag="smoke_")
     rc = 0
-    speedup = out["recurse"] / out["kcm"]
-    print(f"# smoke: kcm {speedup:.2f}x vs recursion (threshold {threshold}x)")
-    if speedup < threshold:
-        print("# FAIL: KCM fast path is slower than the recursion path")
-        rc = 1
     mpix = _bank_scaling(rng, tag="smoke_", h=64, w=64,
                          filters=SCALING_GUARD_FILTERS)
     for filt in SCALING_GUARD_FILTERS:
@@ -292,29 +266,18 @@ def smoke_dist(threshold: float = 1.0) -> int:
     return rc
 
 
-def smoke_tune(threshold: float = 0.8) -> int:
+def smoke_tune() -> int:
     """Plan-tuning guard (scripts/check.sh --smoke-tune, DESIGN.md §11).
 
     For each --quick sweep shape: (a) time every plan candidate once
     (exhaustive, prune=False), (b) replay the recorded timings through the
     pruned sweep and fail if pruning changed the winner, timed as many
     candidates as the exhaustive pass, or skipped nothing -- the roofline
-    loop may only save time, never flip the winner; (c) fail if the
-    *committed* gaussian5 plan loses to the best measured time of any
-    other dataflow by more than the jitter slack -- the shipped cache must
-    still be the right call on this machine. The 0.8x threshold (after a
-    median-of-5 head-to-head confirmation) deliberately tolerates the
-    (2, 64, 64) shape, where direct and two_pass genuinely tie and trade
-    places run to run, while still catching every real inversion: a wrong
-    dataflow measures 0.4-0.7x at the n=8 shape and a wrong mult_impl
-    ~0.01x. Takes a few minutes: the exhaustive pass times the ~90x
-    slower recursion candidates the real sweep exists to prune.
+    loop may only save time, never flip the winner. Takes a few minutes:
+    the exhaustive pass times every candidate the real sweep prunes.
     """
-    from repro.tuning import load_plans, plan_key
-    from repro.tuning.autotune import PLAN_QUICK, measure_plan, sweep_plan
-    from repro.tuning.plans import PlanConfig
+    from repro.tuning.autotune import PLAN_QUICK, sweep_plan
 
-    plans = load_plans()
     rc = 0
     for name, n, h, w in PLAN_QUICK:
         print(f"# smoke-tune: exhaustive {name} n{n}x{h}x{w} plan sweep "
@@ -325,17 +288,16 @@ def smoke_tune(threshold: float = 0.8) -> int:
 
         replay, _ = sweep_plan(name, n, h, w, prune=True,
                                measure_fn=lambda p: timed[p], verbose=False)
-        keys = ("dataflow", "mult_impl", "block_rows", "block_cols",
-                "batch_fold")
+        keys = ("dataflow", "block_rows", "block_cols", "batch_fold")
         print(f"# smoke-tune: {name} n{n}x{h}x{w} exhaustive winner "
-              f"{full['dataflow']}/{full['mult_impl']} "
+              f"{full['dataflow']} "
               f"br={full['block_rows']} bc={full['block_cols']} "
               f"fold={full['batch_fold']} ({full['us_per_call']}us); pruned "
               f"replay swept {replay['swept']}/{replay['candidates']} "
               f"(pruned {replay['pruned']})")
         if any(replay[k] != full[k] for k in keys):
             print(f"# FAIL: pruning discarded the measured winner (replay "
-                  f"picked {replay['dataflow']}/{replay['mult_impl']} "
+                  f"picked {replay['dataflow']} "
                   f"br={replay['block_rows']} bc={replay['block_cols']} "
                   f"fold={replay['batch_fold']})")
             rc = 1
@@ -343,41 +305,6 @@ def smoke_tune(threshold: float = 0.8) -> int:
                 and replay["swept"] < replay["candidates"]):
             print("# FAIL: pruned replay timed every candidate -- the "
                   "roofline loop is not pruning")
-            rc = 1
-
-        entry = plans.get(plan_key(name, n, h, w))
-        if not entry:
-            print(f"# FAIL: no committed plan for {plan_key(name, n, h, w)} "
-                  "-- regenerate with `python -m repro.tuning.autotune`")
-            rc = 1
-            continue
-        cached = PlanConfig(entry["dataflow"], entry["mult_impl"],
-                            int(entry["block_rows"]), int(entry["block_cols"]),
-                            bool(entry["batch_fold"]))
-        cached_us = timed.get(cached)
-        if cached_us is None:     # cache predates the current candidate grid
-            cached_us = measure_plan(name, cached, n, h, w, iters=1)
-        losers = {p: us for p, us in records if p.dataflow != cached.dataflow}
-        loser_plan = min(losers, key=losers.get)
-        ratio = losers[loser_plan] / cached_us
-        print(f"# smoke-tune: cached {name} n{n}x{h}x{w} winner "
-              f"{cached.dataflow}/{cached.mult_impl} runs {cached_us:.1f}us "
-              f"vs best losing dataflow {losers[loser_plan]:.1f}us "
-              f"({ratio:.2f}x, threshold {threshold}x)")
-        if ratio < threshold:
-            # the exhaustive pass took one iters=1 sample each way; on
-            # shapes where two dataflows genuinely tie that flips on noise,
-            # so confirm head-to-head with medians before failing
-            cached_us = measure_plan(name, cached, n, h, w, iters=5)
-            loser_us = measure_plan(name, loser_plan, n, h, w, iters=5)
-            ratio = loser_us / cached_us
-            print(f"# smoke-tune: head-to-head re-measure (median of 5): "
-                  f"{cached.dataflow} {cached_us:.1f}us vs "
-                  f"{loser_plan.dataflow} {loser_us:.1f}us ({ratio:.2f}x)")
-        if ratio < threshold:
-            print(f"# FAIL: the committed {cached.dataflow} plan loses to "
-                  "another dataflow beyond jitter slack -- regenerate the "
-                  "cache with `python -m repro.tuning.autotune`")
             rc = 1
     return rc
 

@@ -598,7 +598,7 @@ def smoke_slo() -> int:
     rng = np.random.default_rng(5)
     imgs = [rng.integers(0, 256, (32, 32)).astype(np.int32)
             for _ in range(6)]
-    key = bucket_key("gaussian3", "refmlm", "auto", "sharded", 8, 32, 32,
+    key = bucket_key("gaussian3", "refmlm", "sharded", 8, 32, 32,
                      "normal")
     target = max(("m0", "m1"), key=lambda m: rendezvous_score(m, key))
     inj = FaultInjector().on_key(SITE_EXECUTE,

@@ -183,22 +183,17 @@ def infer_models():
 
 
 def assert_served_plans() -> None:
-    """The executor's plan for every served bucket takes the recursion
-    (Mosaic cannot lower the KCM gather) and lowers to a Mosaic kernel."""
+    """The executor's plan for the first served bucket lowers to a Mosaic
+    kernel."""
     import numpy as np
     from repro.filters import apply_filter, resolve_filter_plan
 
-    for (h, w), filt, method, _ in SERVE_MIX:
-        plan = resolve_filter_plan(filt, 1, h, w, method=method)
-        check(plan.mult_impl == "recurse",
-              f"served plan for {filt}/{h}x{w} pins {plan.mult_impl!r}")
     (h, w), filt, method, _ = SERVE_MIX[0]
     plan = resolve_filter_plan(filt, 1, h, w, method=method)
     assert_compiled(
         lambda v: apply_filter(v, filt, method=method,
                                separable=plan.dataflow != "direct",
                                fused=plan.dataflow == "fused",
-                               mult_impl=plan.mult_impl,
                                block_rows=plan.block_rows,
                                block_cols=plan.block_cols,
                                batch_fold=plan.batch_fold),
@@ -345,7 +340,7 @@ def pool_buckets() -> list:
     members = [f"m{i}" for i in range(len(POOL))]
     chosen: dict[str, tuple] = {}
     for shape, filt, method in POOL_CANDIDATES:
-        key = bucket_key(filt, method, "auto", "local", 8, *shape)
+        key = bucket_key(filt, method, "local", 8, *shape)
         owner = max(members, key=lambda name: rendezvous_score(name, key))
         chosen.setdefault(owner, (shape, filt, method))
     check(len(chosen) == len(members),

@@ -11,11 +11,9 @@
 #   scripts/check.sh --smoke-obs    observability guard only (DESIGN.md §15)
 #
 # The perf smoke runs benchmarks/kernel_bench.py --smoke on a reduced size
-# and fails if (a) the KCM constant-coefficient path is slower than the
-# per-tap recursion path on the 5x5 Gaussian (DESIGN.md §7 guard) or
-# (b) n=8 batched throughput falls below n=1 for any guarded bank filter
-# (the DESIGN.md §8 batch-scaling guard). Generous 1.0x thresholds so only
-# a real inversion trips them.
+# and fails if n=8 batched throughput falls below n=1 for any guarded bank
+# filter (the DESIGN.md §8 batch-scaling guard). A generous 1.0x threshold
+# so only a real inversion trips it.
 #
 # The multi-device smoke (--smoke-dist) restarts the bench with 8 host
 # platform devices and fails if sharded/streamed output ever differs from
@@ -29,12 +27,10 @@
 # spot-checked bit-identical to the direct apply_filter call.
 #
 # The plan-tuning smoke (--smoke-tune, kernel_bench.py --smoke-tune) is the
-# DESIGN.md §11 guard: the committed gaussian5 dataflow winner must beat
-# the losing alternatives within jitter slack on the smoke shapes, and a
-# pruned replay of an exhaustive plan sweep must select the same winner
-# while timing strictly fewer candidates (pruning may only save time,
-# never flip the winner). Opt-in -- the exhaustive pass times the ~90x
-# slower recursion candidates, so it takes a few minutes.
+# DESIGN.md §11 guard: a pruned replay of an exhaustive plan sweep must
+# select the same winner while timing strictly fewer candidates (pruning
+# may only save time, never flip the winner). Opt-in -- the exhaustive
+# pass times every candidate, so it takes a few minutes.
 #
 # The fault smoke (--smoke-fault, serve_bench.py --smoke-fault) is the
 # DESIGN.md §12 guard: a deterministically poisoned request must be

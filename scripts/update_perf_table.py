@@ -5,13 +5,12 @@ BENCH_serve.json + BENCH_infer.json.
     PYTHONPATH=src python -m benchmarks.run        # writes the artifacts
     python scripts/update_perf_table.py            # splices the README table
 
-The table is the curated DESIGN.md §7/§8 before/after story (recursion vs
-KCM, two-pass vs fused, separable vs direct, serial batch axis vs
-batch-folded parallel grid) plus the §10 serving rows (sequential vs
-coalesced submission under the mixed-shape load generator) and the §11
-tuned-plan row (the default call resolving the committed dataflow winner
-against the best losing alternative); the full row set stays in the JSON
-artifacts. Content between the BENCH_TABLE markers
+The table is the curated DESIGN.md §7/§8 before/after story (two-pass vs
+fused, separable vs direct, serial batch axis vs batch-folded parallel
+grid) plus the §10 serving rows (sequential vs coalesced submission under
+the mixed-shape load generator); the full row set stays in the JSON
+artifacts. These are CPU interpreter timings, not speeds: chip numbers
+are in PERF.md. Content between the BENCH_TABLE markers
 is owned by this script.
 """
 from __future__ import annotations
@@ -26,18 +25,11 @@ END = "<!-- BENCH_TABLE_END -->"
 
 #: (json row name, human label) in display order.
 ROWS = [
-    ("kernel_bank_gaussian5_refmlm_recurse",
-     "5×5 Gaussian, refmlm, direct, per-tap recursion"),
-    ("kernel_bank_gaussian5_refmlm_kcm",
-     "5×5 Gaussian, refmlm, direct, **KCM tables**"),
     ("kernel_bank_gaussian5_sep_two_pass",
      "5×5 Gaussian, refmlm, separable, two kernels (HBM intermediate)"),
     ("kernel_bank_gaussian5_sep_fused",
      "5×5 Gaussian, refmlm, separable, **fused kernel** (VMEM halo band)"),
     ("kernel_bank_gaussian5_direct", "5×5 Gaussian, refmlm, direct (kh·kw taps)"),
-    ("kernel_bank_gaussian5_sep", "5×5 Gaussian, refmlm, separable (kh+kw taps)"),
-    ("kernel_bank_gaussian5_dataflow_winner",
-     "5×5 Gaussian, refmlm, **default call = cached plan winner** (§11)"),
     ("kernel_bank_gaussian3_n8_nofold",
      "3×3 Gaussian, refmlm, batch n=8, serial batch axis"),
     ("kernel_bank_gaussian3_n8",
@@ -67,10 +59,7 @@ ROWS = [
      "CNN inference, mitchell_ecc2 (Babic 2-bit error correction)"),
 ]
 SPEEDUPS = [
-    ("kernel_bank_gaussian5_kcm_speedup", "KCM vs recursion"),
     ("kernel_bank_gaussian5_fused_speedup", "fused vs two-pass"),
-    ("kernel_bank_gaussian5_winner_speedup",
-     "tuned plan vs best losing dataflow (§11)"),
     ("kernel_bank_gaussian3_fold_speedup", "batch fold vs serial batch (n=8)"),
     ("kernel_bank_gaussian3_batch_scaling", "n=8 vs n=1 throughput"),
     ("kernel_dist_gaussian5_sharded_speedup", "sharded vs local (n=32, §9)"),

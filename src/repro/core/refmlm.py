@@ -38,7 +38,7 @@ Evaluation strategies (DESIGN.md §7):
       so all leaves execute as ONE stacked base call over a leading
       digit-plane axis -- 16 kernel-visible base calls collapse to 1 at
       8-bit kom4 (64 -> 1 at 16-bit). Bit-identical to the unrolled
-      recursion by construction, asserted in tests/test_kcm.py.
+      recursion by construction, asserted in tests/test_tap_paths.py.
   flatten=False -- the paper-literal Python-unrolled recursion, kept as the
       structural reference.
 

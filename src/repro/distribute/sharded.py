@@ -211,7 +211,7 @@ def sharded_apply_filter(imgs: Array, filt: FilterSpec | str, *,
     """`repro.filters.apply_filter` over the (batch, rows) mesh.
 
     Accepts the same image shapes ((H, W), (N, H, W), (N, H, W, 1)) and
-    filter keywords (method, nbits, separable, fused, mult_impl, block_*,
+    filter keywords (method, nbits, separable, fused, block_*,
     interpret) as the local entry point and returns a bit-identical uint8
     batch. The per-shard pass resolves its block shapes from the
     shard-local shape (DESIGN.md §9)."""

@@ -20,7 +20,7 @@ stitching bit-identical). Planner invariants (asserted in tests):
 `np.memmap` -- only the rows a window touches are ever faulted in), tiles
 are gathered `tile_batch` at a time into one (k, TH, TW) batch, pushed
 through the ordinary local `apply_filter` (any multiplier, any dataflow,
-any `mult_impl` -- the datapath is untouched), and the owned region of
+any grid -- the datapath is untouched), and the owned region of
 each output tile is written incrementally into `out` (a caller-provided
 array or memmap for gigapixel outputs, else an allocated ndarray). The
 datapath traces with the *tile-local* batch shape, so the block-shape
@@ -114,7 +114,7 @@ def _normalize_src(src) -> tuple[np.ndarray, tuple[int, ...]]:
 #: datapath kwargs that identify the bytes a plan produces; filled into
 #: the fingerprint so the direct `stream_filter` spelling and the
 #: `apply_filter(exec='streamed')` spelling of one plan agree
-_FP_DEFAULTS = {"method": "refmlm", "mult_impl": "auto", "nbits": 8}
+_FP_DEFAULTS = {"method": "refmlm", "nbits": 8}
 
 
 def journal_fingerprint(orig: tuple, name: str, th: int, tw: int,
@@ -168,7 +168,7 @@ def stream_filter(src, filt: FilterSpec | str, *,
     into one uniform batch, riding the PR-3 batch fold); `out` -- optional
     preallocated uint8 array (or memmap) of the source's shape; `kw` -- the
     local `apply_filter` keywords (method, nbits, separable, fused,
-    mult_impl, block_*, interpret). Returns `out` (allocated if None),
+    block_*, interpret). Returns `out` (allocated if None),
     bit-identical to the local pass (DESIGN.md §9). `out` must not alias
     `src` (including two memmaps of one file): overlapping tiles read
     neighbor halos from the source, so in-place streaming would read back

@@ -21,7 +21,6 @@ from repro.filters.bank import (
 )
 from repro.filters.conv import (
     METHODS,
-    MULT_IMPLS,
     choose_block_rows,
     conv2d_pass,
     fused_separable_pass,
@@ -41,7 +40,6 @@ __all__ = [
     "FILTER_BANK",
     "FILTER_NAMES",
     "METHODS",
-    "MULT_IMPLS",
     "FilterSpec",
     "apply_filter",
     "apply_filter_batch",

@@ -51,27 +51,15 @@ Either way every tap product routes through the selected multiplier via
 the signed-magnitude contract (DESIGN.md §4):
 p = sgn(t)*sgn(c)*mult(|t|,|c|), so negative coefficients (sharpen, Sobel,
 Laplacian) reuse the unsigned paper multipliers unchanged. The in-register
-accumulation is the CSA tree, carried at the narrowest width the exact
-table-bound analysis admits (int16 when every |partial sum| < 2**15, the
-direct-path analogue of `second_pass_nbits`; DESIGN.md §8); `post` then
-applies the filter's fixed-point normalization ('clip'), gradient-magnitude
-display ('abs'), or nothing ('none', the separable intermediate) in int32,
-clipping to `top`, the top of the pixels' container (255 for uint8, 65535
-for uint16; DESIGN.md §5).
+accumulation is the CSA tree, carried in int32; `post` then applies the
+filter's fixed-point normalization ('clip'), gradient-magnitude display
+('abs'), or nothing ('none', the separable intermediate), clipping to
+`top`, the top of the pixels' container (255 for uint8, 65535 for uint16;
+DESIGN.md §5).
 
-Tap-product implementations (`mult_impl`, DESIGN.md §7):
-  * 'recurse' -- expand the selected multiplier's dataflow per tap (for
-    'refmlm': the constant-operand kom4 recursion when the taps are baked,
-    the digit-plane-flattened KOM recursion when they ride in SMEM);
-  * 'kcm'     -- constant-coefficient fast path: coefficients are trace-time
-    constants, so each tap is a `repro.core.kcm` product-table gather
-    (sign baked in), bit-identical to 'recurse' for every method;
-  * 'auto'    -- 'kcm' whenever the taps are static (not traced), the
-    pass runs under the interpreter and its widest multiplier has at most
-    `KCM_MAX_NBITS` bits, else 'recurse'. Compiled TPU passes always take
-    'recurse': Mosaic lowers only 2-D gathers, not the 1-D ROM lookup. A
-    32-bit pass (the column pass of 16-bit pixels) always takes it too: its
-    ROM would need 2**32 entries a tap.
+One tap-product implementation, the selected multiplier's recursion, in
+two forms chosen by `_is_static` (DESIGN.md §7): taps readable at trace
+time are baked (`const_tap_multiplier`), traced taps ride in SMEM.
 
 Multiplier methods: 'exact', 'refmlm', 'refmlm_nc', 'mitchell',
 'mitchell_ecc{k}', 'odma' -- see repro/core and DESIGN.md §1.
@@ -87,23 +75,12 @@ from jax import Array
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.kcm import (
-    KCM_MAX_NBITS,
-    METHODS,
-    const_tap_multiplier,
-    filter_tables,
-    tables_acc_bound,
-    tap_multiplier,
-)
 from repro.core.platform import grid_compiler_params, resolve_interpret
 from repro.core.refmlm import const_base_mults, flat_base_mults
+from repro.core.taps import METHODS, const_tap_multiplier, tap_multiplier
 from repro.obs.metrics import MetricsRegistry
 from repro.tuning import choose_block_rows, resolve_blocks
 from repro.tuning.blocks import min_block_cols, min_block_rows, round_up
-
-MULT_IMPLS = ("recurse", "kcm", "auto")
-
-_ACC_DTYPES = {"int16": jnp.int16, "int32": jnp.int32}
 
 #: Kernel-build telemetry. Process-wide, like the jit cache whose builds it
 #: records: a kernel traced once serves every later caller.
@@ -113,8 +90,7 @@ _REFMLM_METHODS = ("refmlm", "refmlm_nc")
 
 
 def accumulate_taps(bands, k_ref, acc_shape, *, kh: int, kw: int, w: int,
-                    method: str, nbits: int, tables=None,
-                    acc_dtype=jnp.int32) -> Array:
+                    method: str, nbits: int) -> Array:
     """Shared CSA-tree body: Σ_taps sgn * mult(|tap|, |coeff|) over a band.
 
     `bands` -- kh arrays of shape (..., w + kw - 1); `k_ref` -- the (kh, kw)
@@ -123,51 +99,34 @@ def accumulate_taps(bands, k_ref, acc_shape, *, kh: int, kw: int, w: int,
     product takes `const_tap_multiplier`. One definition serves every
     dataflow so the direct / separable / fused paths are bit-exact by
     construction.
-
-    With `tables` (a (kh*kw, 2**nbits) KCM ROM stack, coefficient signs
-    baked in) each tap product becomes a gather -- `k_ref`/`method` are then
-    unused and the contract reduces to sgn(tap) * tables[tap_idx][|tap|].
-    `acc_dtype` is the accumulator carry width; callers may narrow it to
-    int16 only when the exact bound analysis proves every partial sum fits
-    (`tables_acc_bound`, DESIGN.md §8) -- the sum is then value-identical to
-    the int32 carry.
     """
-    acc = jnp.zeros(acc_shape, acc_dtype)
+    acc = jnp.zeros(acc_shape, jnp.int32)
     baked = isinstance(k_ref, tuple)
-    if tables is not None:
-        mult = None
-    else:
-        mult = const_tap_multiplier(method) if baked else tap_multiplier(method)
+    mult = const_tap_multiplier(method) if baked else tap_multiplier(method)
     for di in range(kh):
         band = bands[di]
         for dj in range(kw):
             if baked and k_ref[di][dj] == 0:
                 continue
             tap = band[..., dj : dj + w]
-            if tables is not None:
-                prod = jnp.take(tables[di * kw + dj], jnp.abs(tap), axis=0)
-                term = jnp.sign(tap).astype(acc_dtype) * prod.astype(acc_dtype)
-            elif baked:
+            if baked:
                 c = k_ref[di][dj]
                 term = jnp.sign(tap) * mult(jnp.abs(tap), abs(c), nbits)
-                term = (-term if c < 0 else term).astype(acc_dtype)
+                term = -term if c < 0 else term
             else:
                 c = k_ref[di, dj]
                 prod = mult(jnp.abs(tap),
                             jnp.broadcast_to(jnp.abs(c), tap.shape), nbits)
-                term = (jnp.sign(c) * jnp.sign(tap) * prod).astype(acc_dtype)
-            acc = acc + term
+                term = jnp.sign(c) * jnp.sign(tap) * prod
+            acc = acc + term.astype(jnp.int32)
     return acc
 
 
 def apply_post(acc: Array, *, post: str, shift: int, top: int = 255) -> Array:
     """Fixed-point epilogue: rounding shift + clip / abs / raw (DESIGN.md §5).
 
-    Always widens to int32 first so a narrow accumulator keeps rounding
-    headroom (the carry bound covers the sum, not the +2**(shift-1) bias).
     'clip' and 'abs' clip to [0, top], the top of the pixels' container.
     """
-    acc = acc.astype(jnp.int32)
     if post == "none":
         return acc
     if post == "abs":
@@ -176,41 +135,6 @@ def apply_post(acc: Array, *, post: str, shift: int, top: int = 255) -> Array:
     if post in ("clip", "abs"):
         return jnp.clip(rounded, 0, top)
     raise ValueError(f"unknown post {post!r}")
-
-
-@functools.lru_cache(maxsize=None)
-def _host_tables(method: str, taps_key: tuple, shape: tuple, nbits: int):
-    """Stacked KCM ROMs (narrow dtype) + their exact accumulator bound."""
-    taps = np.asarray(taps_key, np.int64).reshape(shape)
-    stack = filter_tables(method, taps, nbits)
-    return stack, tables_acc_bound(stack)
-
-
-@functools.lru_cache(maxsize=None)
-def _device_tables(method: str, taps_key: tuple, shape: tuple, nbits: int):
-    """Device-resident ROM stack, cached per coefficient table.
-
-    `product_table` already caches the per-coefficient host ROMs; this layer
-    keeps the stacked, device-put array out of the per-call hot path (the
-    16-bit second-pass stack is ~128 KiB per tap at the narrowed width).
-    Forced eager: the cached array must be a concrete constant even when
-    the first request arrives inside a trace (shard_map in the distributed
-    path, DESIGN.md §9) -- an lru-cached tracer would leak into every
-    later call."""
-    with jax.ensure_compile_time_eval():
-        return jnp.asarray(_host_tables(method, taps_key, shape, nbits)[0])
-
-
-def _tables_for(method: str, taps, nbits: int):
-    """-> (device ROM stack, accumulator carry dtype name)."""
-    flat = np.asarray(taps, np.int64)
-    key = (method, tuple(flat.reshape(-1).tolist()), flat.shape, nbits)
-    bound = _host_tables(*key)[1]
-    if bound >= (1 << 31):
-        raise ValueError(f"accumulator bound {bound} exceeds the int32 "
-                         "datapath; narrow the taps or nbits")
-    acc = "int16" if bound < (1 << 15) else "int32"
-    return _device_tables(*key), acc
 
 
 def _baked(taps, shape: tuple[int, int]) -> tuple[tuple[int, ...], ...]:
@@ -252,32 +176,6 @@ def _is_static(taps) -> bool:
         return False
 
 
-def _resolve_mult_impl(mult_impl: str, *tap_arrays, interpret: bool,
-                       nbits: int = 8) -> str:
-    """'auto' -> 'kcm' for static taps under the interpreter at widths up to
-    `KCM_MAX_NBITS`, else 'recurse'. `nbits` is the widest multiplier of the
-    pass. Compiled (Mosaic) passes never take 'kcm': its ROM lookup is a
-    1-D gather, which Mosaic does not lower."""
-    if mult_impl not in MULT_IMPLS:
-        raise ValueError(f"mult_impl must be one of {MULT_IMPLS}, got {mult_impl!r}")
-    static = all(_is_static(t) for t in tap_arrays)
-    narrow = nbits <= KCM_MAX_NBITS
-    if mult_impl == "auto":
-        return "kcm" if static and interpret and narrow else "recurse"
-    if mult_impl == "kcm" and not narrow:
-        raise ValueError(f"mult_impl='kcm' builds ROMs of up to "
-                         f"{KCM_MAX_NBITS} bits, and this pass multiplies at "
-                         f"{nbits}; use 'recurse' (bit-identical) or 'auto'")
-    if mult_impl == "kcm" and not interpret:
-        raise ValueError("mult_impl='kcm' runs only under the Pallas "
-                         "interpreter: Mosaic cannot lower the KCM table "
-                         "gather; use 'recurse' (bit-identical) or 'auto'")
-    if mult_impl == "kcm" and not static:
-        raise ValueError("mult_impl='kcm' needs trace-time-constant taps; "
-                         "traced coefficients must use 'recurse'")
-    return mult_impl
-
-
 # ----------------------------------------------------------------- batch fold
 
 def _fold_batch(imgs: Array, ph: int) -> Array:
@@ -301,14 +199,12 @@ def _unfold_batch(out: Array, n: int, h: int, ph: int) -> Array:
 # ---------------------------------------------------------------- single pass
 
 def _kernel(*refs, kh: int, kw: int, method: str, nbits: int, shift: int,
-            post: str, top: int, kcm: bool, taps, tiled: bool, acc: str):
+            post: str, top: int, taps, tiled: bool):
     """`taps` -- the baked (kh, kw) int tuples; None when the first ref is
-    the coefficient operand (the SMEM table, or with `kcm` the ROM stack)."""
+    the SMEM coefficient table."""
     *band_refs, o_ref = refs
-    tables = None
     if taps is None:
-        coef_ref, *band_refs = band_refs
-        taps, tables = (None, coef_ref[...]) if kcm else (coef_ref, None)
+        taps, *band_refs = band_refs
     bc = o_ref.shape[-1]
     if tiled:
         # paired column-block views j / j+1: their concatenation holds the
@@ -318,8 +214,7 @@ def _kernel(*refs, kh: int, kw: int, method: str, nbits: int, shift: int,
     else:
         bands = [band_refs[di][0] for di in range(kh)]  # each (br, bc + kw - 1)
     tacc = accumulate_taps(bands, taps, o_ref.shape[1:], kh=kh, kw=kw, w=bc,
-                           method=method, nbits=nbits, tables=tables,
-                           acc_dtype=_ACC_DTYPES[acc])
+                           method=method, nbits=nbits)
     o_ref[...] = apply_post(tacc, post=post, shift=shift, top=top)[None]
 
 
@@ -328,8 +223,8 @@ def _pass_call(imgs: Array, coefs: tuple, coef_specs: tuple, kernel, *,
                interpret: bool) -> Array:
     """Shared pallas_call plumbing for one tiled convolution pass.
 
-    `coefs` -- the coefficient operand ahead of the image views (the SMEM
-    table or the KCM ROM stack), or () when the kernel bakes its taps.
+    `coefs` -- the SMEM coefficient table ahead of the image views, or ()
+    when the kernel bakes its taps.
 
     `bc`/`tiled` come pre-derived from `_dispatch` (the single source): the
     kernel's static band-unpacking mode must match the spec layout built
@@ -391,27 +286,8 @@ def _conv2d_recurse(imgs, coef, *, taps, method, nbits, shift, post, top,
     def call(x, bc, tiled):
         k = functools.partial(_kernel, kh=kh, kw=kw, method=method,
                               nbits=nbits, shift=shift, post=post, top=top,
-                              kcm=False, taps=taps, tiled=tiled, acc="int32")
+                              taps=taps, tiled=tiled)
         return _pass_call(x, coefs, specs, k, kh=kh, kw=kw,
-                          block_rows=block_rows, bc=bc, tiled=tiled,
-                          interpret=interpret)
-
-    return _dispatch(imgs, call, kh=kh, kw=kw, batch_fold=batch_fold,
-                     block_cols=block_cols)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "kh", "kw", "shift", "post", "top", "block_rows", "block_cols",
-    "batch_fold", "interpret", "acc"))
-def _conv2d_kcm(imgs, tables, *, kh, kw, shift, post, top, block_rows,
-                block_cols, batch_fold, interpret, acc):
-    spec = pl.BlockSpec(tables.shape, lambda nn, i, j: (0, 0))  # whole ROM, VMEM
-
-    def call(x, bc, tiled):
-        k = functools.partial(_kernel, kh=kh, kw=kw, method="", nbits=0,
-                              shift=shift, post=post, top=top, kcm=True,
-                              taps=None, tiled=tiled, acc=acc)
-        return _pass_call(x, (tables,), (spec,), k, kh=kh, kw=kw,
                           block_rows=block_rows, bc=bc, tiled=tiled,
                           interpret=interpret)
 
@@ -450,7 +326,6 @@ def conv2d_pass(
     block_cols: int | None = None,
     batch_fold: bool | None = None,
     interpret: bool | None = None,
-    mult_impl: str = "auto",
 ) -> Array:
     """One batched convolution pass: (N, H, W) int32 -> (N, H, W) int32.
     uint8 and uint16 input is widened to int32 inside the jit.
@@ -463,25 +338,15 @@ def conv2d_pass(
     intermediate); `nbits` must cover the widest |operand| on either side of
     each tap product. `top` is the clip of 'clip' and 'abs', the top of
     the pixels' container. interpret=None autodetects the backend
-    (DESIGN.md §7); mult_impl picks the tap-product implementation (module
-    docstring).
+    (DESIGN.md §7). Taps readable at trace time are baked into the kernel,
+    traced taps ride in SMEM (module docstring).
     """
     interpret = resolve_interpret(interpret)
-    impl = _resolve_mult_impl(mult_impl, taps, interpret=interpret,
-                              nbits=nbits)
     n, h, w = imgs.shape
     kh, kw = np.shape(taps)     # list/tuple taps accepted, Tracers untouched
-    cfg = resolve_blocks("direct", n, h, w, kh, kw, impl,
+    cfg = resolve_blocks("direct", n, h, w, kh, kw,
                          block_rows=block_rows, block_cols=block_cols,
                          batch_fold=batch_fold, interpret=interpret)
-    if impl == "kcm":
-        taps_np = np.asarray(taps)
-        tables, acc = _tables_for(method, taps_np, nbits)
-        return _conv2d_kcm(imgs, tables, kh=kh, kw=kw, shift=shift, post=post,
-                           top=top, block_rows=cfg.block_rows,
-                           block_cols=cfg.block_cols,
-                           batch_fold=cfg.batch_fold, interpret=interpret,
-                           acc=acc)
     baked = _is_static(taps)
     return _conv2d_recurse(imgs,
                            None if baked else jnp.asarray(taps, jnp.int32),
@@ -495,13 +360,13 @@ def conv2d_pass(
 # ------------------------------------------------------------ fused separable
 
 def _fused_kernel(*refs, kh: int, kw: int, method: str, nbits: int,
-                  nbits2: int, shift: int, post: str, top: int, kcm: bool,
-                  taps, tiled: bool):
+                  nbits2: int, shift: int, post: str, top: int, taps,
+                  tiled: bool):
     """Both separable passes on one tile (DESIGN.md §7/§8 halo math).
 
     `taps` -- the baked ((1, kw), (kh, 1)) int tuples of the row and column
-    filters; None when the first two refs are their coefficient operands
-    (SMEM tables, or with `kcm` the ROM stacks).
+    filters; None when the first two refs are their SMEM coefficient
+    tables.
 
     The band refs are block views of the same padded image whose
     concatenation holds the (br + 2*ph, bc + 2*pw) input window feeding this
@@ -510,14 +375,10 @@ def _fused_kernel(*refs, kh: int, kw: int, method: str, nbits: int,
     accumulator never leaves VMEM.
     """
     *band_refs, o_ref = refs
-    row_tab = col_tab = None
     if taps is None:
         row_ref, col_ref, *band_refs = band_refs
-        if kcm:
-            row_tab, col_tab = row_ref[...], col_ref[...]
-        else:
-            taps = (row_ref, col_ref)
-    row_taps, col_taps = (None, None) if taps is None else taps
+        taps = (row_ref, col_ref)
+    row_taps, col_taps = taps
     rows, bc = o_ref.shape[1], o_ref.shape[2]
     ph, pw = kh // 2, kw // 2
     if tiled:
@@ -530,10 +391,10 @@ def _fused_kernel(*refs, kh: int, kw: int, method: str, nbits: int,
         full = jnp.concatenate((band_refs[0][0], band_refs[1][0]),
                                axis=0)[: rows + 2 * ph]
     hacc = accumulate_taps([full], row_taps, (rows + 2 * ph, bc), kh=1, kw=kw,
-                           w=bc, method=method, nbits=nbits, tables=row_tab)
+                           w=bc, method=method, nbits=nbits)
     vbands = [hacc[di : di + rows] for di in range(kh)]
     acc = accumulate_taps(vbands, col_taps, (rows, bc), kh=kh, kw=1, w=bc,
-                          method=method, nbits=nbits2, tables=col_tab)
+                          method=method, nbits=nbits2)
     o_ref[...] = apply_post(acc, post=post, shift=shift, top=top)[None]
 
 
@@ -607,31 +468,11 @@ def _fused_sep_recurse(imgs, row, col, *, taps, method, nbits, nbits2, shift,
     def call(x, bc, tiled):
         kernel = functools.partial(_fused_kernel, kh=kh, kw=kw, method=method,
                                    nbits=nbits, nbits2=nbits2, shift=shift,
-                                   post=post, top=top, kcm=False, taps=taps,
+                                   post=post, top=top, taps=taps,
                                    tiled=tiled)
         return _fused_call(x, coefs, specs, kernel, kh=kh, kw=kw,
                            block_rows=block_rows, bc=bc, tiled=tiled,
                            interpret=interpret)
-
-    return _dispatch(imgs, call, kh=kh, kw=kw, batch_fold=batch_fold,
-                     block_cols=block_cols)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "kh", "kw", "shift", "post", "top", "block_rows", "block_cols",
-    "batch_fold", "interpret"))
-def _fused_sep_kcm(imgs, row_tables, col_tables, *, kh, kw, shift, post, top,
-                   block_rows, block_cols, batch_fold, interpret):
-    rspec = pl.BlockSpec(row_tables.shape, lambda nn, i, j: (0, 0))
-    cspec = pl.BlockSpec(col_tables.shape, lambda nn, i, j: (0, 0))
-
-    def call(x, bc, tiled):
-        kernel = functools.partial(_fused_kernel, kh=kh, kw=kw, method="",
-                                   nbits=0, nbits2=0, shift=shift, post=post,
-                                   top=top, kcm=True, taps=None, tiled=tiled)
-        return _fused_call(x, (row_tables, col_tables), (rspec, cspec), kernel,
-                           kh=kh, kw=kw, block_rows=block_rows, bc=bc,
-                           tiled=tiled, interpret=interpret)
 
     return _dispatch(imgs, call, kh=kh, kw=kw, batch_fold=batch_fold,
                      block_cols=block_cols)
@@ -652,7 +493,6 @@ def fused_separable_pass(
     block_cols: int | None = None,
     batch_fold: bool | None = None,
     interpret: bool | None = None,
-    mult_impl: str = "auto",
 ) -> Array:
     """Fused separable convolution: both 1-D passes in one `pallas_call`.
 
@@ -666,12 +506,10 @@ def fused_separable_pass(
     `conv2d_pass` (DESIGN.md §8).
     """
     interpret = resolve_interpret(interpret)
-    impl = _resolve_mult_impl(mult_impl, row, col, interpret=interpret,
-                              nbits=max(nbits, nbits2))
     n, h, w = imgs.shape
     kh = int(np.asarray(col).size) if _is_static(col) else col.shape[-1]
     kw = int(np.asarray(row).size) if _is_static(row) else row.shape[-1]
-    cfg = resolve_blocks("fused", n, h, w, kh, kw, impl,
+    cfg = resolve_blocks("fused", n, h, w, kh, kw,
                          block_rows=block_rows, block_cols=block_cols,
                          batch_fold=batch_fold, interpret=interpret)
     if cfg.block_rows < 2 * (kh // 2):
@@ -679,14 +517,6 @@ def fused_separable_pass(
             raise ValueError(f"block_rows={block_rows} too shallow for a "
                              f"{kh // 2}-row halo")
         cfg = cfg._replace(block_rows=min_block_rows(kh))
-    if impl == "kcm":
-        rt = _tables_for(method, row, nbits)[0]
-        ct = _tables_for(method, col, nbits2)[0]
-        return _fused_sep_kcm(imgs, rt, ct, kh=ct.shape[0], kw=rt.shape[0],
-                              shift=shift, post=post, top=top,
-                              block_rows=cfg.block_rows,
-                              block_cols=cfg.block_cols,
-                              batch_fold=cfg.batch_fold, interpret=interpret)
     if _is_static(row) and _is_static(col):
         taps = (_baked(row, (1, kw)), _baked(col, (kh, 1)))
         row = col = None
@@ -717,11 +547,9 @@ def second_pass_nbits(intermediate_max: int, coeff_max: int) -> int:
 
 __all__ = [
     "METHODS",
-    "MULT_IMPLS",
     "accumulate_taps",
     "apply_post",
     "choose_block_rows",
-    "KCM_MAX_NBITS",
     "KERNEL_METRICS",
     "conv2d_pass",
     "fused_separable_pass",

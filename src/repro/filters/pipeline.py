@@ -30,8 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import Array
 
-from repro.core.kcm import KCM_MAX_NBITS, method_max_nbits
 from repro.core.platform import resolve_interpret
+from repro.core.taps import method_max_nbits
 from repro.filters.bank import (
     FILTER_NAMES,
     FilterSpec,
@@ -138,8 +138,7 @@ def _restore(out: Array, orig: tuple[int, ...]) -> Array:
 
 
 def _apply(imgs: Array, spec: FilterSpec, method: str, nbits: int,
-           separable: bool, fused: bool, mult_impl: str,
-           block_rows: int | None, block_cols: int | None,
+           separable: bool, fused: bool, block_rows: int | None, block_cols: int | None,
            batch_fold: bool | None, interpret: bool | None,
            container: str) -> Array:
     blocks = dict(block_rows=block_rows, block_cols=block_cols,
@@ -151,13 +150,12 @@ def _apply(imgs: Array, spec: FilterSpec, method: str, nbits: int,
             out = fused_separable_pass(
                 imgs, spec.sep_row, spec.sep_col, method=method,
                 nbits=nbits, nbits2=nb2, shift=spec.shift, post=spec.post,
-                top=top, interpret=interpret, mult_impl=mult_impl, **blocks)
+                top=top, interpret=interpret, **blocks)
         else:
-            run = partial(conv2d_pass, interpret=interpret,
-                          mult_impl=mult_impl, **blocks)
+            run = partial(conv2d_pass, interpret=interpret, **blocks)
             # keep the taps host-side NumPy: under a trace (shard_map in the
             # distributed path, DESIGN.md §9) a jnp constant would become a
-            # tracer and defeat the KCM staticness check
+            # tracer, and the pass could no longer bake them
             row = np.asarray(spec.sep_row, np.int32)[None, :]    # (1, kw)
             col = np.asarray(spec.sep_col, np.int32)[:, None]    # (kh, 1)
             tmp = run(imgs, row, method=method, nbits=nbits, shift=0,
@@ -168,7 +166,7 @@ def _apply(imgs: Array, spec: FilterSpec, method: str, nbits: int,
         out = conv2d_pass(imgs, np.asarray(spec.taps, np.int32),
                           method=method, nbits=nbits, shift=spec.shift,
                           post=spec.post, top=top, interpret=interpret,
-                          mult_impl=mult_impl, **blocks)
+                          **blocks)
     return out.astype(CONTAINERS[container])
 
 
@@ -183,7 +181,6 @@ def apply_filter(
     nbits: int = 8,
     separable: bool | None = None,
     fused: bool | None = None,
-    mult_impl: str = "auto",
     block_rows: int | None = None,
     block_cols: int | None = None,
     batch_fold: bool | None = None,
@@ -208,8 +205,7 @@ def apply_filter(
     from it; a method without a multiplier that wide raises ValueError.
     Only exec='local' serves a 'uint16' container.
 
-    The execution plan -- dataflow, tap-product implementation and grid
-    organization -- resolves through the per-backend plan cache
+    The execution plan -- dataflow and grid organization -- resolves through the per-backend plan cache
     (DESIGN.md §11): on default arguments a tuned `PlanConfig` for this
     (filter, batch/image shape) wins, and a cache miss reproduces the
     fixed pre-plan defaults. Explicit arguments always override.
@@ -218,9 +214,7 @@ def apply_filter(
     the two 1-D pass dataflows. Of those, fused=True runs both passes in
     one kernel (DESIGN.md §7) and fused=False forces the two-kernel
     dataflow with its HBM intermediate (the before/after benchmark axis).
-    mult_impl pins the tap-product implementation ('recurse' | 'kcm';
-    'auto' defers to the plan, then to the pass-level resolution --
-    see repro.filters.conv); interpret=None autodetects the backend. The
+    interpret=None autodetects the backend. The
     grid organization (block_rows, block_cols, batch_fold) defaults
     through the plan, then the §8 block cache -- outputs are bit-identical
     across every plan (asserted in tests/test_plan_equivalence.py), so all
@@ -245,7 +239,7 @@ def apply_filter(
         raise ValueError(f"exec={exec!r} answers uint8 pixels only; "
                          f"{container} pixels take exec='local'")
     filter_kw = dict(method=method, nbits=nbits, separable=separable,
-                     fused=fused, mult_impl=mult_impl, block_rows=block_rows,
+                     fused=fused, block_rows=block_rows,
                      block_cols=block_cols, batch_fold=batch_fold,
                      interpret=interpret)
     if exec == "sharded":
@@ -282,13 +276,12 @@ def apply_filter(
     n, h, w = arr.shape
     kh, kw = spec.ksize
     plan = resolve_plan(spec.name, n, h, w, kh, kw,
-                        separable_ok=spec.separable, mult_impl=mult_impl,
+                        separable_ok=spec.separable,
                         separable=separable, fused=fused,
                         block_rows=block_rows, block_cols=block_cols,
-                        batch_fold=batch_fold, interpret=interpret,
-                        kcm_ok=max(widths) <= KCM_MAX_NBITS)
+                        batch_fold=batch_fold)
     out = _apply(arr, spec, method, nbits, plan.dataflow != "direct",
-                 plan.dataflow == "fused", plan.mult_impl, plan.block_rows,
+                 plan.dataflow == "fused", plan.block_rows,
                  plan.block_cols, plan.batch_fold, interpret, container)
     return _restore(out, orig)
 
@@ -300,14 +293,13 @@ def resolve_filter_blocks(
     w: int,
     *,
     method: str = "refmlm",
-    mult_impl: str = "auto",
     separable: bool | None = None,
     fused: bool | None = None,
     interpret: bool | None = None,
 ) -> "BlockConfig":
     """The grid organization `apply_filter` would resolve for an (n, h, w)
-    batch of `filt` -- dataflow kind, tap extents and resolved mult_impl
-    included, one `repro.tuning.resolve_blocks` consult total.
+    batch of `filt` -- dataflow kind and tap extents included, one
+    `repro.tuning.resolve_blocks` consult total.
 
     This is the serving layer's per-bucket memoisation hook (DESIGN.md
     §10): resolve once per (bucket, coalesced batch size), then pin the
@@ -318,7 +310,6 @@ def resolve_filter_blocks(
     is returned in the cache's vocabulary: None means full width, which
     pins explicitly as `block_cols=w`.
     """
-    from repro.filters.conv import _resolve_mult_impl
     from repro.tuning import resolve_blocks_cached
 
     interpret = resolve_interpret(interpret)
@@ -328,13 +319,10 @@ def resolve_filter_blocks(
     if fused and separable:
         kind = "fused"
         kh, kw = len(spec.sep_col), len(spec.sep_row)
-        tap_arrays = (spec.sep_row, spec.sep_col)
     else:
         kind = "direct"
         kh, kw = np.shape(spec.taps)
-        tap_arrays = (spec.taps,)
-    impl = _resolve_mult_impl(mult_impl, *tap_arrays, interpret=interpret)
-    return resolve_blocks_cached(kind, n, h, w, kh, kw, impl, interpret)
+    return resolve_blocks_cached(kind, n, h, w, kh, kw, interpret)
 
 
 def resolve_filter_plan(
@@ -344,64 +332,49 @@ def resolve_filter_plan(
     w: int,
     *,
     method: str = "refmlm",
-    mult_impl: str = "auto",
     separable: bool | None = None,
     fused: bool | None = None,
     interpret: bool | None = None,
-    nbits: int = 8,
-    container: str = "uint8",
 ) -> PlanConfig:
     """The fully-concrete execution plan `apply_filter` would run for an
-    (n, h, w) batch of `filt` on `container` pixels at `nbits`: dataflow,
-    resolved mult_impl and grid organization, one plan-cache consult total
-    (DESIGN.md §11).
+    (n, h, w) batch of `filt`: dataflow and grid organization, one
+    plan-cache consult total (DESIGN.md §11).
 
     This is the serving layer's per-bucket memoisation hook (DESIGN.md
     §10): resolve once per (bucket, coalesced batch size), then pin every
     field explicitly on each `apply_filter` dispatch so the steady-state
     hot path takes `resolve_plan`'s fully-explicit fast path and does no
     cache re-resolution. Fields the plan defers (an untuned shape) are
-    concretized here -- mult_impl through the pass-level staticness
-    resolution, blocks through the §8 block cache of the matching pass
-    kind (a full-width tile pins explicitly as `block_cols=w`). Outputs
-    are bit-identical across plans, so pinning is throughput-only.
+    concretized here through the §8 block cache of the matching pass kind
+    (a full-width tile pins explicitly as `block_cols=w`). Outputs are
+    bit-identical across plans, so pinning is throughput-only.
     """
-    from repro.filters.conv import _resolve_mult_impl
     from repro.tuning import resolve_blocks_cached
 
     interpret = resolve_interpret(interpret)
     spec = get_filter(filt) if isinstance(filt, str) else filt
-    widest = max(filter_nbits(spec, nbits, container))
     plan = resolve_plan(spec.name, n, h, w, *spec.ksize,
-                        separable_ok=spec.separable, mult_impl=mult_impl,
-                        separable=separable, fused=fused, interpret=interpret,
-                        kcm_ok=widest <= KCM_MAX_NBITS)
+                        separable_ok=spec.separable,
+                        separable=separable, fused=fused)
     if plan.dataflow == "fused":
         kind = "fused"
         kh, kw = len(spec.sep_col), len(spec.sep_row)
-        tap_arrays = (spec.sep_row, spec.sep_col)
     elif plan.dataflow == "two_pass":
         # the second (column) pass carries the row halo; its §8 entry sizes
         # the pinned grid when the plan defers
         kind = "direct"
         kh, kw = len(spec.sep_col), 1
-        tap_arrays = (spec.sep_row, spec.sep_col)
     else:
         kind = "direct"
         kh, kw = spec.ksize
-        tap_arrays = (spec.taps,)
-    impl = _resolve_mult_impl(plan.mult_impl, *tap_arrays,
-                              interpret=interpret, nbits=widest)
     if None in (plan.block_rows, plan.block_cols, plan.batch_fold):
-        base = resolve_blocks_cached(kind, n, h, w, kh, kw, impl, interpret)
+        base = resolve_blocks_cached(kind, n, h, w, kh, kw, interpret)
         plan = PlanConfig(
-            plan.dataflow, impl,
+            plan.dataflow,
             base.block_rows if plan.block_rows is None else plan.block_rows,
             (plan.block_cols if plan.block_cols is not None
              else w if base.block_cols is None else base.block_cols),
             base.batch_fold if plan.batch_fold is None else plan.batch_fold)
-    else:
-        plan = plan._replace(mult_impl=impl)
     return plan
 
 

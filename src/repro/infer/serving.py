@@ -73,8 +73,8 @@ class InferWorkload(Workload):
         self.compiles = 0
 
     # ------------------------------------------------------------ validation
-    def validate(self, payload, *, target: str, method: str, mult_impl: str,
-                 exec_mode: str, nbits: int) -> np.ndarray:
+    def validate(self, payload, *, target: str, method: str, exec_mode: str,
+                 nbits: int) -> np.ndarray:
         cal = self.models.get(target)
         if cal is None:
             raise ValueError(f"unknown infer model {target!r}; registered: "
@@ -87,9 +87,6 @@ class InferWorkload(Workload):
             raise ValueError("infer workload serves exec='local' only "
                              f"(got {exec_mode!r}); scale-out modes are "
                              "filter-specific (DESIGN.md §9)")
-        if mult_impl != "auto":
-            raise ValueError("infer routes multipliers per scalar product; "
-                             f"mult_impl must stay 'auto', got {mult_impl!r}")
         if nbits != cal.nbits:
             raise ValueError(f"model {target!r} is calibrated for "
                              f"nbits={cal.nbits}, got {nbits}")
@@ -136,8 +133,8 @@ class InferWorkload(Workload):
             return [logits[i] for i in range(len(requests))]
 
     def warm(self, executor, shape: tuple[int, int], target: str, *,
-             method: str, mult_impl: str, exec_mode: str, nbits: int,
-             traced_n: int, container: str = "uint8") -> None:
+             method: str, exec_mode: str, nbits: int, traced_n: int,
+             container: str = "uint8") -> None:
         cal = self.models.get(target)
         if cal is None:
             raise ValueError(f"unknown infer model {target!r}")

@@ -40,16 +40,14 @@ def gaussian_conv3x3_kernel(
     nbits: int = 8,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    mult_impl: str = "auto",
 ) -> Array:
     """img (H, W) int32 pixels in [0,255]; kernel (3,3) int32 scale-256.
 
     block_rows=None defaults through the autotune cache (DESIGN.md §8);
-    mult_impl='auto' takes the KCM fast path whenever `kernel` is a concrete
-    (non-traced) table -- callers must not jit over this wrapper with the
-    table as a traced argument, or the per-tap recursion is all that's left.
+    a concrete (non-traced) `kernel` is baked into the compiled kernel, a
+    traced one rides in SMEM (DESIGN.md §7).
     """
     return conv2d_pass(
         img[None], kernel, method=method, nbits=nbits, shift=8, post="clip",
-        block_rows=block_rows, interpret=interpret, mult_impl=mult_impl,
+        block_rows=block_rows, interpret=interpret,
     )[0]

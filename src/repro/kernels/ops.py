@@ -104,7 +104,6 @@ def gaussian_filter(
     nbits: int = 8,
     block_rows: int | None = None,
     interpret: bool | None = None,
-    mult_impl: str = "auto",
 ) -> Array:
     """3x3 Gaussian smoothing of a uint8 image with the selected multiplier.
 
@@ -114,14 +113,13 @@ def gaussian_filter(
     repro.filters (re-exported here; DESIGN.md §5).
 
     Deliberately NOT wrapped in an outer `jax.jit`: tracing would turn the
-    coefficient table into a Tracer and force `mult_impl='auto'` down the
-    per-tap recursion path (DESIGN.md §7). Eager taps keep the KCM
-    constant-coefficient fast path, and the conv pass jits internally;
-    a caller's own jit still composes (degrading to the recursion path).
+    coefficient table into a Tracer, which rides in SMEM instead of being
+    baked into the kernel (DESIGN.md §7). The conv pass jits internally;
+    a caller's own jit still composes (on the SMEM path).
     """
     out = gaussian_conv3x3_kernel(
         img.astype(jnp.int32), kernel, method=method, nbits=nbits,
-        block_rows=block_rows, interpret=interpret, mult_impl=mult_impl,
+        block_rows=block_rows, interpret=interpret,
     )
     return out.astype(jnp.uint8)
 

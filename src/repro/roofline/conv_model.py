@@ -8,14 +8,12 @@ roofline terms from the conv's own flop/byte accounting rather than a
 compiled module (`analysis.analyze_compiled` needs the lowered HLO, which
 is exactly the compile the pruner is trying to avoid):
 
-  * **flops** -- 2 (mult+add) per tap product over the padded output grid.
-    The direct dataflow pays kh*kw taps per pixel, the separable dataflows
-    kh+kw; the *fused* dataflow additionally recomputes the horizontal
-    pass on each band's 2*(kh//2) halo rows (the VMEM-band price,
-    DESIGN.md §7), which grows as bands shrink. A 'recurse' plan expands
-    every product into the digit-plane-flattened REFMLM recursion --
-    modeled as a conservative `RECURSE_FLOP_FACTOR` x one KCM gather
-    (measured ~90-100x, so the factor is a true lower bound).
+  * **flops** -- 2 (mult+add) per tap product over the padded output grid,
+    times `RECURSE_FLOP_FACTOR` for the multiplier's recursion that every
+    tap product runs. The direct dataflow pays kh*kw taps per pixel, the
+    separable dataflows kh+kw; the *fused* dataflow additionally
+    recomputes the horizontal pass on each band's 2*(kh//2) halo rows (the
+    VMEM-band price, DESIGN.md §7), which grows as bands shrink.
   * **hbm_bytes** -- int32 reads of the padded input including the halo
     *re*-reads every row band and column tile pays (2*(kh//2) rows per
     band, 2*(kw//2) columns per tile), plus the output write. 'two_pass'
@@ -49,22 +47,24 @@ import jax
 from repro.core.platform import default_interpret
 from repro.roofline.analysis import HW
 
-#: conservative flop expansion of one digit-plane-flattened REFMLM
-#: recursion product relative to one KCM table gather. Measured ~90-100x
-#: (BENCH_kernels.json kernel_bank_gaussian5_kcm_speedup); kept well under
-#: that so a 'recurse' bound never overshoots a real 'recurse' time.
+#: conservative flop expansion of one tap product through the REFMLM
+#: recursion relative to one multiply-add: the 8-bit kom4 recursion runs
+#: up to 16 2x2 base products of several integer ops each, so the factor
+#: stays a lower bound.
 RECURSE_FLOP_FACTOR = 32.0
 
 #: roofline constants by device key (`device_key`). 'cpu' models the
-#: interpret-mode executor: `peak_flops` is the *effective* per-element op
-#: throughput of interpreted Pallas (~1.4 ns/op, measured), far below any
-#: hardware peak, and the byte term is scaled to be nearly free --
-#: candidates rank by op counts plus launch floors. Compiled devices are
+#: interpret-mode executor: `peak_flops` is the *effective* rate of the
+#: modeled flops (recursion factor included) under interpreted Pallas,
+#: where the baked recursion is a few whole-array ops a tap (a (2, 64, 64)
+#: gaussian5 direct pass measures ~0.7 ms), and the byte term is scaled to
+#: be nearly free -- candidates rank by op counts plus launch floors. Compiled devices are
 #: keyed by `device_kind`: 'TPU v5 lite' is TPU v5e -- 197 TFLOP/s bf16,
 #: 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links (Google Cloud
 #: documentation, "TPU v5e"), the `analysis.HW` defaults.
 HW_PRESETS: dict[str, HW] = {
-    "cpu": HW(peak_flops=7e8, hbm_bw=2e12, ici_bw=50e9),
+    "cpu": HW(peak_flops=7e8 * RECURSE_FLOP_FACTOR, hbm_bw=2e12,
+              ici_bw=50e9),
     "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
 }
 
@@ -141,7 +141,6 @@ def _pass_terms(n_img: int, rows: int, w: int, kh: int, kw: int, br: int,
 
 def plan_cost(
     dataflow: str,
-    mult_impl: str,
     n: int,
     h: int,
     w: int,
@@ -201,11 +200,7 @@ def plan_cost(
     else:
         raise ValueError(f"unknown dataflow {dataflow!r}")
 
-    if mult_impl == "recurse":
-        flops *= RECURSE_FLOP_FACTOR
-    elif mult_impl != "kcm":
-        raise ValueError(f"unknown mult_impl {mult_impl!r}")
-
+    flops *= RECURSE_FLOP_FACTOR
     compute_s = flops / hw.peak_flops
     memory_s = bytes_ / hw.hbm_bw
     roofline_s = max(compute_s, memory_s)

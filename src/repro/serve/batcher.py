@@ -3,7 +3,7 @@
 Pure flush-policy state machine, deliberately free of threads and locks:
 the server drives it under its own condition variable, and tests drive it
 with a fake clock. Requests land in per-`bucket_key` FIFO queues -- one
-bucket per (H, W) × filter × method × mult_impl × exec × nbits × priority,
+bucket per (H, W) × filter × method × exec × nbits × priority,
 the set of fields one `apply_filter` call can serve -- and a bucket
 flushes as one `MicroBatch` when either trigger fires:
 

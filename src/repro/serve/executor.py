@@ -17,7 +17,7 @@ bytes no matter which coalesced batch, bucket, or exec mode served it
 Two steady-state amortisations:
 
   * **per-bucket plan resolution** -- the full `PlanConfig` winner
-    (dataflow, mult_impl, grid organization, DESIGN.md §11) for a
+    (dataflow and grid organization, DESIGN.md §11) for a
     (bucket, traced batch size) is resolved once via
     `repro.filters.resolve_filter_plan` and pinned explicitly on every
     dispatch, so the hot path never re-consults the tuning cache
@@ -223,14 +223,11 @@ class BatchExecutor:
         self._c_d2h.inc(d2h_bytes, member=self.name, container=container)
 
     # -------------------------------------------------- per-bucket plan memo
-    def _plan(self, filt: str, method: str, mult_impl: str, n: int, h: int,
-              w: int, *, nbits: int = 8, container: str = "uint8") -> dict:
+    def _plan(self, filt: str, method: str, n: int, h: int, w: int) -> dict:
         """Explicit plan fields for a local-exec (n, h, w) dispatch of
-        `filt` on `container` pixels at `nbits` (their widths decide
-        whether a KCM ROM exists) -- the full `PlanConfig` (dataflow,
-        resolved mult_impl, grid
-        organization, DESIGN.md §11) resolved once per (bucket, traced
-        batch size), pinned on every later call (the §10 hot-path
+        `filt` -- the full `PlanConfig` (dataflow and grid organization,
+        DESIGN.md §11) resolved once per (bucket, traced batch size),
+        pinned on every later call (the §10 hot-path
         memoisation: all-explicit fields take `resolve_plan`'s fast path).
         The memo follows the tuning cache's generation so an
         `invalidate_cache()` (an autotune store under a running server)
@@ -238,7 +235,7 @@ class BatchExecutor:
         server's lifetime, and is LRU-bounded at `plan_memo_max` entries
         so long-tail shape traffic cannot grow it without limit
         (DESIGN.md §13)."""
-        memo_key = (filt, method, mult_impl, n, h, w, nbits, container)
+        memo_key = (filt, method, n, h, w)
         with self._lock:
             gen = cache_generation()
             if gen != self._plans_gen:
@@ -251,12 +248,9 @@ class BatchExecutor:
                 return plan
             self._c_plan_misses.inc(member=self.name)
         cfg = resolve_filter_plan(filt, n, h, w, method=method,
-                                  mult_impl=mult_impl,
-                                  interpret=self.interpret, nbits=nbits,
-                                  container=container)
+                                  interpret=self.interpret)
         plan = {"separable": cfg.dataflow != "direct",
                 "fused": cfg.dataflow == "fused",
-                "mult_impl": cfg.mult_impl,
                 "block_rows": cfg.block_rows,
                 "block_cols": cfg.block_cols,
                 "batch_fold": cfg.batch_fold}
@@ -268,23 +262,20 @@ class BatchExecutor:
                 self._c_plan_evicts.inc(member=self.name)
         return plan
 
-    def _exec_kw(self, exec_mode: str, filt: str, method: str,
-                 mult_impl: str, n: int, h: int, w: int, *, nbits: int = 8,
-                 container: str = "uint8") -> dict:
-        """Complete per-dispatch kwargs, mult_impl included (the local plan
-        pins its resolved impl; scale-out modes forward the request's)."""
+    def _exec_kw(self, exec_mode: str, filt: str, method: str, n: int,
+                 h: int, w: int) -> dict:
+        """Complete per-dispatch kwargs (the local plan pins every plan
+        field; scale-out modes resolve their own)."""
         if exec_mode == "local":
-            return dict(self._plan(filt, method, mult_impl, n, h, w,
-                                   nbits=nbits, container=container))
+            return dict(self._plan(filt, method, n, h, w))
         if exec_mode == "sharded":
-            return {"exec": "sharded", "devices": self.devices,
-                    "mult_impl": mult_impl}
+            return {"exec": "sharded", "devices": self.devices}
         if exec_mode == "streamed":
             # tiles never exceed the bucket's image -- tiny buckets stream
             # as one tile instead of erroring on an oversized plan
             th, tw = min(self.tile[0], h), min(self.tile[1], w)
             return {"exec": "streamed", "tile": (th, tw),
-                    "tile_batch": self.tile_batch, "mult_impl": mult_impl}
+                    "tile_batch": self.tile_batch}
         raise ValueError(f"unknown exec mode {exec_mode!r}")
 
     def _plan_tag(self, mode: str, r0: FilterRequest, traced_n: int) -> str:
@@ -294,12 +285,10 @@ class BatchExecutor:
         tracing is on; the memo makes it a plan-memo hit."""
         if mode == "local" and r0.workload == "filter":
             h, w = r0.img.shape
-            p = self._plan(r0.filt, r0.method, r0.mult_impl, traced_n, h, w,
-                           nbits=r0.nbits, container=r0.container)
+            p = self._plan(r0.filt, r0.method, traced_n, h, w)
             df = ("fused" if p["fused"]
                   else "two_pass" if p["separable"] else "direct")
-            tag = (f"{df}/{p['mult_impl']}"
-                   f"/br{p['block_rows']}xbc{p['block_cols']}")
+            tag = f"{df}/br{p['block_rows']}xbc{p['block_cols']}"
             return tag + ("/fold" if p["batch_fold"] else "")
         return f"{mode}/{r0.workload}"
 
@@ -470,8 +459,7 @@ class BatchExecutor:
 
     # ---------------------------------------------------------------- warmup
     def warm(self, shape: tuple[int, int], filt: str, *,
-             method: str = "refmlm", mult_impl: str = "auto",
-             exec_mode: str = "local", nbits: int = 8, n: int = 1,
+             method: str = "refmlm", exec_mode: str = "local", nbits: int = 8, n: int = 1,
              priority: str = "normal", workload: str = "filter") -> str:
         """Pre-compile one (bucket, batch size) point with a zero dummy
         batch; returns the serve_key it warmed. `priority` only names the
@@ -484,12 +472,11 @@ class BatchExecutor:
         h, w = shape
         traced_n = next_pow2(n) if self.pad_pow2 else n
         container = warm_container(nbits, workload, exec_mode)
-        key = bucket_key(filt, method, mult_impl, exec_mode, nbits, h, w,
+        key = bucket_key(filt, method, exec_mode, nbits, h, w,
                          priority, workload, container)
         with self._placed(exec_mode):
             self.workloads[workload].warm(
-                self, (h, w), filt, method=method, mult_impl=mult_impl,
-                exec_mode=exec_mode, nbits=nbits, traced_n=traced_n,
+                self, (h, w), filt, method=method, exec_mode=exec_mode, nbits=nbits, traced_n=traced_n,
                 container=container)
         skey = serve_key(key, traced_n)
         with self._lock:

@@ -201,10 +201,10 @@ class ExecutorPool:
     # ----------------------------------------------------------------- health
     @staticmethod
     def _native_mode(key: str) -> str:
-        """The exec mode a bucket was *submitted* under -- the 4th segment
+        """The exec mode a bucket was *submitted* under -- the 3rd segment
         of its `bucket_key` (request.py's format)."""
         parts = key.split("/")
-        return parts[3] if len(parts) > 3 else ""
+        return parts[2] if len(parts) > 2 else ""
 
     def _on_dispatch(self, name: str, key: str, mode: str, ok: bool) -> None:
         """The §13 health feed: one call per member dispatch, with the
@@ -270,19 +270,17 @@ class ExecutorPool:
 
     # --------------------------------------- BatchExecutor-compatible surface
     def warm(self, shape: tuple[int, int], filt: str, *,
-             method: str = "refmlm", mult_impl: str = "auto",
-             exec_mode: str = "local", nbits: int = 8, n: int = 1,
+             method: str = "refmlm", exec_mode: str = "local", nbits: int = 8, n: int = 1,
              priority: str = "normal", workload: str = "filter") -> str:
         """Warm one serve point on the member that will actually serve it
         (same signature as `BatchExecutor.warm`, so `warmup.sweep` and
         `ImageFilterServer.warmup()` drive pools unchanged)."""
         h, w = shape
-        key = bucket_key(filt, method, mult_impl, exec_mode, nbits, h, w,
+        key = bucket_key(filt, method, exec_mode, nbits, h, w,
                          priority, workload,
                          warm_container(nbits, workload, exec_mode))
         return self.route(key).executor.warm(
-            (h, w), filt, method=method, mult_impl=mult_impl,
-            exec_mode=exec_mode, nbits=nbits, n=n, priority=priority,
+            (h, w), filt, method=method, exec_mode=exec_mode, nbits=nbits, n=n, priority=priority,
             workload=workload)
 
     @property

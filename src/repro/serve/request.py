@@ -1,8 +1,8 @@
 """Request/response vocabulary of the serving layer (DESIGN.md §10/§13).
 
 A `FilterRequest` is one client image plus its full datapath routing --
-the bank filter, multiplier method, tap-product implementation, pixel
-width, execution mode and pixel container (uint8, or uint16 for pixels
+the bank filter, multiplier method, pixel width, execution mode and
+pixel container (uint8, or uint16 for pixels
 wider than 8 bits, DESIGN.md §5) -- and, since §13, its *service level*: a
 priority class, a tenant, and an optional latency SLO. The micro-batcher
 coalesces concurrent requests whose `bucket_key` agrees -- same (H, W),
@@ -13,8 +13,8 @@ executable). Results come back through a `FilterFuture`.
 
 `serve_key` extends a bucket key with the coalesced batch size: it is the
 warm-start compile-cache key, the serving analogue of
-`repro.tuning.config_key` (shape bucket × filter × mult_impl × exec, plus
-the padded N the executable actually traces with).
+`repro.tuning.config_key` (shape bucket × filter × exec, plus the padded
+N the executable actually traces with).
 
 Service-level fields (DESIGN.md §13):
 
@@ -66,20 +66,17 @@ class DeadlineExceeded(RuntimeError):
     shed at flush time without being dispatched (DESIGN.md §12)."""
 
 
-def bucket_key(filt: str, method: str, mult_impl: str, exec_mode: str,
-               nbits: int, h: int, w: int, priority: str = "normal",
+def bucket_key(filt: str, method: str, exec_mode: str, nbits: int, h: int,
+               w: int, priority: str = "normal",
                workload: str = "filter", container: str = "uint8") -> str:
     """Coalescing key: requests sharing it may ride one micro-batch.
     Priority is part of the key (DESIGN.md §13): classes never coalesce,
     so shedding or deprioritising 'low' can never touch a 'high' batch.
     A pixel container other than uint8 (DESIGN.md §5), then a non-default
-    workload class (DESIGN.md §14), are appended as suffixes -- uint8
-    filter keys keep their historical spelling, which the pool's
-    rendezvous routing hashes, and the exec mode stays the 4th segment
-    (the pool's `_native_mode` contract) -- so distinct containers and
-    workloads can never share a batch."""
-    key = (f"{filt}/{method}/{mult_impl}/{exec_mode}/b{nbits}/{h}x{w}"
-           f"/{priority}")
+    workload class (DESIGN.md §14), are appended as suffixes -- the exec
+    mode stays the 3rd segment (the pool's `_native_mode` contract) -- so
+    distinct containers and workloads can never share a batch."""
+    key = f"{filt}/{method}/{exec_mode}/b{nbits}/{h}x{w}/{priority}"
     if container != "uint8":
         key = f"{key}/{container}"
     return key if workload == "filter" else f"{key}/{workload}"
@@ -145,7 +142,6 @@ class FilterRequest:
     img: np.ndarray              # (H, W) grayscale, any integer dtype
     filt: str
     method: str
-    mult_impl: str
     exec: str
     nbits: int
     future: FilterFuture
@@ -162,8 +158,7 @@ class FilterRequest:
     @property
     def key(self) -> str:
         h, w = self.img.shape
-        return bucket_key(self.filt, self.method, self.mult_impl, self.exec,
-                          self.nbits, h, w, self.priority, self.workload,
+        return bucket_key(self.filt, self.method, self.exec, self.nbits, h, w, self.priority, self.workload,
                           self.container)
 
     @property

@@ -235,7 +235,7 @@ class ImageFilterServer:
 
     # ------------------------------------------------------------ client API
     def submit(self, img, filt: str, *, method: str = "refmlm",
-               mult_impl: str = "auto", nbits: int = 8,
+               nbits: int = 8,
                exec: str | None = None,
                deadline_ms: float | None = None,
                timeout: float | None = None,
@@ -251,7 +251,7 @@ class ImageFilterServer:
         request fails fast instead of poisoning a coalesced batch: `exec`
         must be a §9 mode, `priority` a §13 class, and the payload must
         pass the workload's own validation (for 'filter': a known filter
-        name, a known `mult_impl`, one 2-D or (H, W, 1) frame). Blocks
+        name, one 2-D or (H, W, 1) frame). Blocks
         while the server (or `tenant`'s quota) is out of weighted
         in-flight slots (up to `timeout`, then `ServerOverloaded` /
         `TenantOverQuota`).
@@ -278,8 +278,7 @@ class ImageFilterServer:
             raise ValueError(f"unknown workload {workload!r}; registered: "
                              f"{tuple(self._workloads)}")
         arr = wl.validate(img, target=filt, method=method,
-                          mult_impl=mult_impl, exec_mode=exec_mode,
-                          nbits=int(nbits))
+                          exec_mode=exec_mode, nbits=int(nbits))
         if self._closing:
             raise ServerClosed("server is closed")
         if self.config.fail_fast_degraded and not self._is_healthy():
@@ -309,7 +308,7 @@ class ImageFilterServer:
             deadline = None if deadline_ms is None else now + deadline_ms / 1e3
             slo = None if slo_ms is None else now + slo_ms / 1e3
             req = FilterRequest(img=arr, filt=filt, method=method,
-                                mult_impl=mult_impl, exec=exec_mode,
+                                exec=exec_mode,
                                 nbits=int(nbits), future=future,
                                 submitted=now, seq=self._seq,
                                 deadline=deadline, priority=priority,
@@ -330,7 +329,7 @@ class ImageFilterServer:
         return future
 
     def warmup(self, shapes, filters=("gaussian3",), *, methods=("refmlm",),
-               mult_impls=("auto",), execs=None, batches=(1,),
+               execs=None, batches=(1,),
                nbits: int = 8, priorities=("normal",),
                workload: str = "filter") -> list[str]:
         """Pre-compile the cross product of serve points; returns the warmed
@@ -339,8 +338,7 @@ class ImageFilterServer:
         workload's targets (infer model names for 'infer')."""
         from repro.serve.warmup import sweep
         execs = (self.config.exec,) if execs is None else tuple(execs)
-        return sweep(self._executor, shapes, filters, methods, mult_impls,
-                     execs, batches, nbits=nbits, priorities=priorities,
+        return sweep(self._executor, shapes, filters, methods, execs, batches, nbits=nbits, priorities=priorities,
                      workload=workload)
 
     def _is_healthy(self) -> bool:

@@ -2,14 +2,13 @@
 
     PYTHONPATH=src python -m repro.serve.warmup \\
         --shapes 128x128,256x256 --filters gaussian3,gaussian5 \\
-        --methods refmlm --mult-impls auto --execs local --batches 1,8
+        --methods refmlm --execs local --batches 1,8
 
 Each point of the cross product is one warm `serve_key` -- shape bucket ×
-filter × mult_impl × exec × traced batch size, the same keying as the
-tuning cache (`repro.tuning.config_key`) one level up. Warming runs a
-zero dummy batch through the exact `apply_filter_batch` dispatch the
-server will issue, so jax's jit cache (and the KCM ROM/device-table
-caches under it) are populated before the first real request: first-hit
+filter × exec × traced batch size, the same keying as the tuning cache
+(`repro.tuning.config_key`) one level up. Warming runs a zero dummy
+batch through the exact `apply_filter_batch` dispatch the server will
+issue, so jax's jit cache is populated before the first real request: first-hit
 latency collapses to steady-state latency, amortised at deploy time
 instead of on a user.
 
@@ -39,8 +38,8 @@ def parse_shapes(text: str) -> list[tuple[int, int]]:
     return shapes
 
 
-def sweep(executor: BatchExecutor, shapes, filters, methods, mult_impls,
-          execs, batches, *, nbits: int = 8, priorities=("normal",),
+def sweep(executor: BatchExecutor, shapes, filters, methods, execs,
+          batches, *, nbits: int = 8, priorities=("normal",),
           workload: str = "filter", verbose: bool = False) -> list[str]:
     """Warm the cross product of serve points on `executor`; returns the
     warmed keys. The one sweep definition shared by this CLI and
@@ -52,12 +51,11 @@ def sweep(executor: BatchExecutor, shapes, filters, methods, mult_impls,
     follows `nbits` (`repro.serve.executor.warm_container`): a width above
     8 bits warms the uint16 container of wider pixels (DESIGN.md §5)."""
     keys = []
-    for (h, w), filt, method, impl, em, n, pri in itertools.product(
-            shapes, filters, methods, mult_impls, execs, batches,
-            priorities):
+    for (h, w), filt, method, em, n, pri in itertools.product(
+            shapes, filters, methods, execs, batches, priorities):
         t0 = time.perf_counter()
         key = executor.warm((int(h), int(w)), filt, method=method,
-                            mult_impl=impl, exec_mode=em, nbits=nbits,
+                            exec_mode=em, nbits=nbits,
                             n=int(n), priority=pri, workload=workload)
         keys.append(key)
         if verbose:
@@ -66,11 +64,11 @@ def sweep(executor: BatchExecutor, shapes, filters, methods, mult_impls,
     return keys
 
 
-def warm(shapes, filters, methods, mult_impls, execs, batches, *,
+def warm(shapes, filters, methods, execs, batches, *,
          interpret: bool | None = None, verbose: bool = True) -> list[str]:
     """Run the warmup sweep on a fresh executor; returns the warmed keys."""
     return sweep(BatchExecutor(interpret=interpret), shapes, filters,
-                 methods, mult_impls, execs, batches, verbose=verbose)
+                 methods, execs, batches, verbose=verbose)
 
 
 def main(argv=None) -> int:
@@ -79,7 +77,6 @@ def main(argv=None) -> int:
                     help="comma-separated HxW shape buckets")
     ap.add_argument("--filters", default=",".join(FILTER_NAMES))
     ap.add_argument("--methods", default="refmlm")
-    ap.add_argument("--mult-impls", default="auto")
     ap.add_argument("--execs", default="local",
                     help="comma-separated exec modes (DESIGN.md §9)")
     ap.add_argument("--batches", default="1,8",
@@ -88,7 +85,7 @@ def main(argv=None) -> int:
     enable_compile_cache()
     keys = warm(parse_shapes(args.shapes),
                 args.filters.split(","), args.methods.split(","),
-                args.mult_impls.split(","), args.execs.split(","),
+                args.execs.split(","),
                 [int(b) for b in args.batches.split(",")])
     print(f"warmed {len(keys)} serve keys")
     return 0

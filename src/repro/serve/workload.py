@@ -28,7 +28,6 @@ from __future__ import annotations
 import numpy as np
 
 from repro.filters.bank import get_filter
-from repro.filters.conv import MULT_IMPLS
 from repro.filters.pipeline import (
     CONTAINERS,
     apply_filter_batch,
@@ -47,8 +46,8 @@ class Workload:
 
     name = "base"
 
-    def validate(self, payload, *, target: str, method: str, mult_impl: str,
-                 exec_mode: str, nbits: int) -> np.ndarray:
+    def validate(self, payload, *, target: str, method: str, exec_mode: str,
+                 nbits: int) -> np.ndarray:
         """Client-thread validation: raise on a bad request, return the
         canonical 2-D payload array the request will carry."""
         raise NotImplementedError
@@ -70,8 +69,8 @@ class Workload:
         raise NotImplementedError
 
     def warm(self, executor, shape: tuple[int, int], target: str, *,
-             method: str, mult_impl: str, exec_mode: str, nbits: int,
-             traced_n: int, container: str = "uint8") -> None:
+             method: str, exec_mode: str, nbits: int, traced_n: int,
+             container: str = "uint8") -> None:
         """Compile one (bucket, traced batch size) point with dummy data."""
         raise NotImplementedError
 
@@ -93,11 +92,8 @@ class FilterWorkload(Workload):
 
     name = "filter"
 
-    def validate(self, payload, *, target: str, method: str, mult_impl: str,
-                 exec_mode: str, nbits: int) -> np.ndarray:
-        if mult_impl not in MULT_IMPLS:
-            raise ValueError(f"mult_impl must be one of {MULT_IMPLS}, got "
-                             f"{mult_impl!r}")
+    def validate(self, payload, *, target: str, method: str, exec_mode: str,
+                 nbits: int) -> np.ndarray:
         get_filter(target)                   # unknown names fail fast
         arr = np.asarray(payload)
         if arr.ndim == 3 and arr.shape[-1] == 1:
@@ -122,9 +118,7 @@ class FilterWorkload(Workload):
                 traced_n: int, exec_mode: str) -> list[np.ndarray]:
         r0 = requests[0]
         h, w = r0.img.shape
-        kw = executor._exec_kw(exec_mode, r0.filt, r0.method, r0.mult_impl,
-                               traced_n, h, w, nbits=r0.nbits,
-                               container=r0.container)
+        kw = executor._exec_kw(exec_mode, r0.filt, r0.method, traced_n, h, w)
         imgs = [r.img for r in requests]
         # the batch as uploaded, pad images included, in its wire dtype
         executor.count_transfer(
@@ -140,12 +134,10 @@ class FilterWorkload(Workload):
         return outs
 
     def warm(self, executor, shape: tuple[int, int], target: str, *,
-             method: str, mult_impl: str, exec_mode: str, nbits: int,
-             traced_n: int, container: str = "uint8") -> None:
+             method: str, exec_mode: str, nbits: int, traced_n: int,
+             container: str = "uint8") -> None:
         h, w = shape
-        kw = executor._exec_kw(exec_mode, target, method, mult_impl,
-                               traced_n, h, w, nbits=nbits,
-                               container=container)
+        kw = executor._exec_kw(exec_mode, target, method, traced_n, h, w)
         zeros = np.zeros((h, w), CONTAINERS[container])
         apply_filter_batch([zeros] * traced_n, target, method=method,
                            nbits=nbits, interpret=executor.interpret, **kw)
@@ -157,12 +149,10 @@ class FilterWorkload(Workload):
         from repro.roofline.conv_model import plan_cost
         h, w = req.img.shape
         spec = get_filter(req.filt)
-        plan = resolve_filter_plan(spec, n, h, w, method=req.method,
-                                   mult_impl=req.mult_impl, nbits=req.nbits,
-                                   container=req.container)
+        plan = resolve_filter_plan(spec, n, h, w, method=req.method)
         kh, kw = ((len(spec.sep_col), len(spec.sep_row))
                   if plan.dataflow == "fused" else spec.ksize)
-        cost = plan_cost(plan.dataflow, plan.mult_impl, n, h, w, kh, kw,
+        cost = plan_cost(plan.dataflow, n, h, w, kh, kw,
                          block_rows=plan.block_rows,
                          block_cols=plan.block_cols,
                          batch_fold=bool(plan.batch_fold),

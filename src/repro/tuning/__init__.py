@@ -2,11 +2,10 @@
 
 Layers:
   blocks.py   -- `BlockConfig` + the cache-miss heuristic (`default_blocks`);
-  plans.py    -- `PlanConfig` (dataflow x mult_impl x blocks) + the plan
-                 lookup path (`resolve_plan`: explicit > cached > pre-plan
-                 defaults);
-  cache.py    -- the committable per-backend JSON cache (schema v2: blocks
-                 + plans sections, v1 migration) and the block lookup path
+  plans.py    -- `PlanConfig` (dataflow x blocks) + the plan lookup path
+                 (`resolve_plan`: explicit > cached > pre-plan defaults);
+  cache.py    -- the committable per-backend JSON cache (blocks + plans
+                 sections) and the block lookup path
                  (`resolve_blocks`: explicit > cached > heuristic);
   autotune.py -- the sweeping tuner that populates both sections, with
                  roofline-pruned plan sweeps

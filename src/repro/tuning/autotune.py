@@ -8,11 +8,11 @@ sweeps with roofline pruning (DESIGN.md).
 Two tuned units share the per-backend cache file:
 
   * **blocks** (§8) -- candidate (block_rows, block_cols, batch_fold) grid
-    organizations per (image shape, dataflow, mult_impl), exhaustively
+    organizations per (image shape, dataflow), exhaustively
     timed; the pass-level fallback every conv call resolves through.
-  * **plans** (§11) -- full `PlanConfig`s (dataflow x mult_impl x blocks)
-    per (filter, shape), the pipeline-level choice `apply_filter` resolves
-    on default arguments. The plan space is ~6x the block space, so the
+  * **plans** (§11) -- full `PlanConfig`s (dataflow x blocks) per
+    (filter, shape), the pipeline-level choice `apply_filter` resolves on
+    default arguments. The plan space is ~3x the block space, so the
     sweep closes the loop with `repro.roofline.conv_model`: candidates are
     enumerated deterministically, sorted by their roofline lower bound,
     and -- once an incumbent is measured -- any candidate whose
@@ -59,18 +59,18 @@ from repro.tuning.cache import (
     config_key,
     store_cache,
 )
-from repro.tuning.plans import PLAN_MULT_IMPLS, PlanConfig, plan_key
+from repro.tuning.plans import PlanConfig, plan_key
 
-#: (kind, n, h, w, kh, kw, mult_impl) rows of the default block sweep.
+#: (kind, n, h, w, kh, kw) rows of the default block sweep.
 DEFAULT_SWEEP: tuple[tuple, ...] = tuple(
-    (kind, n, h, w, k, k, "kcm")
+    (kind, n, h, w, k, k)
     for kind in ("direct", "fused")
     for (n, h, w) in ((1, 128, 128), (4, 128, 128), (8, 128, 128),
                       (2, 64, 64), (8, 64, 64))
     for k in (3, 5)
 )
 QUICK_SWEEP: tuple[tuple, ...] = tuple(
-    (kind, n, 64, 64, 3, 3, "kcm")
+    (kind, n, 64, 64, 3, 3)
     for kind in ("direct", "fused") for n in (1, 8)
 )
 #: shard-local band / tile-local batch shapes of distributed execution
@@ -78,7 +78,7 @@ QUICK_SWEEP: tuple[tuple, ...] = tuple(
 #: row-sharded single image -> (1, H/8 + 2*ph, W) bands; the streamed
 #: default (256, 256) tile at tile_batch=8 -> (8, 260, 260) for a 5x5.
 DIST_SWEEP: tuple[tuple, ...] = tuple(
-    (kind, n, h, w, k, k, "kcm")
+    (kind, n, h, w, k, k)
     for kind in ("direct", "fused")
     for (n, h, w, k) in ((4, 128, 128, 5), (1, 132, 128, 5), (1, 20, 128, 5),
                          (8, 260, 260, 5), (8, 132, 132, 3))
@@ -107,8 +107,8 @@ PLAN_QUICK: tuple[tuple[str, int, int, int], ...] = (
 #: 2x is deliberately wide slack for the model's halo/fold/launch-floor
 #: approximations: the dataflows measure within ~1.6x of each other on the
 #: small shapes (where the winner even flips to direct), so every plausible
-#: winner is always measured, while the recurse branch (32x bound) and the
-#: pathological grid shapes still prune wholesale.
+#: winner is always measured, while the pathological grid shapes still
+#: prune wholesale.
 PRUNE_MARGIN = 2.0
 
 
@@ -162,7 +162,7 @@ def _time_us(fn, *args, iters: int = 3, warmup: int = 1) -> float:
 
 
 def measure(kind: str, cfg: BlockConfig, n: int, h: int, w: int, kh: int,
-            kw: int, mult_impl: str, *, iters: int = 3) -> float:
+            kw: int, *, iters: int = 3) -> float:
     """Median us/call of one dataflow under one grid organization."""
     # Lazy import: repro.filters.conv imports this package for its defaults.
     from repro.filters.conv import conv2d_pass, fused_separable_pass
@@ -171,8 +171,7 @@ def measure(kind: str, cfg: BlockConfig, n: int, h: int, w: int, kh: int,
     rng = np.random.default_rng(0)
     imgs = jnp.asarray(rng.integers(0, 256, (n, h, w)), jnp.int32)
     taps1d = np.array([1, 4, 6, 4, 1] if kh == 5 else [4, 8, 4], np.int64)
-    kw_common = dict(method="refmlm", mult_impl=mult_impl,
-                     block_rows=cfg.block_rows,
+    kw_common = dict(method="refmlm", block_rows=cfg.block_rows,
                      block_cols=w if cfg.block_cols is None else cfg.block_cols,
                      batch_fold=cfg.batch_fold)
     if kind == "fused":
@@ -191,19 +190,19 @@ def tune(sweep: Iterable[tuple] = DEFAULT_SWEEP, *, iters: int = 3,
     """Sweep every (shape, dataflow) block row and return the winning
     configs as a `store_cache`-ready blocks mapping."""
     configs: dict[str, dict] = {}
-    for kind, n, h, w, kh, kw, impl in sweep:
+    for kind, n, h, w, kh, kw in sweep:
         best: tuple[float, BlockConfig] | None = None
         for cfg in candidate_blocks(kind, n, h, w, kh, kw):
-            us = measure(kind, cfg, n, h, w, kh, kw, impl, iters=iters)
+            us = measure(kind, cfg, n, h, w, kh, kw, iters=iters)
             if verbose:
-                print(f"# tune {kind} n{n}x{h}x{w} k{kh}x{kw} {impl} "
+                print(f"# tune {kind} n{n}x{h}x{w} k{kh}x{kw} "
                       f"br={cfg.block_rows} bc={cfg.block_cols} "
                       f"fold={cfg.batch_fold}: {us:.1f}us")
             if best is None or us < best[0]:
                 best = (us, cfg)
         assert best is not None
         us, cfg = best
-        key = config_key(kind, n, h, w, kh, kw, impl)
+        key = config_key(kind, n, h, w, kh, kw)
         configs[key] = {**cfg.as_dict(), "us_per_call": round(us, 1)}
         # A fold winner that loses to the heuristic default would mean the
         # heuristic is strictly better -- still record the measurement.
@@ -219,8 +218,8 @@ def tune(sweep: Iterable[tuple] = DEFAULT_SWEEP, *, iters: int = 3,
 def plan_candidates(name: str, n: int, h: int, w: int) -> list[PlanConfig]:
     """Deterministic, fully-concrete plan candidates for one (filter, shape).
 
-    Every admissible dataflow of the spec x both tap-product
-    implementations x the §8 block candidates of the matching pass kind.
+    Every admissible dataflow of the spec x the §8 block candidates of the
+    matching pass kind.
     All fields are concrete (full width spelled `block_cols=w`): tuned
     entries never defer, so a cache hit resolves without any further
     pass-level lookup.
@@ -234,12 +233,11 @@ def plan_candidates(name: str, n: int, h: int, w: int) -> list[PlanConfig]:
     out: list[PlanConfig] = []
     for df in dataflows:
         kind = "fused" if df == "fused" else "direct"
-        for impl in PLAN_MULT_IMPLS:
-            for cfg in candidate_blocks(kind, n, h, w, kh, kw):
-                out.append(PlanConfig(
-                    df, impl, cfg.block_rows,
-                    w if cfg.block_cols is None else cfg.block_cols,
-                    cfg.batch_fold))
+        for cfg in candidate_blocks(kind, n, h, w, kh, kw):
+            out.append(PlanConfig(
+                df, cfg.block_rows,
+                w if cfg.block_cols is None else cfg.block_cols,
+                cfg.batch_fold))
     return out
 
 
@@ -249,7 +247,7 @@ def plan_bound_us(plan: PlanConfig, name: str, n: int, h: int, w: int,
     from repro.filters.bank import get_filter
 
     kh, kw = get_filter(name).ksize
-    cost = plan_cost(plan.dataflow, plan.mult_impl, n, h, w, kh, kw,
+    cost = plan_cost(plan.dataflow, n, h, w, kh, kw,
                      block_rows=plan.block_rows, block_cols=plan.block_cols,
                      batch_fold=bool(plan.batch_fold),
                      backend=backend)
@@ -269,8 +267,8 @@ def measure_plan(name: str, plan: PlanConfig, n: int, h: int, w: int, *,
     import jax.numpy as jnp
     rng = np.random.default_rng(0)
     imgs = jnp.asarray(rng.integers(0, 256, (n, h, w)), jnp.int32)
-    kw_plan = dict(method="refmlm", mult_impl=plan.mult_impl,
-                   block_rows=plan.block_rows, block_cols=plan.block_cols,
+    kw_plan = dict(method="refmlm", block_rows=plan.block_rows,
+                   block_cols=plan.block_cols,
                    batch_fold=bool(plan.batch_fold))
     if plan.dataflow == "direct":
         fn = lambda x: apply_filter(x, name, separable=False, **kw_plan)
@@ -306,7 +304,7 @@ def sweep_plan(
     without timing when `bound * scale > incumbent * margin`. Because
     candidates arrive bound-ascending, pruning is monotone -- once one
     candidate prunes, the rest of the tail prunes too, which is what makes
-    the 6x-bigger plan space sweepable.
+    the 3x-bigger plan space sweepable.
 
     `measure_fn` injects the timer (tests replay recorded timings through
     the same loop to prove pruning never discards the exhaustive winner);
@@ -334,8 +332,8 @@ def sweep_plan(
         if bound > 0:
             scale = us / bound if scale is None else min(scale, us / bound)
         if verbose:
-            print(f"# plan {name} n{n}x{h}x{w} {plan.dataflow}/"
-                  f"{plan.mult_impl} br={plan.block_rows} "
+            print(f"# plan {name} n{n}x{h}x{w} {plan.dataflow} "
+                  f"br={plan.block_rows} "
                   f"bc={plan.block_cols} fold={plan.batch_fold}: "
                   f"{us:.1f}us (bound {bound:.1f}us)")
         if best is None or us < best[0]:
@@ -346,8 +344,8 @@ def sweep_plan(
              "generated": cache_timestamp(), "candidates": len(cands),
              "swept": swept, "pruned": pruned}
     if verbose:
-        print(f"# plan {plan_key(name, n, h, w)}: winner {plan.dataflow}/"
-              f"{plan.mult_impl} br={plan.block_rows} bc={plan.block_cols} "
+        print(f"# plan {plan_key(name, n, h, w)}: winner {plan.dataflow} "
+              f"br={plan.block_rows} bc={plan.block_cols} "
               f"fold={plan.batch_fold} ({us:.1f}us; swept {swept}/"
               f"{len(cands)}, pruned {pruned})")
     return entry, records

@@ -7,37 +7,34 @@ next to this module (override the directory with `REPRO_TUNE_CACHE`):
     {
       "meta": {"backend": "cpu", "generated": "<ISO-8601>", "version": 2},
       "blocks": {
-        "<kind>/<mult_impl>/n4x128x128/k5x5": {
+        "<kind>/n4x128x128/k5x5": {
           "block_rows": 1040, "block_cols": null, "batch_fold": true,
           "us_per_call": 1234.5
         }, ...
       },
       "plans": {
         "gaussian5/n4x128x128": {
-          "dataflow": "two_pass", "mult_impl": "kcm", "block_rows": 520,
-          "block_cols": 128, "batch_fold": true, "us_per_call": 1234.5,
-          "generated": "<ISO-8601>", "candidates": 36, "swept": 14,
-          "pruned": 22
+          "dataflow": "two_pass", "block_rows": 520, "block_cols": 128,
+          "batch_fold": true, "us_per_call": 1234.5,
+          "generated": "<ISO-8601>", "candidates": 12, "swept": 7,
+          "pruned": 5
         }, ...
       }
     }
 
-Schema v2 (DESIGN.md §11) split the flat v1 `configs` mapping into two
-sections. `blocks` keeps the v1 per-pass grid winners under
-`config_key(kind, n, h, w, kh, kw, mult_impl)` -- the pass-level dataflow
+`blocks` holds the per-pass grid winners under
+`config_key(kind, n, h, w, kh, kw)` -- the pass-level dataflow
 ('direct' | 'fused'; the two-pass separable stages are 'direct' entries
-distinguished by their 1-D tap extents), the resolved tap-product
-implementation ('kcm' | 'recurse'), the batch/image shape and the filter
-extent. `plans` holds the filter-level execution plans under
-`repro.tuning.plans.plan_key(filter, n, h, w)`, each entry a full
-`PlanConfig` plus its measured time, its own BENCH_TIMESTAMP-honoring
-`generated` stamp and the roofline-pruning audit counters
-(candidates/swept/pruned) of the sweep that produced it. Legacy v1 files
-(`configs` at top level) migrate on load: the old mapping is read as the
-`blocks` section and the `plans` section starts empty; the next
-`store_cache` writes v2. The multiplier *method* is deliberately in
-neither key family: the KCM gather's cost is method-independent and the
-tuner sweeps refmlm -- plans and blocks are throughput-only artifacts.
+distinguished by their 1-D tap extents), the batch/image shape and the
+filter extent. `plans` holds the filter-level execution plans
+(DESIGN.md §11) under `repro.tuning.plans.plan_key(filter, n, h, w)`,
+each entry a full `PlanConfig` plus its measured time, its own
+BENCH_TIMESTAMP-honoring `generated` stamp and the roofline-pruning audit
+counters (candidates/swept/pruned) of the sweep that produced it. No
+backend has a committed file: without one every pass takes the
+`default_blocks` heuristic. The multiplier *method* is deliberately in
+neither key family: the tuner sweeps refmlm, and plans and blocks are
+throughput-only artifacts.
 
 The (n, h, w) in the key is ALWAYS the shape the conv pass itself traces
 with. Under distributed execution (`repro.distribute`, DESIGN.md §9) that
@@ -85,9 +82,8 @@ def cache_path(backend: str | None = None) -> pathlib.Path:
     return cache_dir() / f"blocks_{backend or backend_key()}.json"
 
 
-def config_key(kind: str, n: int, h: int, w: int, kh: int, kw: int,
-               mult_impl: str) -> str:
-    return f"{kind}/{mult_impl}/n{n}x{h}x{w}/k{kh}x{kw}"
+def config_key(kind: str, n: int, h: int, w: int, kh: int, kw: int) -> str:
+    return f"{kind}/n{n}x{h}x{w}/k{kh}x{kw}"
 
 
 def cache_timestamp() -> str:
@@ -98,8 +94,8 @@ def cache_timestamp() -> str:
 
 @lru_cache(maxsize=None)
 def _load(path: str) -> dict:
-    """-> {"blocks": {...}, "plans": {...}}, migrating legacy v1 files
-    (top-level `configs` = the old flat block mapping, no plans)."""
+    """-> {"blocks": {...}, "plans": {...}}; both empty when the file is
+    missing or unreadable."""
     empty = {"blocks": {}, "plans": {}}
     p = pathlib.Path(path)
     if not p.exists():
@@ -110,21 +106,18 @@ def _load(path: str) -> dict:
         return empty
     if not isinstance(data, dict):
         return empty
-    if "configs" in data:                       # v1: flat block mapping
-        return {"blocks": data.get("configs") or {}, "plans": {}}
     return {"blocks": data.get("blocks") or {},
             "plans": data.get("plans") or {}}
 
 
 def load_cache(backend: str | None = None) -> dict:
     """Block section: key -> {block_rows, block_cols, batch_fold,
-    us_per_call} (v1 files migrate transparently)."""
+    us_per_call}."""
     return _load(str(cache_path(backend)))["blocks"]
 
 
 def load_plans(backend: str | None = None) -> dict:
-    """Plan section: plan_key -> full PlanConfig entry (DESIGN.md §11);
-    empty for legacy v1 files."""
+    """Plan section: plan_key -> full PlanConfig entry (DESIGN.md §11)."""
     return _load(str(cache_path(backend)))["plans"]
 
 
@@ -151,8 +144,8 @@ def store_cache(configs: dict, plans: dict | None = None,
     """Write the committable per-backend cache file; returns its path.
 
     `configs` is the block section; `plans=None` preserves the file's
-    existing plan section (so a blocks-only store -- the pre-v2 call
-    signature -- never wipes tuned plans), `plans={...}` replaces it.
+    existing plan section (so a blocks-only store never wipes tuned
+    plans), `plans={...}` replaces it.
     Keys in both sections are sorted and `generated` honors
     BENCH_TIMESTAMP, so regeneration is byte-deterministic up to the
     measured winners themselves.
@@ -180,7 +173,6 @@ def resolve_blocks(
     w: int,
     kh: int,
     kw: int,
-    mult_impl: str,
     *,
     block_rows: int | None = None,
     block_cols: int | None = None,
@@ -191,7 +183,7 @@ def resolve_blocks(
 
     Any explicitly supplied field wins unconditionally. Unset fields come
     from the backend cache only when its entry for this exact
-    (kind, shape, mult_impl) AGREES with every explicit field -- a cached
+    (kind, shape) AGREES with every explicit field -- a cached
     winner tuned for (say) a folded grid must not donate its fold-sized
     band height to an explicitly unfolded call. On disagreement (or cache
     miss) the `default_blocks` heuristic fills the gaps, with the fold
@@ -207,7 +199,7 @@ def resolve_blocks(
         # DESIGN.md §10)
         return BlockConfig(int(block_rows), int(block_cols), bool(batch_fold))
     base: BlockConfig | None = None
-    entry = load_cache().get(config_key(kind, n, h, w, kh, kw, mult_impl))
+    entry = load_cache().get(config_key(kind, n, h, w, kh, kw))
     if entry:
         cached = BlockConfig(entry["block_rows"], entry["block_cols"],
                              bool(entry["batch_fold"]))
@@ -228,20 +220,19 @@ def resolve_blocks(
 
 @lru_cache(maxsize=None)
 def resolve_blocks_cached(kind: str, n: int, h: int, w: int, kh: int,
-                          kw: int, mult_impl: str,
+                          kw: int,
                           interpret: bool | None = None) -> BlockConfig:
     """Memoised default-field `resolve_blocks` for steady-state dispatch.
 
     The serving layer (and any other hot loop re-resolving the same
-    (kind, shape, mult_impl) point) pays the JSON-dict lookup and key
+    (kind, shape) point) pays the JSON-dict lookup and key
     formatting once; later calls are one dict hit on the memo.
     `invalidate_cache()` clears this memo together with the file cache, so
     a `store_cache` write is still visible process-wide. Explicit
     per-call overrides have no business here -- they bypass the cache
     entirely via `resolve_blocks`' fully-explicit fast path.
     """
-    return resolve_blocks(kind, n, h, w, kh, kw, mult_impl,
-                          interpret=interpret)
+    return resolve_blocks(kind, n, h, w, kh, kw, interpret=interpret)
 
 
 __all__ = ["CACHE_VERSION", "backend_key", "cache_generation", "cache_path",

@@ -2,27 +2,25 @@
 
 A `PlanConfig` names everything the tuner may choose for one
 (filter, batch/image shape) point -- not just the §8 grid organization but
-the *dataflow* and the tap-product implementation:
+the *dataflow*:
 
   * `dataflow`   -- 'direct' (one KxK pass), 'two_pass' (separable row then
                     column kernels with an HBM int32 intermediate), or
                     'fused' (both 1-D passes in one kernel, the intermediate
                     held in a VMEM halo band, DESIGN.md §7);
-  * `mult_impl`  -- 'kcm' | 'recurse' (DESIGN.md §7), or 'auto' meaning
-                    "defer to the pass-level resolution";
   * `block_rows` / `block_cols` / `batch_fold` -- the §8 grid fields; None
                     means "defer to the pass-level block cache/heuristic".
 
-Tuned plan entries (the `plans` section of the v2 cache,
+Tuned plan entries (the `plans` section of the cache,
 `repro.tuning.cache`) are always fully concrete; the deferring spellings
 exist so an *untuned* resolution changes nothing about the pre-plan
 behavior -- on a cache miss `resolve_plan` reproduces exactly the fixed
-defaults the pipeline used before plans existed (separable specs run
-fused, taps static resolves 'kcm').
+default the pipeline used before plans existed (separable specs run
+fused).
 
 Every plan is a pure throughput choice: outputs are bit-identical across
-dataflows (the separability contract, DESIGN.md §5), mult_impls (§7) and
-grid organizations (§8), so a wrong -- even adversarially poisoned --
+dataflows (the separability contract, DESIGN.md §5) and grid
+organizations (§8), so a wrong -- even adversarially poisoned --
 cache entry can only ever cost time, never bytes
 (tests/test_plan_equivalence.py). `sanitize_plan` enforces that by
 clamping cached fields to the kernel floors (`min_block_rows` /
@@ -34,31 +32,24 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
-from repro.core.platform import resolve_interpret
 from repro.tuning.blocks import min_block_cols, min_block_rows, round_up
 from repro.tuning.cache import load_plans
 
 #: dataflow vocabulary of the plan search space (DESIGN.md §11).
 DATAFLOWS = ("direct", "two_pass", "fused")
 
-#: concrete tap-product implementations a tuned plan may pin ('auto' is the
-#: deferring spelling, never stored).
-PLAN_MULT_IMPLS = ("recurse", "kcm")
-
 
 class PlanConfig(NamedTuple):
     """One full execution plan of the filter datapath (DESIGN.md §11)."""
 
     dataflow: str               # 'direct' | 'two_pass' | 'fused'
-    mult_impl: str              # 'recurse' | 'kcm' | 'auto' (= defer)
     block_rows: int | None      # None = defer to pass-level resolution
     block_cols: int | None      # None = defer (tuned entries store ints;
                                 # a full-width tile is spelled block_cols=w)
     batch_fold: bool | None     # None = defer
 
     def as_dict(self) -> dict:
-        return {"dataflow": self.dataflow, "mult_impl": self.mult_impl,
-                "block_rows": self.block_rows, "block_cols": self.block_cols,
+        return {"dataflow": self.dataflow, "block_rows": self.block_rows, "block_cols": self.block_cols,
                 "batch_fold": self.batch_fold}
 
 
@@ -102,8 +93,6 @@ def sanitize_plan(plan: PlanConfig, n: int, h: int, w: int, kh: int,
     """
     if plan.dataflow not in DATAFLOWS:
         return None
-    if plan.mult_impl not in PLAN_MULT_IMPLS:
-        return None
     ph = kh // 2
     br, bc, fold = plan.block_rows, plan.block_cols, plan.batch_fold
     fold = None if fold is None else bool(fold)
@@ -120,8 +109,7 @@ def sanitize_plan(plan: PlanConfig, n: int, h: int, w: int, kh: int,
 def _entry_plan(entry: dict) -> PlanConfig | None:
     """A cache entry's PlanConfig, or None when the entry is malformed."""
     try:
-        return PlanConfig(str(entry["dataflow"]), str(entry["mult_impl"]),
-                          int(entry["block_rows"]),
+        return PlanConfig(str(entry["dataflow"]), int(entry["block_rows"]),
                           int(entry["block_cols"]),
                           bool(entry["batch_fold"]))
     except (KeyError, TypeError, ValueError):
@@ -137,14 +125,11 @@ def resolve_plan(
     kw: int,
     *,
     separable_ok: bool,
-    mult_impl: str = "auto",
     separable: bool | None = None,
     fused: bool | None = None,
     block_rows: int | None = None,
     block_cols: int | None = None,
     batch_fold: bool | None = None,
-    interpret: bool | None = None,
-    kcm_ok: bool = True,
 ) -> PlanConfig:
     """The single plan lookup path: explicit > cached > pre-plan defaults.
 
@@ -153,28 +138,20 @@ def resolve_plan(
       * every explicitly supplied argument wins unconditionally;
       * the cached plan donates its remaining fields only where it AGREES
         with the explicit ones -- a dataflow the caller's `separable=` /
-        `fused=` arguments exclude rejects the entry wholesale, a pinned
-        `mult_impl` that differs keeps the entry's dataflow but drops its
-        tuned grid fields (they were measured under the other impl), and
-        any disagreeing explicit block field likewise drops the entry's
-        block fields as a unit;
+        `fused=` arguments exclude rejects the entry wholesale, and any
+        disagreeing explicit block field drops the entry's block fields as
+        a unit;
       * what remains unset defers downstream: dataflow to the pre-plan
-        fixed default (fused when the spec separates, else direct),
-        mult_impl to the pass-level 'auto', block fields to the §8 block
-        cache/heuristic inside the conv passes.
-
-    A cached 'kcm' plan is dropped when the passes compile (`interpret`
-    resolves False): Mosaic cannot lower the KCM gather. It is dropped too
-    where `kcm_ok` is False: a pass wider than a KCM ROM (the 32-bit column
-    pass of 16-bit pixels).
+        fixed default (fused when the spec separates, else direct), block
+        fields to the §8 block cache/heuristic inside the conv passes.
     """
     allowed = allowed_dataflows(separable_ok, separable, fused)
-    if (len(allowed) == 1 and mult_impl != "auto"
+    if (len(allowed) == 1
             and None not in (block_rows, block_cols, batch_fold)):
         # fully explicit call: nothing to look up (the serve hot path, which
         # pins a memoised per-bucket plan on every dispatch, DESIGN.md §10)
-        return PlanConfig(allowed[0], mult_impl, int(block_rows),
-                          int(block_cols), bool(batch_fold))
+        return PlanConfig(allowed[0], int(block_rows), int(block_cols),
+                          bool(batch_fold))
     cand: PlanConfig | None = None
     entry = load_plans().get(plan_key(name, n, h, w))
     if entry:
@@ -183,32 +160,24 @@ def resolve_plan(
             cand = sanitize_plan(cand, n, h, w, kh, kw)
         if cand is not None and cand.dataflow not in allowed:
             cand = None
-        if (cand is not None and cand.mult_impl == "kcm"
-                and not (kcm_ok and resolve_interpret(interpret))):
-            cand = None
-        if cand is not None:
-            if mult_impl != "auto" and cand.mult_impl != mult_impl:
-                cand = cand._replace(mult_impl=mult_impl, block_rows=None,
-                                     block_cols=None, batch_fold=None)
-            elif any(
-                exp is not None and exp != got
-                for exp, got in ((block_rows, cand.block_rows),
-                                 (block_cols, cand.block_cols),
-                                 (None if batch_fold is None
-                                  else bool(batch_fold), cand.batch_fold))
-            ):
-                cand = cand._replace(block_rows=None, block_cols=None,
-                                     batch_fold=None)
+        if cand is not None and any(
+            exp is not None and exp != got
+            for exp, got in ((block_rows, cand.block_rows),
+                             (block_cols, cand.block_cols),
+                             (None if batch_fold is None
+                              else bool(batch_fold), cand.batch_fold))
+        ):
+            cand = cand._replace(block_rows=None, block_cols=None,
+                                 batch_fold=None)
     if cand is None:
-        cand = PlanConfig(allowed[0], mult_impl, None, None, None)
+        cand = PlanConfig(allowed[0], None, None, None)
     return PlanConfig(
         cand.dataflow,
-        cand.mult_impl if mult_impl == "auto" else mult_impl,
         cand.block_rows if block_rows is None else int(block_rows),
         cand.block_cols if block_cols is None else int(block_cols),
         cand.batch_fold if batch_fold is None else bool(batch_fold),
     )
 
 
-__all__ = ["DATAFLOWS", "PLAN_MULT_IMPLS", "PlanConfig", "allowed_dataflows",
+__all__ = ["DATAFLOWS", "PlanConfig", "allowed_dataflows",
            "plan_key", "resolve_plan", "sanitize_plan"]

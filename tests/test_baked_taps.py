@@ -5,9 +5,9 @@ adds nothing, and REFMLM runs the kom4 recursion with a constant operand,
 so every base product whose coefficient digit is 0 is dropped. Traced taps
 (a caller that jits over them) ride in SMEM and pay the full recursion.
 Both must be byte-equal to each other and to the oracle in
-`repro.filters.ref`, for every bank filter, both REFMLM bases and every
-dataflow. `mult_impl='recurse'` is the path a compiled pass takes; the
-kernels run in interpret mode here.
+`repro.filters.ref`, for every bank filter, both REFMLM bases, Mitchell's
+multiplier (which the `fvc2004_mitchell` cell serves baked) and every
+dataflow. The kernels run in interpret mode here.
 """
 import functools
 
@@ -28,9 +28,9 @@ from repro.filters.ref import apply_filter_ref
 
 RNG = np.random.default_rng(11)
 IMGS = jnp.asarray(RNG.integers(0, 256, (2, 9, 12)), jnp.int32)
-REFMLM = ("refmlm", "refmlm_nc")
+METHODS = ("refmlm", "refmlm_nc", "mitchell")
 CASES = [(name, method, flow)
-         for name in FILTER_NAMES for method in REFMLM
+         for name in FILTER_NAMES for method in METHODS
          for flow in (("direct", "two_pass", "fused")
                       if get_filter(name).separable else ("direct",))]
 
@@ -44,7 +44,7 @@ def _taps(spec, flow):
 
 def _run(imgs, *taps, spec, method, flow):
     """One dataflow of `spec` through the recursion; taps static or traced."""
-    kw = dict(method=method, interpret=True, mult_impl="recurse")
+    kw = dict(method=method, interpret=True)
     if flow == "direct":
         return conv2d_pass(imgs, taps[0], nbits=8, shift=spec.shift,
                            post=spec.post, **kw)
@@ -105,7 +105,7 @@ def test_conv_tap_leaves_of_the_served_filters(name, row, col, baked, smem):
 def test_direct_pass_records_its_own_kernel():
     taps = get_filter("laplacian").taps
     conv2d_pass(IMGS, taps, method="refmlm_nc", nbits=8, shift=0, post="abs",
-                interpret=True, mult_impl="recurse")
+                interpret=True)
     # taps 0,1,0 / 1,-4,1 / 0,1,0: five non-zero taps of one digit each
     assert _leaves(kernel="refmlm_conv_pass", coef="baked",
                    method="refmlm_nc", nbits="8",
@@ -124,8 +124,8 @@ def test_distributed_paths_bake_their_taps(exec_kw, monkeypatch):
     monkeypatch.setattr(conv, "_record_tap_leaves",
                         lambda *a: builds.append(a) or record(*a))
     jax.clear_caches()                  # trace the kernels afresh
-    got = apply_filter(IMGS, "sobel_y", method="refmlm", mult_impl="recurse",
-                       interpret=True, **exec_kw)
+    got = apply_filter(IMGS, "sobel_y", method="refmlm", interpret=True,
+                       **exec_kw)
     np.testing.assert_array_equal(
         np.asarray(got), np.asarray(apply_filter_ref(IMGS, "sobel_y")))
     assert builds and all(isinstance(taps, tuple)

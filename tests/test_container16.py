@@ -29,12 +29,11 @@ from repro.core.refmlm import (
 from repro.filters import FILTER_BANK, FILTER_NAMES, apply_filter, get_filter
 from repro.filters.bank import max_intermediate
 from repro.filters.conv import (
-    _resolve_mult_impl,
     conv2d_pass,
     fused_separable_pass,
     second_pass_nbits,
 )
-from repro.filters.pipeline import filter_nbits, resolve_filter_plan
+from repro.filters.pipeline import filter_nbits
 from repro.filters.ref import plain_filter_ref
 
 RNG = np.random.default_rng(16)
@@ -135,7 +134,7 @@ def _taps(spec, flow):
 
 def _run16(imgs, *taps, spec, flow):
     """One dataflow of `spec` on 16-bit pixels through the recursion."""
-    kw = dict(method="refmlm", interpret=True, mult_impl="recurse")
+    kw = dict(method="refmlm", interpret=True)
     if flow == "direct":
         return conv2d_pass(imgs, taps[0], nbits=16, shift=spec.shift,
                            post=spec.post, top=65535, **kw)
@@ -216,22 +215,6 @@ def test_sobel_keeps_its_magnitude_past_the_stored_bits():
     assert out.dtype == np.uint16
     assert out.max() == 4 * 4095 > 4095
     np.testing.assert_array_equal(out, plain_filter_ref(step, "sobel_x"))
-
-
-def test_kcm_has_no_32_bit_rom():
-    taps = np.asarray([1, 4, 6, 4, 1], np.int32)
-    assert _resolve_mult_impl("auto", taps, interpret=True, nbits=16) == "kcm"
-    assert _resolve_mult_impl("auto", taps, interpret=True,
-                              nbits=32) == "recurse"
-    with pytest.raises(ValueError, match="32"):
-        _resolve_mult_impl("kcm", taps, interpret=True, nbits=32)
-    # a cached KCM plan (the CPU plan cache's gaussian5 at 1 x 128 x 128)
-    # serves 8-bit pixels and is dropped for 16-bit ones
-    assert resolve_filter_plan("gaussian5", 1, 128, 128,
-                               interpret=True).mult_impl == "kcm"
-    assert resolve_filter_plan("gaussian5", 1, 128, 128, interpret=True,
-                               nbits=16, container="uint16"
-                               ).mult_impl == "recurse"
 
 
 def test_methods_without_the_width_and_scale_out_modes_refuse_uint16():

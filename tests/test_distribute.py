@@ -36,9 +36,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 RNG = np.random.default_rng(7)
 BATCH = jnp.asarray(RNG.integers(0, 256, (2, 48, 40)), jnp.int32)
 
-#: the multiplier configs of the invariance contract: exact, the paper's
-#: REFMLM recursion, and the KCM constant-coefficient fast path.
-MULT_CONFIGS = (("exact", "auto"), ("refmlm", "recurse"), ("refmlm", "kcm"))
+#: the multipliers of the invariance contract: exact, the paper's REFMLM
+#: recursion, and Mitchell's approximate multiplier.
+MULT_METHODS = ("exact", "refmlm", "mitchell")
 
 
 def run_sub(code: str) -> str:
@@ -103,10 +103,10 @@ class TestShardPlanning:
 
 class TestStreamed:
     @pytest.mark.parametrize("name", FILTER_NAMES)
-    @pytest.mark.parametrize("method,impl", MULT_CONFIGS)
-    def test_bit_identical_to_local(self, name, method, impl):
-        local = apply_filter(BATCH, name, method=method, mult_impl=impl)
-        got = apply_filter(BATCH, name, method=method, mult_impl=impl,
+    @pytest.mark.parametrize("method", MULT_METHODS)
+    def test_bit_identical_to_local(self, name, method):
+        local = apply_filter(BATCH, name, method=method)
+        got = apply_filter(BATCH, name, method=method,
                            exec="streamed", tile=(16, 16), tile_batch=5)
         np.testing.assert_array_equal(np.asarray(local), got)
 
@@ -191,11 +191,11 @@ class TestShardedOneDevice:
         np.testing.assert_array_equal(local, got)
 
     def test_sharded_first_then_local(self):
-        """Regression: KCM product tables first materialized INSIDE the
-        shard_map trace must stay concrete constants -- an lru-cached
+        """Regression: a kernel whose taps are first baked INSIDE the
+        shard_map trace must leave nothing traced behind -- a cached
         tracer would poison every later local call with the same
         (method, taps) key (UnexpectedTracerError). Uses a multiplier
-        config no other test touches so the table cache is cold."""
+        config no other test touches so every cache is cold."""
         got = apply_filter(BATCH, "sharpen3", method="mitchell_ecc3",
                            exec="sharded", mesh_shape=(1, 1))
         local = apply_filter(BATCH, "sharpen3", method="mitchell_ecc3")
@@ -239,8 +239,8 @@ class TestDistributedCacheKeying:
         calls = []
         real = conv.resolve_blocks
 
-        def spy(kind, n, h, w, kh, kw, impl, **kwargs):
-            cfg = real(kind, n, h, w, kh, kw, impl, **kwargs)
+        def spy(kind, n, h, w, kh, kw, **kwargs):
+            cfg = real(kind, n, h, w, kh, kw, **kwargs)
             calls.append(((n, h, w), cfg))
             return cfg
 
@@ -249,7 +249,7 @@ class TestDistributedCacheKeying:
 
     def test_streamed_ignores_global_shape_winner(self, tmp_cache, monkeypatch):
         n, h, w = BATCH.shape
-        store_cache({config_key("fused", n, h, w, 5, 5, "kcm"):
+        store_cache({config_key("fused", n, h, w, 5, 5):
                      {"block_rows": self.SENTINEL, "block_cols": None,
                       "batch_fold": True, "us_per_call": 1.0}})
         calls = self._recording(monkeypatch)
@@ -265,7 +265,7 @@ class TestDistributedCacheKeying:
 
     def test_streamed_honors_tile_shape_winner(self, tmp_cache, monkeypatch):
         # gaussian5 / tile 16x16 / batch 5 -> fused passes on (5, 20, 20)
-        store_cache({config_key("fused", 5, 20, 20, 5, 5, "kcm"):
+        store_cache({config_key("fused", 5, 20, 20, 5, 5):
                      {"block_rows": 16, "block_cols": None,
                       "batch_fold": True, "us_per_call": 1.0}})
         calls = self._recording(monkeypatch)
@@ -316,14 +316,12 @@ def test_sharded_multi_device_sweep():
             got = np.asarray(apply_filter(imgs, name, exec="sharded", **kw))
             assert (got == np.asarray(local)).all(), (name, kw)
 
-        # every bank filter x {exact, refmlm, kcm} on a (2, 4) mesh:
+        # every bank filter x {exact, refmlm, mitchell} on a (2, 4) mesh:
         # batch 3 over 2 shards and 50 rows over 4 shards, both non-divisible
         for name in FILTER_NAMES:
-            for method, impl in (("exact", "auto"), ("refmlm", "recurse"),
-                                 ("refmlm", "kcm")):
-                local = apply_filter(imgs, name, method=method, mult_impl=impl)
-                check(name, local, method=method, mult_impl=impl,
-                      mesh_shape=(2, 4))
+            for method in ("exact", "refmlm", "mitchell"):
+                local = apply_filter(imgs, name, method=method)
+                check(name, local, method=method, mesh_shape=(2, 4))
         print("bank x mult sweep ok")
 
         # mesh shapes, halo modes, device counts {2, 8}
@@ -369,14 +367,14 @@ def test_sharded_multi_device_sweep():
         os.environ["REPRO_TUNE_CACHE"] = tempfile.mkdtemp()
         from repro.tuning import config_key, invalidate_cache, store_cache
         SENTINEL = 104
-        store_cache({config_key("fused", 3, 52, 44, 5, 5, "kcm"):
+        store_cache({config_key("fused", 3, 52, 44, 5, 5):
                      {"block_rows": SENTINEL, "block_cols": None,
                       "batch_fold": True, "us_per_call": 1.0}})
         import repro.filters.conv as conv
         calls = []
         real = conv.resolve_blocks
-        def spy(kind, n, h, w, kh, kw, impl, **kwargs):
-            cfg = real(kind, n, h, w, kh, kw, impl, **kwargs)
+        def spy(kind, n, h, w, kh, kw, **kwargs):
+            cfg = real(kind, n, h, w, kh, kw, **kwargs)
             calls.append(((n, h, w), cfg))
             return cfg
         conv.resolve_blocks = spy

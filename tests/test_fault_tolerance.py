@@ -208,7 +208,7 @@ class TestExecutorExactlyOnce:
     def _batch(self, n: int, seq0: int = 1) -> tuple[MicroBatch, list]:
         reqs = tuple(
             FilterRequest(img=image(seq0 + i), filt="gaussian3",
-                          method="refmlm", mult_impl="auto", exec="local",
+                          method="refmlm", exec="local",
                           nbits=8, future=FilterFuture(), submitted=0.0,
                           seq=seq0 + i)
             for i in range(n))

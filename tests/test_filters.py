@@ -121,12 +121,10 @@ class TestTilingInvariance:
                                                   f"bc={bc} fold={fold}")
 
     def test_recursion_impl_invariant_across_grids(self):
-        base = np.asarray(apply_filter(BATCH, "gaussian3", method="refmlm",
-                                       mult_impl="recurse"))
+        base = np.asarray(apply_filter(BATCH, "gaussian3", method="refmlm"))
         for br, bc, fold in TILINGS[1:2]:
             got = apply_filter(BATCH, "gaussian3", method="refmlm",
-                               mult_impl="recurse", block_rows=br,
-                               block_cols=bc, batch_fold=fold)
+                               block_rows=br, block_cols=bc, batch_fold=fold)
             np.testing.assert_array_equal(np.asarray(got), base)
 
     def test_narrow_column_tile_raises_below_halo_floor(self):

@@ -91,12 +91,12 @@ def test_per_layer_pinning(cal_models, x_eval):
 
 # ------------------------------------------------------------------ serving
 def test_bucket_keys_separate_workloads():
-    filt = bucket_key("gaussian3", "refmlm", "auto", "local", 8, 8, 8)
-    inf = bucket_key("mlp", "refmlm", "auto", "local", 8, 8, 8,
+    filt = bucket_key("gaussian3", "refmlm", "local", 8, 8, 8)
+    inf = bucket_key("mlp", "refmlm", "local", 8, 8, 8,
                      workload="infer")
     assert not filt.endswith("/infer")
     assert inf.endswith("/infer")
-    assert inf.split("/")[3] == "local"      # pool._native_mode contract
+    assert inf.split("/")[2] == "local"      # pool._native_mode contract
 
 
 @pytest.mark.parametrize("max_batch", [1, 3, 8])

@@ -30,8 +30,7 @@ DATAFLOWS = {"fused": dict(separable=True, fused=True),
              "direct": dict(separable=False)}
 
 #: every jitted conv pass, whose traces are the compiled executables
-JITTED_PASSES = (conv._conv2d_recurse, conv._conv2d_kcm,
-                 conv._fused_sep_recurse, conv._fused_sep_kcm)
+JITTED_PASSES = (conv._conv2d_recurse, conv._fused_sep_recurse)
 
 
 def batch(seed: int, top: int, dtype, n: int = 4) -> np.ndarray:
@@ -73,10 +72,10 @@ def test_uint8_batch_matches_int32(filt, dataflow, method):
 @pytest.mark.parametrize("filt,dataflow",
                          cases(["sobel_x"]) + [("gaussian5", "fused")])
 def test_uint8_batch_matches_int32_with_baked_taps(filt, dataflow, method):
-    """The compiled backends' tap products (baked taps, `mult_impl=
-    'recurse'`), where the interpreter's default is the KCM ROM."""
+    """The compiled backends' tap products (baked taps), on a second seed
+    of pixels."""
     assert_narrow_matches_int32(batch(2, 255, np.uint8), filt, method=method,
-                                mult_impl="recurse", **DATAFLOWS[dataflow])
+                                **DATAFLOWS[dataflow])
 
 
 @pytest.mark.parametrize("filt,dataflow", cases(["gaussian5", "sobel_x"]))
@@ -111,12 +110,15 @@ def test_the_uploaded_batch_is_in_its_wire_dtype(monkeypatch, dtypes, wire):
 
 def test_an_int32_pixel_above_255_does_not_wrap():
     """An int32 pixel past the 8-bit range reaches the datapath as it is,
-    not cut to its low byte on the way."""
+    not cut to its low byte on the way. The exact multiplier takes the
+    whole int32 value, so a box3 of 300 differs from one of 44; the 8-bit
+    recursion reads only the low 8 bits of its operand."""
     img = np.full(SHAPE, 300, np.int32)
-    served = apply_filter_batch([img], "box3")[0]
-    np.testing.assert_array_equal(served, np.asarray(apply_filter(img,
-                                                                  "box3")))
-    wrapped = apply_filter_batch([img.astype(np.uint8)], "box3")[0]
+    served = apply_filter_batch([img], "box3", method="exact")[0]
+    np.testing.assert_array_equal(
+        served, np.asarray(apply_filter(img, "box3", method="exact")))
+    wrapped = apply_filter_batch([img.astype(np.uint8)], "box3",
+                                 method="exact")[0]
     assert not np.array_equal(served, wrapped)
 
 

@@ -68,7 +68,7 @@ def image(seed: int, shape=(24, 20)) -> np.ndarray:
 def make_req(seq: int, *, t: float = 0.0, shape=(24, 20),
              filt="gaussian3", priority="normal") -> FilterRequest:
     return FilterRequest(img=image(seq, shape), filt=filt, method="refmlm",
-                         mult_impl="auto", exec="local", nbits=8,
+                         exec="local", nbits=8,
                          future=FilterFuture(), submitted=t, seq=seq,
                          priority=priority)
 

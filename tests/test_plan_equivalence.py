@@ -2,9 +2,9 @@
 random, degenerate, or adversarially poisoned -- must produce output
 bit-identical to the untuned direct-dataflow reference (DESIGN.md §11).
 
-Plans are pure throughput artifacts: the dataflow equivalence (§5), the
-mult_impl equivalence (§7) and the grid-organization invariance (§8) are
-each argued and tested separately, so a tuned plan composes only
+Plans are pure throughput artifacts: the dataflow equivalence (§5) and
+the grid-organization invariance (§8) are each argued and tested
+separately, so a tuned plan composes only
 bit-preserving choices. This file tests the *composition* end to end
 through `apply_filter`'s plan resolution, across filters x methods
 {exact, refmlm} x exec modes {local, streamed}, because that is the
@@ -39,8 +39,7 @@ def _imgs(n, h, w):
 
 def _run_plan(imgs, name, plan, *, method, exec_mode="local"):
     """Dispatch one fully-explicit plan the way the tuner does."""
-    kw = dict(method=method, mult_impl=plan.mult_impl,
-              block_rows=plan.block_rows, block_cols=plan.block_cols,
+    kw = dict(method=method, block_rows=plan.block_rows, block_cols=plan.block_cols,
               batch_fold=plan.batch_fold)
     if exec_mode == "streamed":
         kw.update(exec="streamed", tile=(16, 16), tile_batch=2)
@@ -60,7 +59,6 @@ def _random_plan(rng, separable_ok: bool, h: int, w: int) -> PlanConfig:
         ["direct", "two_pass", "fused"] if separable_ok else ["direct"])
     return PlanConfig(
         str(dataflow),
-        str(rng.choice(["kcm", "recurse"])),
         int(rng.choice([8, 16, 24, h, 4 * h])),
         int(rng.choice([8, 16, w, 2 * w])),
         bool(rng.choice([False, True])),
@@ -112,9 +110,9 @@ class TestRandomPlans:
         imgs = _imgs(n, h, w)
         ref = np.asarray(apply_filter(imgs, "gaussian5", separable=False))
         for plan in (
-            PlanConfig("fused", "kcm", 16 * h, w, True),
-            PlanConfig("two_pass", "kcm", h, 2 * w, False),
-            PlanConfig("direct", "recurse", 8, 8, True),
+            PlanConfig("fused", 16 * h, w, True),
+            PlanConfig("two_pass", h, 2 * w, False),
+            PlanConfig("direct", 8, 8, True),
         ):
             out = _run_plan(imgs, "gaussian5", plan, method="refmlm")
             np.testing.assert_array_equal(out, ref, err_msg=str(plan))
@@ -134,15 +132,15 @@ class TestPoisonedCache:
 
     @pytest.mark.parametrize("exec_mode", ["local", "streamed"])
     def test_absurd_winner_only_costs_time(self, tmp_cache, exec_mode):
-        """An adversarial committed winner -- worst dataflow, the ~90x
-        slower mult_impl, a band far taller than the image, a tile
-        narrower than the halo floor -- still yields identical bytes
-        through default-argument `apply_filter`."""
+        """An adversarial committed winner -- worst dataflow, a band far
+        taller than the image, a tile narrower than the halo floor --
+        still yields identical bytes through default-argument
+        `apply_filter`."""
         n, h, w = SHAPE
         imgs = _imgs(n, h, w)
         ref = np.asarray(apply_filter(imgs, "gaussian5", separable=False))
         self._poison("gaussian5", n, h, w, {
-            "dataflow": "direct", "mult_impl": "recurse",
+            "dataflow": "direct",
             "block_rows": 10_000, "block_cols": 4, "batch_fold": True,
             "us_per_call": 1.0})
         kw = ({"exec": "streamed", "tile": (16, 16), "tile_batch": 2}
@@ -155,7 +153,7 @@ class TestPoisonedCache:
         imgs = _imgs(n, h, w)
         ref = np.asarray(apply_filter(imgs, "gaussian5", separable=False))
         self._poison("gaussian5", n, h, w,
-                     {"dataflow": "systolic", "mult_impl": "kcm",
+                     {"dataflow": "systolic",
                       "block_rows": 8, "block_cols": 8, "batch_fold": False,
                       "us_per_call": 1.0})
         out = np.asarray(apply_filter(imgs, "gaussian5"))
@@ -163,11 +161,11 @@ class TestPoisonedCache:
 
     def test_sanitize_clamps_poisoned_blocks(self):
         clamped = sanitize_plan(
-            PlanConfig("fused", "kcm", 10_000, 4, False), 3, 24, 20, 5, 5)
+            PlanConfig("fused", 10_000, 4, False), 3, 24, 20, 5, 5)
         assert clamped is not None
         assert clamped.block_rows <= 24  # one band over the unfolded height
         assert clamped.block_cols >= 8   # the column-halo floor
-        assert sanitize_plan(PlanConfig("systolic", "kcm", 8, 8, False),
+        assert sanitize_plan(PlanConfig("systolic", 8, 8, False),
                              3, 24, 20, 5, 5) is None
 
 
@@ -190,19 +188,16 @@ class TestHypothesisProperty:
         @hypothesis.settings(max_examples=15, deadline=None)
         @hypothesis.given(
             name=st.sampled_from(["gaussian5", "laplacian"]),
-            mult_impl=st.sampled_from(["kcm", "recurse"]),
             dataflow=st.sampled_from(["direct", "two_pass", "fused"]),
             block_rows=st.sampled_from([8, 16, 24, h, 4 * h]),
             block_cols=st.sampled_from([8, 16, w, 2 * w]),
             batch_fold=st.booleans(),
         )
-        def check(name, mult_impl, dataflow, block_rows, block_cols,
-                  batch_fold):
+        def check(name, dataflow, block_rows, block_cols, batch_fold):
             from repro.filters import get_filter
             if not get_filter(name).separable:
                 dataflow = "direct"
-            plan = PlanConfig(dataflow, mult_impl, block_rows, block_cols,
-                              batch_fold)
+            plan = PlanConfig(dataflow, block_rows, block_cols, batch_fold)
             out = _run_plan(imgs, name, plan, method="refmlm")
             np.testing.assert_array_equal(out, refs[name], err_msg=str(plan))
 

@@ -137,9 +137,9 @@ class TestAnalyzeCompiled:
 # ------------------------------------------------------------ conv cost model
 
 
-def _cost(df, impl="kcm", n=8, h=128, w=128, k=5, br=64, bc=128,
-          fold=False, backend="cpu"):
-    return plan_cost(df, impl, n, h, w, k, k, block_rows=br, block_cols=bc,
+def _cost(df, n=8, h=128, w=128, k=5, br=64, bc=128, fold=False,
+          backend="cpu"):
+    return plan_cost(df, n, h, w, k, k, block_rows=br, block_cols=bc,
                      batch_fold=fold, backend=backend)
 
 
@@ -168,9 +168,11 @@ class TestConvModel:
         assert shallow.flops > deep.flops
 
     def test_recurse_factor(self):
-        k = _cost("two_pass", impl="kcm")
-        r = _cost("two_pass", impl="recurse")
-        assert r.flops == pytest.approx(k.flops * RECURSE_FLOP_FACTOR)
+        """Every tap product pays the recursion: 2 ops a tap, times the
+        factor, over the (tile-aligned) 8 x 128 x 128 grid."""
+        d = _cost("direct")
+        assert d.flops == pytest.approx(
+            2 * 25 * 8 * 128 * 128 * RECURSE_FLOP_FACTOR)
 
     def test_lower_bound_includes_launch_floor(self):
         c = _cost("two_pass", n=1, h=8, w=8)
@@ -202,8 +204,6 @@ class TestConvModel:
     def test_unknown_vocab_raises(self):
         with pytest.raises(ValueError):
             _cost("systolic")
-        with pytest.raises(ValueError):
-            _cost("direct", impl="booth")
 
     def test_backend_fallback_is_tpu(self):
         # compiled presets are keyed by device_kind; a device without one

@@ -49,10 +49,10 @@ def image(seed: int, shape=(24, 20)) -> np.ndarray:
 
 
 def make_req(seq: int, *, t: float = 0.0, shape=(24, 20),
-             filt="gaussian3", method="refmlm", mult_impl="auto",
+             filt="gaussian3", method="refmlm",
              exec_mode="local") -> FilterRequest:
     return FilterRequest(img=image(seq, shape), filt=filt, method=method,
-                         mult_impl=mult_impl, exec=exec_mode, nbits=8,
+                         exec=exec_mode, nbits=8,
                          future=FilterFuture(), submitted=t, seq=seq)
 
 
@@ -162,9 +162,9 @@ def test_batcher_random_schedule_exactly_once():
 
 # ------------------------------------------------- served output bit-identity
 
-#: the ISSUE's multiplier axis: exact, refmlm via per-tap recursion, and the
-#: KCM constant-coefficient fast path.
-MULT_POINTS = [("exact", "recurse"), ("refmlm", "recurse"), ("refmlm", "kcm")]
+#: the multiplier axis: exact, the paper's REFMLM, and Mitchell's
+#: approximate multiplier (the `fvc2004_mitchell` cell serves it).
+MULT_POINTS = ["exact", "refmlm", "mitchell"]
 
 
 def serve_all(reqs, config) -> list[np.ndarray]:
@@ -176,13 +176,13 @@ def serve_all(reqs, config) -> list[np.ndarray]:
 
 
 class TestServedBitIdentity:
-    @pytest.mark.parametrize("method,mult_impl", MULT_POINTS)
+    @pytest.mark.parametrize("method", MULT_POINTS)
     @pytest.mark.parametrize("name", FILTER_NAMES)
-    def test_local_every_filter_and_multiplier(self, name, method, mult_impl):
+    def test_local_every_filter_and_multiplier(self, name, method):
         """A coalesced batch serves each request bit-identically to the
         direct single-image apply_filter call."""
         imgs = [image(40 + i) for i in range(3)]
-        kw = dict(method=method, mult_impl=mult_impl)
+        kw = dict(method=method)
         outs = serve_all([(im, name, kw) for im in imgs],
                          ServerConfig(max_batch=4, max_delay_ms=FAR))
         for im, out in zip(imgs, outs):
@@ -298,7 +298,7 @@ class TestAdmission:
             with pytest.raises(ValueError):
                 srv.submit(image(6), "gaussian3", exec="warp")
             with pytest.raises(ValueError):
-                srv.submit(image(6), "gaussian3", mult_impl="magic")
+                srv.submit(image(6), "gaussian3", priority="urgent")
             with pytest.raises(ValueError):
                 srv.submit(np.zeros((2, 8, 8), np.int32), "gaussian3")
             assert srv.stats()["submitted"] == 0
@@ -318,9 +318,9 @@ class TestWarmupAndPlans:
         with ImageFilterServer(cfg) as srv:
             keys = srv.warmup([(24, 20)], ["gaussian3"], batches=[1, 4])
             assert keys == [
-                serve_key(bucket_key("gaussian3", "refmlm", "auto", "local",
+                serve_key(bucket_key("gaussian3", "refmlm", "local",
                                      8, 24, 20), 1),
-                serve_key(bucket_key("gaussian3", "refmlm", "auto", "local",
+                serve_key(bucket_key("gaussian3", "refmlm", "local",
                                      8, 24, 20), 4)]
             futs = [srv.submit(image(70 + i), "gaussian3") for i in range(4)]
             for f in futs:
@@ -352,7 +352,7 @@ class TestWarmupAndPlans:
         ex = BatchExecutor()
         key = ex.warm((16, 16), "box3", n=3)     # rounds to pow-2 like run
         assert key == serve_key(
-            bucket_key("box3", "refmlm", "auto", "local", 8, 16, 16), 4)
+            bucket_key("box3", "refmlm", "local", 8, 16, 16), 4)
         assert key in ex.warmed
 
 
@@ -402,23 +402,22 @@ class TestPipelineHooks:
         for name in ("gaussian5", "laplacian"):      # separable + direct
             n, h, w = imgs.shape
             plan = resolve_filter_plan(name, n, h, w)
-            assert plan.mult_impl in ("kcm", "recurse")   # concretized
             assert None not in (plan.block_rows, plan.block_cols,
                                 plan.batch_fold)
             pinned = apply_filter(
                 imgs, name, separable=plan.dataflow != "direct",
-                fused=plan.dataflow == "fused", mult_impl=plan.mult_impl,
+                fused=plan.dataflow == "fused",
                 block_rows=plan.block_rows, block_cols=plan.block_cols,
                 batch_fold=plan.batch_fold)
             np.testing.assert_array_equal(np.asarray(pinned),
                                           np.asarray(apply_filter(imgs, name)))
 
     def test_resolve_blocks_fully_explicit_fast_path(self):
-        got = resolve_blocks("direct", 1, 32, 32, 3, 3, "kcm",
+        got = resolve_blocks("direct", 1, 32, 32, 3, 3,
                              block_rows=16, block_cols=32, batch_fold=False)
         assert got == BlockConfig(16, 32, False)
 
     def test_resolve_blocks_cached_agrees(self):
-        args = ("fused", 4, 64, 64, 5, 5, "kcm")
+        args = ("fused", 4, 64, 64, 5, 5)
         assert resolve_blocks_cached(*args) == resolve_blocks(*args)
         assert resolve_blocks_cached(*args) is resolve_blocks_cached(*args)

@@ -57,14 +57,14 @@ def test_uint8_and_uint16_requests_never_share_a_batch():
 
 
 def test_uint8_bucket_keys_and_answers_are_unchanged():
-    assert (bucket_key("gaussian5", "refmlm", "auto", "local", 8, 24, 20)
-            == "gaussian5/refmlm/auto/local/b8/24x20/normal")
-    assert (bucket_key("gaussian5", "refmlm", "auto", "local", 16, 24, 20,
+    assert (bucket_key("gaussian5", "refmlm", "local", 8, 24, 20)
+            == "gaussian5/refmlm/local/b8/24x20/normal")
+    assert (bucket_key("gaussian5", "refmlm", "local", 16, 24, 20,
                        container="uint16")
-            == "gaussian5/refmlm/auto/local/b16/24x20/normal/uint16")
-    assert (bucket_key("mlp", "refmlm", "auto", "local", 8, 8, 8,
+            == "gaussian5/refmlm/local/b16/24x20/normal/uint16")
+    assert (bucket_key("mlp", "refmlm", "local", 8, 8, 8,
                        workload="infer")
-            == "mlp/refmlm/auto/local/b8/8x8/normal/infer")
+            == "mlp/refmlm/local/b8/8x8/normal/infer")
     imgs = [image(30 + i, 255, dt)
             for i, dt in enumerate((np.uint8, np.int32, np.int64))]
     with ImageFilterServer(ServerConfig(max_batch=4,
@@ -100,7 +100,7 @@ def test_warmup_at_16_bits_compiles_the_uint16_container():
     assert warm_container(16, workload="infer") == "uint8"
     ex = BatchExecutor()
     key = ex.warm(SHAPE, "gaussian5", nbits=16, n=2)
-    assert key == serve_key(bucket_key("gaussian5", "refmlm", "auto",
+    assert key == serve_key(bucket_key("gaussian5", "refmlm",
                                        "local", 16, *SHAPE,
                                        container="uint16"), 2)
     with ImageFilterServer(ServerConfig(max_batch=2,

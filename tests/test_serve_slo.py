@@ -64,7 +64,7 @@ def mk_req(seq: int, *, shape=(32, 32), filt="gaussian3",
            deadline=None) -> FilterRequest:
     h, w = shape
     return FilterRequest(img=image(seq, shape), filt=filt, method="refmlm",
-                         mult_impl="auto", exec="local", nbits=8,
+                         exec="local", nbits=8,
                          future=FilterFuture(), submitted=submitted, seq=seq,
                          deadline=deadline, priority=priority,
                          slo=slo, weight=request_weight(h, w))
@@ -328,24 +328,24 @@ class TestPlanMemoLRU:
         ex = BatchExecutor(plan_memo_max=2)
         shapes = [(32, 32), (48, 48), (64, 64)]
         for h, w in shapes:
-            ex._plan("gaussian3", "refmlm", "auto", 1, h, w)
+            ex._plan("gaussian3", "refmlm", 1, h, w)
         pm = ex.stats()["plan_memo"]
         assert pm == {"size": 2, "max": 2, "hits": 0, "misses": 3,
                       "evicts": 1}
-        ex._plan("gaussian3", "refmlm", "auto", 1, 64, 64)   # still resident
+        ex._plan("gaussian3", "refmlm", 1, 64, 64)   # still resident
         assert ex.stats()["plan_memo"]["hits"] == 1
-        ex._plan("gaussian3", "refmlm", "auto", 1, 32, 32)   # was evicted
+        ex._plan("gaussian3", "refmlm", 1, 32, 32)   # was evicted
         pm = ex.stats()["plan_memo"]
         assert pm["misses"] == 4 and pm["evicts"] == 2 and pm["size"] == 2
 
     def test_lru_keeps_the_hot_entry(self):
         ex = BatchExecutor(plan_memo_max=2)
-        ex._plan("gaussian3", "refmlm", "auto", 1, 32, 32)
-        ex._plan("gaussian3", "refmlm", "auto", 1, 48, 48)
-        ex._plan("gaussian3", "refmlm", "auto", 1, 32, 32)   # touch -> MRU
-        ex._plan("gaussian3", "refmlm", "auto", 1, 64, 64)   # evicts 48
+        ex._plan("gaussian3", "refmlm", 1, 32, 32)
+        ex._plan("gaussian3", "refmlm", 1, 48, 48)
+        ex._plan("gaussian3", "refmlm", 1, 32, 32)   # touch -> MRU
+        ex._plan("gaussian3", "refmlm", 1, 64, 64)   # evicts 48
         assert ex.stats()["plan_memo"]["evicts"] == 1
-        ex._plan("gaussian3", "refmlm", "auto", 1, 32, 32)
+        ex._plan("gaussian3", "refmlm", 1, 32, 32)
         assert ex.stats()["plan_memo"]["hits"] == 2
 
 
@@ -354,14 +354,14 @@ class TestPlanMemoLRU:
 def routed_member(filt: str, members=("m0", "m1"), exec_mode="sharded",
                   shape=(32, 32)) -> str:
     h, w = shape
-    key = bucket_key(filt, "refmlm", "auto", exec_mode, 8, h, w, "normal")
+    key = bucket_key(filt, "refmlm", exec_mode, 8, h, w, "normal")
     return max(members, key=lambda m: rendezvous_score(m, key))
 
 
 class TestExecutorPool:
     def test_rendezvous_is_stable_under_member_removal(self):
         """Removing one member re-routes only that member's keys."""
-        keys = [bucket_key(f"f{i}", "refmlm", "auto", "local", 8, 32, 32)
+        keys = [bucket_key(f"f{i}", "refmlm", "local", 8, 32, 32)
                 for i in range(60)]
         full = {k: max(("m0", "m1", "m2"),
                        key=lambda m: rendezvous_score(m, k)) for k in keys}

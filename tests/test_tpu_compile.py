@@ -2,8 +2,8 @@
 
 The topology is described (not attached), so each test compiles exactly
 what the chip's compiler would receive and fails where Mosaic would refuse
-it: a lowering it does not support (the KCM table gather), a block that
-overflows the 16 MiB scoped VMEM, a dtype the MXU does not take. Nothing
+it: a lowering it does not support, a block that overflows the 16 MiB
+scoped VMEM, a dtype the MXU does not take. Nothing
 runs, so these tests say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
@@ -141,15 +141,6 @@ def test_karatsuba_matmul_kernel(one_chip, karatsuba):
             ah, al, bh, bl, karatsuba=karatsuba, interpret=False),
         one_chip, ((128, 256), jnp.int32), ((128, 256), jnp.int32),
         ((256, 128), jnp.int32), ((256, 128), jnp.int32)))
-
-
-@pytest.mark.parametrize("name", ["gaussian5", "sobel_x", "sharpen3"])
-def test_auto_never_takes_the_kcm_gather(one_chip, name):
-    """Default arguments on a compiled pass resolve to the recursion, so
-    the unlowerable 1-D ROM gather never reaches Mosaic."""
-    assert_mosaic(compiled_text(
-        lambda x: apply_filter(x, name, interpret=False),
-        one_chip, ((2, 64, 128), jnp.uint8)))
 
 
 def coef_operands(text: str, kernel: str) -> list[str]:

@@ -1,6 +1,6 @@
 """The autotuner (repro.tuning): §8 block heuristics and resolution
-order, the v2 cache schema (blocks + plans) with v1 migration, §11 plan
-resolution precedence, and sweep determinism/reproducibility."""
+order, the cache schema (blocks + plans), §11 plan resolution
+precedence, and sweep determinism/reproducibility."""
 import json
 
 import pytest
@@ -127,7 +127,7 @@ class TestVmemBudget:
 class TestCandidates:
     @pytest.mark.parametrize("row", DEFAULT_SWEEP[:4])
     def test_candidates_valid_and_unique(self, row):
-        kind, n, h, w, kh, kw, _ = row
+        kind, n, h, w, kh, kw = row
         cands = list(candidate_blocks(kind, n, h, w, kh, kw))
         assert cands and len(cands) == len(set(cands))
         for cfg in cands:
@@ -136,12 +136,12 @@ class TestCandidates:
 
 
 class TestCache:
-    KEY = config_key("direct", 2, 48, 40, 3, 3, "kcm")
+    KEY = config_key("direct", 2, 48, 40, 3, 3)
     ENTRY = {"block_rows": 24, "block_cols": 16, "batch_fold": True,
              "us_per_call": 1.0}
 
     def test_key_format(self):
-        assert self.KEY == "direct/kcm/n2x48x40/k3x3"
+        assert self.KEY == "direct/n2x48x40/k3x3"
 
     def test_store_load_roundtrip(self, tmp_cache):
         store_cache({self.KEY: self.ENTRY})
@@ -151,7 +151,7 @@ class TestCache:
                                                            monkeypatch):
         monkeypatch.setenv("BENCH_TIMESTAMP", "2026-01-01T00:00:00Z")
         configs = {self.KEY: self.ENTRY,
-                   config_key("fused", 1, 8, 8, 3, 3, "kcm"):
+                   config_key("fused", 1, 8, 8, 3, 3):
                        {"block_rows": 8, "block_cols": None,
                         "batch_fold": False, "us_per_call": 2.0}}
         path = store_cache(configs)
@@ -167,14 +167,14 @@ class TestCache:
         cache_path().write_text("{not json")
         invalidate_cache()
         assert load_cache() == {}
-        cfg = resolve_blocks("direct", 2, 48, 40, 3, 3, "kcm")
+        cfg = resolve_blocks("direct", 2, 48, 40, 3, 3)
         assert cfg == default_blocks("direct", 2, 48, 40, 3, 3)
 
 
 class TestResolve:
     def test_cached_entry_wins_over_heuristic(self, tmp_cache):
         store_cache({TestCache.KEY: TestCache.ENTRY})
-        cfg = resolve_blocks("direct", 2, 48, 40, 3, 3, "kcm")
+        cfg = resolve_blocks("direct", 2, 48, 40, 3, 3)
         assert cfg == BlockConfig(24, 16, True)
 
     def test_explicit_fields_win_over_cache(self, tmp_cache):
@@ -182,14 +182,14 @@ class TestResolve:
         any of them is rejected wholesale (its other fields were tuned for
         a different organization), so the rest comes from the heuristic."""
         store_cache({TestCache.KEY: TestCache.ENTRY})
-        cfg = resolve_blocks("direct", 2, 48, 40, 3, 3, "kcm",
+        cfg = resolve_blocks("direct", 2, 48, 40, 3, 3,
                              block_rows=8, batch_fold=False)
         heur = default_blocks("direct", 2, 48, 40, 3, 3, batch_fold=False)
         assert cfg == BlockConfig(8, heur.block_cols, False)
 
     def test_agreeing_explicit_fields_keep_the_cache(self, tmp_cache):
         store_cache({TestCache.KEY: TestCache.ENTRY})
-        cfg = resolve_blocks("direct", 2, 48, 40, 3, 3, "kcm",
+        cfg = resolve_blocks("direct", 2, 48, 40, 3, 3,
                              batch_fold=True)      # agrees with the entry
         assert cfg == BlockConfig(24, 16, True)
 
@@ -197,20 +197,20 @@ class TestResolve:
         """The serial-batch baseline must not inherit a fold-sized tall
         band from a fold-tuned winner (it would pad every image to the
         tall height and silently waste ~Nx compute)."""
-        key = config_key("direct", 8, 128, 128, 3, 3, "kcm")
+        key = config_key("direct", 8, 128, 128, 3, 3)
         store_cache({key: {"block_rows": 1040, "block_cols": None,
                            "batch_fold": True, "us_per_call": 1.0}})
-        cfg = resolve_blocks("direct", 8, 128, 128, 3, 3, "kcm",
+        cfg = resolve_blocks("direct", 8, 128, 128, 3, 3,
                              batch_fold=False)
         assert cfg == BlockConfig(choose_block_rows(128), None, False)
 
-    def test_other_impl_misses_the_cache(self, tmp_cache):
+    def test_other_extent_misses_the_cache(self, tmp_cache):
         store_cache({TestCache.KEY: TestCache.ENTRY})
-        cfg = resolve_blocks("direct", 2, 48, 40, 3, 3, "recurse")
-        assert cfg == default_blocks("direct", 2, 48, 40, 3, 3)
+        cfg = resolve_blocks("direct", 2, 48, 40, 5, 5)
+        assert cfg == default_blocks("direct", 2, 48, 40, 5, 5)
 
 
-PLAN_ENTRY = {"dataflow": "two_pass", "mult_impl": "kcm",
+PLAN_ENTRY = {"dataflow": "two_pass",
               "block_rows": 136, "block_cols": 64, "batch_fold": True,
               "us_per_call": 500.0, "generated": "2026-01-01T00:00:00Z",
               "candidates": 54, "swept": 13, "pruned": 41}
@@ -226,29 +226,13 @@ class TestCacheV2:
         assert set(data) == {"meta", "blocks", "plans"}
 
     def test_blocks_only_store_preserves_plans(self, tmp_cache):
-        """The pre-v2 call signature (blocks mapping alone) must never
-        wipe tuned plans -- a block-only re-sweep keeps the plan section."""
+        """A blocks mapping alone must never wipe tuned plans -- a
+        block-only re-sweep keeps the plan section."""
         pkey = plan_key("gaussian5", 2, 64, 64)
         store_cache({}, {pkey: PLAN_ENTRY})
         store_cache({TestCache.KEY: TestCache.ENTRY})
         assert load_plans()[pkey] == PLAN_ENTRY
         assert load_cache()[TestCache.KEY] == TestCache.ENTRY
-
-    def test_v1_file_migrates_on_load(self, tmp_cache):
-        """Legacy files store the flat block mapping under 'configs'; they
-        load as the blocks section with an empty plan section, and the
-        next store rewrites them as v2."""
-        cache_path().write_text(json.dumps(
-            {"meta": {"backend": backend_key(), "version": 1},
-             "configs": {TestCache.KEY: TestCache.ENTRY}}))
-        invalidate_cache()
-        assert load_cache()[TestCache.KEY] == TestCache.ENTRY
-        assert load_plans() == {}
-        store_cache(load_cache())
-        data = json.loads(cache_path().read_text())
-        assert data["meta"]["version"] == 2
-        assert "configs" not in data
-        assert data["blocks"][TestCache.KEY] == TestCache.ENTRY
 
 
 class TestResolvePlan:
@@ -262,69 +246,38 @@ class TestResolvePlan:
     def test_miss_reproduces_pre_plan_defaults(self, tmp_cache):
         """An untuned shape must change nothing: separable specs default
         to the fused dataflow, everything else defers downstream."""
-        assert self._resolve() == PlanConfig("fused", "auto",
-                                             None, None, None)
+        assert self._resolve() == PlanConfig("fused", None, None, None)
         assert resolve_plan("laplacian", 2, 64, 64, 3, 3,
                             separable_ok=False) == PlanConfig(
-                                "direct", "auto", None, None, None)
+                                "direct", None, None, None)
 
     def test_cached_plan_wins_on_default_args(self, tmp_cache):
         store_cache({}, {self.KEY: PLAN_ENTRY})
-        assert self._resolve() == PlanConfig("two_pass", "kcm", 136, 64,
-                                             True)
+        assert self._resolve() == PlanConfig("two_pass", 136, 64, True)
 
     def test_explicit_dataflow_rejects_disagreeing_entry(self, tmp_cache):
         store_cache({}, {self.KEY: PLAN_ENTRY})
         # fused=True excludes the cached two_pass winner wholesale
-        assert self._resolve(fused=True) == PlanConfig("fused", "auto",
-                                                       None, None, None)
+        assert self._resolve(fused=True) == PlanConfig("fused", None, None,
+                                                       None)
         # separable=False likewise
         assert self._resolve(separable=False).dataflow == "direct"
-
-    def test_pinned_mult_impl_keeps_dataflow_drops_blocks(self, tmp_cache):
-        """Tuned grid fields were measured under the entry's impl; a
-        different pinned impl keeps the dataflow choice but re-defers the
-        blocks to the §8 pass-level resolution."""
-        store_cache({}, {self.KEY: PLAN_ENTRY})
-        assert self._resolve(mult_impl="recurse") == PlanConfig(
-            "two_pass", "recurse", None, None, None)
 
     def test_disagreeing_block_field_drops_entry_blocks(self, tmp_cache):
         store_cache({}, {self.KEY: PLAN_ENTRY})
         got = self._resolve(block_rows=32)
-        assert got == PlanConfig("two_pass", "kcm", 32, None, None)
+        assert got == PlanConfig("two_pass", 32, None, None)
 
     def test_agreeing_explicit_fields_keep_the_entry(self, tmp_cache):
         store_cache({}, {self.KEY: PLAN_ENTRY})
         assert self._resolve(batch_fold=True) == PlanConfig(
-            "two_pass", "kcm", 136, 64, True)
+            "two_pass", 136, 64, True)
 
     def test_fully_explicit_fast_path_skips_cache(self, tmp_cache):
         store_cache({}, {self.KEY: PLAN_ENTRY})
-        got = self._resolve(fused=True, mult_impl="recurse", block_rows=16,
-                            block_cols=32, batch_fold=False)
-        assert got == PlanConfig("fused", "recurse", 16, 32, False)
-
-    def test_compiled_pass_drops_cached_kcm_plan(self, tmp_cache):
-        """Mosaic cannot lower the KCM gather: a cached 'kcm' plan is
-        unusable when the passes compile, so the lookup falls back to the
-        pre-plan defaults instead of pinning it."""
-        store_cache({}, {self.KEY: PLAN_ENTRY})
-        assert self._resolve(interpret=True).mult_impl == "kcm"
-        assert self._resolve(interpret=False) == PlanConfig(
-            "fused", "auto", None, None, None)
-
-    def test_compiled_filter_plan_pins_recurse(self, tmp_cache):
-        from repro.filters import resolve_filter_plan
-        store_cache({}, {self.KEY: PLAN_ENTRY})
-        plan = resolve_filter_plan("gaussian5", self.N, self.H, self.W,
-                                   interpret=False)
-        assert plan.mult_impl == "recurse"
-        assert plan == PlanConfig("fused", "recurse", *default_blocks(
-            "fused", self.N, self.H, self.W, 5, 5, interpret=False)._replace(
-                block_cols=self.W))
-        assert resolve_filter_plan("gaussian5", self.N, self.H, self.W,
-                                   interpret=True).mult_impl == "kcm"
+        got = self._resolve(fused=True, block_rows=16, block_cols=32,
+                            batch_fold=False)
+        assert got == PlanConfig("fused", 16, 32, False)
 
 
 class TestPlanSweep:
@@ -334,7 +287,6 @@ class TestPlanSweep:
         assert a == b and len(a) == len(set(a))
         for p in a:
             assert p.dataflow in ("direct", "two_pass", "fused")
-            assert p.mult_impl in ("recurse", "kcm")
             assert None not in (p.block_rows, p.block_cols, p.batch_fold)
 
     def test_non_separable_filter_gets_direct_only(self):
@@ -361,7 +313,7 @@ class TestPlanSweep:
         assert entry["candidates"] == len(cands)
         assert entry["swept"] + entry["pruned"] == len(cands)
         assert entry["swept"] == len(records)
-        assert entry["pruned"] > 0          # the recurse tail must prune
+        assert entry["pruned"] > 0          # the hopeless tail must prune
         assert entry["swept"] < len(cands)  # strictly fewer than exhaustive
 
     def test_exhaustive_sweep_times_everything(self, tmp_cache):
@@ -377,7 +329,7 @@ class TestReproducibility:
     def _stub_timers(self, monkeypatch):
         """Deterministic timings as a pure function of the swept point --
         identical across runs, so any byte diff is the tuner's fault."""
-        def measure_stub(kind, cfg, n, h, w, kh, kw, impl, iters=3):
+        def measure_stub(kind, cfg, n, h, w, kh, kw, iters=3):
             return float(
                 100 + cfg.block_rows % 89 + (cfg.block_cols or 0) % 13
                 + cfg.batch_fold + len(kind))
@@ -385,8 +337,7 @@ class TestReproducibility:
         def measure_plan_stub(name, plan, n, h, w, iters=3):
             return float(
                 100 + plan.block_rows % 89 + plan.block_cols % 13
-                + bool(plan.batch_fold) + len(plan.dataflow)
-                + 900 * (plan.mult_impl == "recurse"))
+                + bool(plan.batch_fold) + len(plan.dataflow))
 
         monkeypatch.setattr("repro.tuning.autotune.measure", measure_stub)
         monkeypatch.setattr("repro.tuning.autotune.measure_plan",
@@ -412,17 +363,17 @@ class TestTune:
         fake = {BlockConfig(32, None, False): 30.0,
                 BlockConfig(64, None, False): 10.0}
 
-        def measure_stub(kind, cfg, n, h, w, kh, kw, impl, iters=3):
+        def measure_stub(kind, cfg, n, h, w, kh, kw, iters=3):
             return fake.get(cfg, 99.0)
 
         monkeypatch.setattr("repro.tuning.autotune.measure", measure_stub)
         monkeypatch.setattr("repro.tuning.autotune.candidate_blocks",
                             lambda *a: iter(fake))
-        sweep = [("direct", 1, 128, 128, 3, 3, "kcm")]
+        sweep = [("direct", 1, 128, 128, 3, 3)]
         configs = tune(sweep, verbose=False)
-        key = config_key("direct", 1, 128, 128, 3, 3, "kcm")
+        key = config_key("direct", 1, 128, 128, 3, 3)
         assert configs[key]["block_rows"] == 64
         assert configs[key]["us_per_call"] == 10.0
         store_cache(configs)
-        assert resolve_blocks("direct", 1, 128, 128, 3, 3,
-                              "kcm").block_rows == 64
+        assert resolve_blocks("direct", 1, 128, 128, 3,
+                              3).block_rows == 64
