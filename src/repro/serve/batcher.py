@@ -5,7 +5,7 @@ the server drives it under its own condition variable, and tests drive it
 with a fake clock. Requests land in per-`bucket_key` FIFO queues -- one
 bucket per (H, W) × filter × method × exec × nbits × priority,
 the set of fields one `apply_filter` call can serve -- and a bucket
-flushes as one `MicroBatch` when either trigger fires:
+flushes as one `MicroBatch` when a trigger fires:
 
   * **size**     -- the bucket holds its flush size: pop exactly that
                     many, leaving any remainder with its original arrival
@@ -13,6 +13,13 @@ flushes as one `MicroBatch` when either trigger fires:
   * **deadline** -- the *oldest* request has waited out the bucket's
                     flush deadline: pop up to the flush size (latency
                     floor under light traffic);
+  * **sparse**   -- no size or deadline trigger fires in any bucket (the
+                    worker would sleep), and the bucket's newest request
+                    came at least the bucket's flush delay after the
+                    previous request of the same bucket: holding one more
+                    delay is unlikely to bring a partner, so pop it now.
+                    A bucket's first request, or one whose arrival record
+                    was evicted (`ARRIVALS_MAX`), holds as before;
   * **drain**    -- shutdown or an explicit flush: pop everything.
 
 The flush size and deadline are **per bucket** since §13: an optional
@@ -59,7 +66,11 @@ from typing import Callable, NamedTuple
 from repro.obs.trace import NOOP
 from repro.serve.request import FilterRequest, PRIORITIES
 
-FLUSH_REASONS = ("size", "deadline", "drain")
+FLUSH_REASONS = ("size", "deadline", "sparse", "drain")
+
+#: bound of the per-bucket arrival record the sparse trigger reads; the
+#: least recently fed buckets are evicted first.
+ARRIVALS_MAX = 1024
 
 #: why a request was swept to the shed list (DESIGN.md §12/§13).
 SHED_CAUSES = ("deadline", "overload")
@@ -102,6 +113,10 @@ class ShapeBucketedBatcher:
         # insertion-ordered so equal deadlines flush in arrival order
         self._buckets: OrderedDict[str, deque[FilterRequest]] = OrderedDict()
         self._shed: list[ShedRequest] = []
+        # bucket key -> (newest arrival, gap to the arrival before it or
+        # None); outlives the bucket's deque, which `_pop` deletes
+        self._arrivals: OrderedDict[str, tuple[float, float | None]] = \
+            OrderedDict()
 
     @property
     def pending(self) -> int:
@@ -165,6 +180,11 @@ class ShapeBucketedBatcher:
         """Queue one admitted request; returns its bucket key."""
         key = req.key
         self._buckets.setdefault(key, deque()).append(req)
+        last = self._arrivals.pop(key, None)
+        self._arrivals[key] = (req.submitted, None if last is None
+                               else req.submitted - last[0])
+        if len(self._arrivals) > ARRIVALS_MAX:
+            self._arrivals.popitem(last=False)
         if self.trace.enabled:
             self.trace.event("enqueue", ts=req.submitted, seq=req.seq,
                              bucket=key, priority=req.priority,
@@ -191,13 +211,20 @@ class ShapeBucketedBatcher:
         keys = list(self._buckets)
         return sorted(keys, key=lambda k: self._buckets[k][0].rank)
 
+    def _sparse(self, key: str, delay: float) -> bool:
+        """True when the bucket's newest request came at least `delay`
+        after the one before it (unknown for a first or evicted record)."""
+        gap = self._arrivals.get(key, (0.0, None))[1]
+        return gap is not None and gap >= delay
+
     def ready(self, now: float | None = None) -> list[MicroBatch]:
         """All batches whose size or deadline trigger has fired at `now`,
         high-priority buckets first (expired requests are swept to the
-        shed list beforehand, never batched)."""
+        shed list beforehand, never batched). When none has, every sparse
+        bucket flushes instead, in the same order."""
         now = self.clock() if now is None else now
         self._sweep_expired(now)
-        out = []
+        out, held = [], []
         for key in self._ordered_keys():
             while key in self._buckets:
                 q = self._buckets[key]
@@ -207,7 +234,11 @@ class ShapeBucketedBatcher:
                 elif now - q[0].submitted >= delay:
                     out.append(self._pop(key, size, "deadline", now))
                 else:
+                    held.append((key, size, delay))
                     break
+        if not out:
+            out = [self._pop(key, size, "sparse", now)
+                   for key, size, delay in held if self._sparse(key, delay)]
         return out
 
     def next_deadline(self) -> float | None:
@@ -235,5 +266,5 @@ class ShapeBucketedBatcher:
         return out
 
 
-__all__ = ["FLUSH_REASONS", "SHED_CAUSES", "FlushPolicy", "MicroBatch",
-           "ShapeBucketedBatcher", "ShedRequest"]
+__all__ = ["ARRIVALS_MAX", "FLUSH_REASONS", "SHED_CAUSES", "FlushPolicy",
+           "MicroBatch", "ShapeBucketedBatcher", "ShedRequest"]

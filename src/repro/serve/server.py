@@ -6,8 +6,9 @@ One worker thread owns all device dispatch; client threads only validate,
 stack and wait. `submit()` admits a request through the backpressure gate
 (`repro.serve.admission`), drops it into the shape-bucketed micro-batcher
 (`repro.serve.batcher`) and returns a `FilterFuture`; the worker sleeps
-until the earliest bucket deadline (or a size trigger's notify), flushes
-every ready bucket through the executor, and fulfils the futures.
+until the earliest bucket deadline (or a submit's notify), flushes every
+ready bucket through the executor -- or, when none is, every bucket whose
+traffic is sparse -- and fulfils the futures.
 Admission slots are held until fulfilment, so `max_pending` bounds queued
 plus executing work -- in *weighted* slots since §13 (`request_weight`:
 a satellite frame charges its pixel count, not one thumbnail slot).
@@ -124,7 +125,9 @@ class ServerConfig:
     §13 service levels, §15 observability)."""
 
     max_batch: int = 8              # size flush trigger / occupancy ceiling
-    max_delay_ms: float = 2.0       # deadline flush trigger (oldest wait)
+    max_delay_ms: float = 2.0       # the longest hold; a sparse bucket
+    #                                 is cut earlier when nothing else is
+    #                                 ready (DESIGN.md §10)
     max_pending: int = 256          # admission gate: in-flight weight bound
     admission_timeout_s: float = 10.0
     pad_pow2: bool = True           # round traced batch up to a power of two

@@ -32,6 +32,7 @@ from repro.serve import (
     next_pow2,
     serve_key,
 )
+from repro.serve import batcher as batcher_mod
 from repro.tuning import resolve_blocks, resolve_blocks_cached
 from repro.tuning.blocks import BlockConfig
 
@@ -124,38 +125,142 @@ class TestBatcherPolicy:
         with pytest.raises(ValueError):
             ShapeBucketedBatcher(max_batch=1, max_delay_s=-1.0)
 
+    def test_sparse_bucket_flushes_at_once(self):
+        """A lone request whose bucket last saw a request at least the
+        flush delay earlier is cut at once, not on the deadline."""
+        b = ShapeBucketedBatcher(max_batch=8, max_delay_s=0.002,
+                                 clock=FakeClock())
+        b.add(make_req(0, t=0.0))
+        assert [f.reason for f in b.ready(now=0.002)] == ["deadline"]
+        b.add(make_req(1, t=0.010))
+        flushed = b.ready(now=0.010)
+        assert [f.reason for f in flushed] == ["sparse"]
+        assert [r.seq for r in flushed[0].requests] == [1]
+        assert b.pending == 0
+
+    def test_short_gap_holds_until_deadline(self):
+        b = ShapeBucketedBatcher(max_batch=8, max_delay_s=0.002,
+                                 clock=FakeClock())
+        b.add(make_req(0, t=0.0))
+        b.add(make_req(1, t=0.001))
+        assert b.ready(now=0.001) == []
+        assert [f.reason for f in b.ready(now=0.002)] == ["deadline"]
+        b.add(make_req(2, t=0.0025))   # 1.5 ms after seq 1: holds
+        assert b.ready(now=0.0025) == []
+        flushed = b.ready(now=0.005)
+        assert [f.reason for f in flushed] == ["deadline"]
+        assert [r.seq for r in flushed[0].requests] == [2]
+
+    def test_first_request_of_a_bucket_holds(self):
+        b = ShapeBucketedBatcher(max_batch=8, max_delay_s=0.002,
+                                 clock=FakeClock())
+        b.add(make_req(0, t=5.0))
+        assert b.ready(now=5.0) == []
+        assert b.next_deadline() == pytest.approx(5.002)
+
+    @pytest.mark.parametrize("other", ["size", "deadline"])
+    def test_sparse_bucket_waits_while_other_work_is_ready(self, other):
+        """Sparse flushes only fill a look that finds nothing else: a
+        size or deadline batch in another bucket runs first."""
+        b = ShapeBucketedBatcher(max_batch=2, max_delay_s=0.002,
+                                 clock=FakeClock())
+        b.add(make_req(0, t=0.0, shape=(16, 16)))
+        assert [f.reason for f in b.ready(now=0.002)] == ["deadline"]
+        if other == "size":
+            b.add(make_req(1, t=0.009))
+            b.add(make_req(2, t=0.009))
+        else:
+            b.add(make_req(1, t=0.0075))
+        b.add(make_req(3, t=0.010, shape=(16, 16)))
+        flushed = b.ready(now=0.010)
+        assert [f.reason for f in flushed] == [other]
+        assert {r.seq for r in flushed[0].requests} <= {1, 2}
+        flushed = b.ready(now=0.010)
+        assert [(f.reason, [r.seq for r in f.requests])
+                for f in flushed] == [("sparse", [3])]
+
+    def test_far_delay_never_sparse_flushes(self):
+        b = ShapeBucketedBatcher(max_batch=8, max_delay_s=FAR / 1e3,
+                                 clock=FakeClock())
+        for i in range(4):
+            b.add(make_req(i, t=1000.0 * i))
+            assert b.ready(now=1000.0 * i) == []
+        assert [len(f.requests) for f in b.drain()] == [4]
+
+    def test_arrival_record_is_bounded_and_evicted_key_holds(self,
+                                                            monkeypatch):
+        monkeypatch.setattr(batcher_mod, "ARRIVALS_MAX", 3)
+        b = ShapeBucketedBatcher(max_batch=8, max_delay_s=0.002,
+                                 clock=FakeClock())
+        shapes = [(8 + i, 8) for i in range(5)]
+        for i, shape in enumerate(shapes):
+            b.add(make_req(i, t=0.0, shape=shape))
+            assert len(b._arrivals) <= 3
+        assert sum(len(f.requests) for f in b.ready(now=0.002)) == 5
+        # shapes[0] and [1] were evicted: they hold like a first request,
+        # while the recorded shapes[4] is cut as sparse
+        b.add(make_req(10, t=1.0, shape=shapes[0]))
+        b.add(make_req(14, t=1.0, shape=shapes[4]))
+        flushed = b.ready(now=1.0)
+        assert [(f.reason, [r.seq for r in f.requests])
+                for f in flushed] == [("sparse", [14])]
+        assert b.pending == 1 and len(b._arrivals) == 3
+
+
+def check_schedule(events, max_batch: int) -> list[str]:
+    """Drive one add/advance/flush schedule; assert it partitions the
+    requests -- exactly-once, FIFO per bucket, uniform bucket key per
+    batch. Returns the flush reasons in order."""
+    shapes = [(8, 8), (16, 12), (24, 20)]
+    filters = ["gaussian3", "sobel_x", "box3"]
+    clk = FakeClock()
+    b = ShapeBucketedBatcher(max_batch=max_batch, max_delay_s=0.01,
+                             clock=clk)
+    keys, popped, reasons = {}, [], []
+    for i, (si, fi, tick) in enumerate(events):
+        clk.t += (0.0, 0.004, 0.012)[tick]
+        keys[i] = b.add(make_req(i, t=clk.t, shape=shapes[si],
+                                 filt=filters[fi]))
+        for batch in b.ready():
+            assert {r.key for r in batch.requests} == {batch.key}
+            popped.extend(r.seq for r in batch.requests)
+            reasons.append(batch.reason)
+    for batch in b.drain():
+        assert {r.key for r in batch.requests} == {batch.key}
+        popped.extend(r.seq for r in batch.requests)
+    assert sorted(popped) == list(keys)  # exactly once, none left
+    for key in set(keys.values()):
+        seqs = [s for s in popped if keys[s] == key]
+        assert seqs == sorted(seqs)      # FIFO within a bucket
+    assert b.pending == 0
+    return reasons
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_batcher_seeded_schedule_exactly_once(seed):
+    """Seeded schedules of adds and clock steps short and long against
+    the flush delay: the size, deadline and sparse triggers all fire, and
+    the requests are still partitioned."""
+    rng = np.random.default_rng(seed)
+    events = [tuple(int(v) for v in rng.integers(0, 3, 3))
+              for _ in range(40)]
+    assert set(check_schedule(events, 2)) == {"size", "deadline", "sparse"}
+
 
 def test_batcher_random_schedule_exactly_once():
-    """Property: any add/flush interleaving partitions the requests --
-    exactly-once, FIFO per bucket, uniform bucket key per batch."""
+    """Property: any add/flush interleaving, clock steps short and long
+    against the flush delay (so sparse flushes too), partitions the
+    requests -- exactly-once, FIFO per bucket, uniform bucket key per
+    batch."""
     hypothesis = pytest.importorskip("hypothesis")
     from hypothesis import given, settings, strategies as st
 
-    shapes = [(8, 8), (16, 12), (24, 20)]
-    filters = ["gaussian3", "sobel_x", "box3"]
-
     @settings(max_examples=50, deadline=None)
     @given(st.lists(st.tuples(st.integers(0, 2), st.integers(0, 2),
-                              st.booleans()), max_size=40),
+                              st.integers(0, 2)), max_size=40),
            st.integers(1, 5))
     def run(events, max_batch):
-        clk = FakeClock()
-        b = ShapeBucketedBatcher(max_batch=max_batch, max_delay_s=0.01,
-                                 clock=clk)
-        added, popped = [], []
-        for i, (si, fi, tick) in enumerate(events):
-            b.add(make_req(i, t=clk.t, shape=shapes[si], filt=filters[fi]))
-            added.append(i)
-            if tick:
-                clk.t += 0.02
-            for batch in b.ready():
-                assert {r.key for r in batch.requests} == {batch.key}
-                popped.extend(r.seq for r in batch.requests)
-        for batch in b.drain():
-            assert {r.key for r in batch.requests} == {batch.key}
-            popped.extend(r.seq for r in batch.requests)
-        assert sorted(popped) == added       # exactly once, none left
-        assert b.pending == 0
+        check_schedule(events, max_batch)
 
     run()
 
@@ -259,6 +364,22 @@ class TestExactlyOnceConcurrent:
         # the occupancy histogram accounts for every request exactly once
         assert sum(n * c for n, c in stats["occupancy"].items()) == total
         assert sum(stats["flush_reasons"].values()) == stats["batches"]
+
+
+def test_server_counts_sparse_flushes():
+    """With a finite hold, a bucket's first request waits out the
+    deadline; the next, submitted after that hold has passed, is cut at
+    once as sparse, and `stats()` counts it by reason."""
+    with ImageFilterServer(ServerConfig(max_batch=4,
+                                        max_delay_ms=200.0)) as srv:
+        outs = [srv.submit(image(i), "gaussian3").result(120)
+                for i in range(2)]
+        stats = srv.stats()
+    for i, out in enumerate(outs):
+        np.testing.assert_array_equal(
+            out, np.asarray(apply_filter(image(i), "gaussian3")))
+    assert stats["flush_reasons"] == {"deadline": 1, "sparse": 1}
+    assert stats["occupancy"] == {1: 2}
 
 
 # ------------------------------------------------- admission + lifecycle
